@@ -11,8 +11,8 @@ from repro.core import extensions as ext
 from repro.core.distributions import NAMES, block_sizes
 from repro.core.guidelines import regular_gather_time
 
-# Calibrated so TUW_Gatherv magnitudes land near the paper's Tables 1-6
-# (DESIGN.md §9): alpha ~ 1.8us startup, beta ~ 1.4ns per 4-byte int.
+# Calibrated so TUW_Gatherv magnitudes land near the paper's Tables 1-6:
+# alpha ~ 1.8us startup, beta ~ 1.4ns per 4-byte int.
 PARAMS = CostParams.infiniband_qdr()
 
 SIZES_B = (1, 10, 100, 1_000, 10_000)

@@ -61,8 +61,11 @@ def write_summary(benches: dict[str, tuple], total_s: float,
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import chaos_bench, extensions_bench, guidelines_bench, \
-        jax_runtime, moe_dispatch, moe_e2e, opttree_bench, paper_tables, \
+        moe_dispatch, moe_e2e, opttree_bench, paper_tables, \
         pipeline_bench, roofline, serve_bench, tuner_bench, variants
     t0 = time.time()
     print("name,us_per_call,derived")
@@ -76,7 +79,6 @@ def main() -> None:
     benches["pipeline"] = pipeline_bench.run()
     benches["moe_e2e"] = moe_e2e.run()
     benches["serve"] = serve_bench.run()
-    benches["jax_runtime"] = jax_runtime.run()
     benches["roofline"] = roofline.run()
     benches["chaos"] = chaos_bench.run(quick=True)
     benches["opttree"] = opttree_bench.run(quick=True)

@@ -24,9 +24,12 @@ Two lanes:
   sustained steps/s and p50/p99 step latency for both paths, plus the
   hot plan-path wall cost (classify + cache hit) per step.
 
-* **exec lane** (runs when ≥ 4 JAX devices are available, e.g. under
-  ``XLA_FLAGS=--xla_force_host_platform_device_count=4``) — payloads
-  REALLY flow through the compiled executables on a 4-device mesh:
+* **exec lane** (runs when ≥ 4 JAX devices are available; otherwise it
+  adds an ``exec_not_run`` row saying why) — payloads REALLY flow
+  through the compiled executables on a 4-device mesh, over the slab
+  data plane ``jax_collectives.set_dataplane`` selected (the compiled
+  Pallas kernels unless the caller chose otherwise; on forced CPU
+  devices choose ``"xla"`` or ``"interpret"``):
   per-step wall-clock latencies, and the recompile-free assertion on
   the honest XLA counter (the service's compiled-LRU misses — each
   miss jits one executable): ZERO new compiles after warmup.
@@ -58,6 +61,7 @@ else:
     from .moe_e2e import measure_plan
 
 from repro.core.costmodel import CostParams
+from repro.core.jax_collectives import DATAPLANES, dataplane, set_dataplane
 from repro.tuner import (PlannerService, ServingPlanner,
                          SyntheticTimingBackend)
 
@@ -193,8 +197,13 @@ def exec_lane(rows: list, seed: int = 3) -> dict:
     import jax
 
     if jax.device_count() < EXEC_P:
-        return {"skipped": f"device_count={jax.device_count()} < {EXEC_P}"}
-    mesh = jax.make_mesh((EXEC_P,), ("x",))
+        reason = f"device_count={jax.device_count()} < {EXEC_P}"
+        rows.append(("serve_bench/exec_not_run", 0.0, f"reason={reason}"))
+        print(f"# serve_bench exec lane did NOT run: {reason}",
+              file=sys.stderr)
+        return {"not_run": reason}
+    mesh = jax.make_mesh((EXEC_P,), ("x",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     svc = PlannerService(mesh=mesh, axis_name="x", quantum=1,
                          max_cached_plans=512, max_compiled=256)
     serving = ServingPlanner(svc, max_overhead=BOUND,
@@ -239,8 +248,10 @@ def exec_lane(rows: list, seed: int = 3) -> dict:
                  f"p50_us={steady['p50'] * 1e6:.0f};"
                  f"p99_us={steady['p99'] * 1e6:.0f};"
                  f"devices={EXEC_P};steady_steps={EXEC_STEPS - EXEC_WARMUP};"
-                 f"xla_recompiles=0;compiles_total={stats['compiles']}"))
-    return {"seed": seed, "devices": EXEC_P, "steps": EXEC_STEPS,
+                 f"xla_recompiles=0;compiles_total={stats['compiles']};"
+                 f"dataplane={dataplane()}"))
+    return {"seed": seed, "devices": EXEC_P, "dataplane": dataplane(),
+            "steps": EXEC_STEPS,
             "warmup": EXEC_WARMUP, "steady_wall": steady,
             "compiles_total": stats["compiles"],
             "steady_new_compiles": new_compiles,
@@ -275,7 +286,11 @@ def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--out", default=None,
                     help="JSON output path (default results/serve_bench.json)")
+    ap.add_argument("--dataplane", choices=DATAPLANES, default="pallas",
+                    help="slab data plane of the exec lane: compiled "
+                    "'pallas' (TPU), or 'interpret' / 'xla' on CPU devices")
     args = ap.parse_args()
+    set_dataplane(args.dataplane)
     print("name,us_per_call,derived")
     run(out_path=args.out)
 

@@ -18,7 +18,8 @@ dispatch signature of the second batch from the cache (no tree
 construction — watch the hit counter).
 
 Run WITHOUT setting XLA_FLAGS yourself — the script forces 8 host devices
-for the shard_map demo:
+for the shard_map demo and moves the slabs with the jnp reference data
+plane (the compiled Pallas kernels need a TPU):
 
     PYTHONPATH=src python examples/moe_irregular.py
 """
@@ -29,9 +30,11 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from repro.configs import get_config
 from repro.core.composed import independent_scatter_bytes
+from repro.core.jax_collectives import set_dataplane
 from repro.models import init_params
 from repro.models.moe import moe_apply
 from repro.tuner import PlannerService
@@ -48,7 +51,8 @@ print(f"routed {4 * 64} tokens x top-{cfg.moe.top_k} over "
       f"{E} experts; loads = {loads.tolist()} "
       f"(dropped {int(aux['dropped'])})")
 
-mesh = jax.make_mesh((8,), ("x",))
+set_dataplane("xla")
+mesh = jax.make_mesh((8,), ("x",), axis_types=(AxisType.Auto,))
 rng = np.random.default_rng(0)
 svc = PlannerService(mesh=mesh, axis_name="x", quantum=4)
 

@@ -4,6 +4,6 @@ JAX collective, inside a multi-pod training/serving framework.
 Subpackages: core (the paper), tuner (autotuning planner service:
 calibration, selection, plan cache), kernels (Pallas TPU), models,
 configs, data, optim, train, checkpoint, runtime, launch, analysis.
-See DESIGN.md / EXPERIMENTS.md at the repo root.
+See docs/ARCHITECTURE.md and EXPERIMENTS.md.
 """
 __version__ = "1.0.0"

@@ -1,3 +1,3 @@
 """Compiled-artifact analysis: HLO collective-byte accounting and roofline
-terms (DESIGN.md §8, EXPERIMENTS.md §Roofline)."""
+terms (EXPERIMENTS.md §Roofline)."""
 from .hlo import collective_bytes_from_hlo, CollectiveStats  # noqa: F401

@@ -1,7 +1,7 @@
 """Checkpointing: per-leaf shard files + manifest, atomic commit, async
 double-buffered saves, elastic restore (reshard to any mesh), and the
 TUW-tree consolidation plan (the paper's gatherv as checkpoint
-infrastructure — DESIGN.md §3).
+infrastructure).
 
 Layout:
   <dir>/step_<n>/manifest.json        tree structure, shapes, dtypes, step

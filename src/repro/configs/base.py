@@ -97,7 +97,7 @@ SHAPES = {
 
 
 def shape_applicable(cfg: ArchConfig, shape: str) -> bool:
-    """long_500k needs sub-quadratic attention (DESIGN.md §6)."""
+    """long_500k needs sub-quadratic attention."""
     if shape == "long_500k":
         return cfg.sublinear_attention
     return True

@@ -42,7 +42,7 @@ class CostParams:
     Canonical calibrations:
 
     * ``infiniband_qdr`` — the paper's Tables 1-6 setting: microseconds per
-      MPI_INT-sized (4-byte) unit (DESIGN.md §9).
+      MPI_INT-sized (4-byte) unit.
     * ``tpu_ici`` — SI units, no folklore factors: seconds and bytes
       (alpha = 1e-6 s/hop, beta = 1/50e9 s/byte for a 50 GB/s ICI link).
       Use ``to_us()`` when a caller reports microseconds.

@@ -13,8 +13,7 @@ G4:  Alltoallv(S)  <= Allreduce(1) + Alltoall(p^2 * max S_ij)
 where the RHS regular collective is the composed algorithm itself on the
 max-padded (regular) problem, exactly like G2's manual-padding transform.
 
-Evaluated in the alpha-beta cost model for any gatherv algorithm; the same
-checks run against measured wall-clock times in benchmarks/jax_runtime.py.
+Evaluated in the alpha-beta cost model for any gatherv algorithm.
 """
 from __future__ import annotations
 

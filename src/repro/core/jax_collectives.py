@@ -1,6 +1,6 @@
 """TUW gatherv/scatterv as JAX collectives (shard_map + lax.ppermute).
 
-TPU adaptation of the paper's point-to-point schedules (DESIGN.md §2):
+TPU adaptation of the paper's point-to-point schedules:
 
 * **static-irregular mode** — block sizes are known at trace time (uneven
   parameter shards, per-expert capacities, ragged eval outputs).  The tree
@@ -55,9 +55,9 @@ TPU adaptation of the paper's point-to-point schedules (DESIGN.md §2):
   contiguous slab and rounds overlap across chunks in ``R + S - 1``
   stages (the allgatherv broadcast streams chunks instead of repeating
   the full buffer).  Every step still moves only its live slab —
-  extracted/merged at dynamic offsets by the pluggable slab backend
-  (Pallas kernels on TPU via ``use_pallas_dataplane``, jnp reference
-  elsewhere) — and results are byte-identical to the monolithic path.
+  extracted/merged at dynamic offsets by the selected slab data plane
+  (compiled Pallas kernels by default, see ``set_dataplane``) — and
+  results are byte-identical to the monolithic path.
 
 * **hierarchical (multi-host) mode** — nothing in the lowering is
   single-host-specific: a two-level schedule
@@ -84,6 +84,7 @@ range offsets with no reordering.
 """
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass
 
@@ -93,8 +94,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map  # noqa: F401  (re-exported for callers)
-from repro.compat import shard_map_unchecked
 from repro.obs import trace as obs_trace
 from repro.obs.metrics import REGISTRY as _OBS_REGISTRY
 
@@ -105,30 +104,32 @@ from .pipeline import pipeline_rounds, pipeline_rounds_per_tree
 from .treegather import GatherTree, build_gather_tree, ceil_log2
 
 # --------------------------------------------------------------------------
-# slab backend: jnp reference vs Pallas kernels (repro.kernels.ragged_gather)
+# slab data plane: Pallas kernels (repro.kernels.ragged_gather) or XLA
 # --------------------------------------------------------------------------
 
-# None = auto (Pallas only on TPU, where the kernels compile); True/False
-# force.  The two backends are differentially tested row-identical.
-_PALLAS_SLABS: bool | None = None
+DATAPLANES = ("pallas", "interpret", "xla")
+_DATAPLANE = "pallas"
 
 
-def use_pallas_dataplane(enable: bool | None) -> None:
-    """Select the slab copy backend for the SPMD executors.
+def set_dataplane(kind: str) -> None:
+    """Select the slab data plane of every SPMD executor.
 
-    ``True`` routes every per-step slab extract/merge through the Pallas
-    kernels in ``repro.kernels.ragged_gather`` (compiled on TPU); ``False``
-    uses the jnp ``dynamic_slice`` reference; ``None`` (default) picks
-    Pallas exactly when running on TPU.
+    ``"pallas"`` (the default) runs the compiled Pallas kernels of
+    ``repro.kernels.ragged_gather``, the TPU path.  ``"interpret"`` runs
+    the same kernels in Pallas interpret mode, and ``"xla"`` the jnp
+    ``dynamic_slice`` reference; the two are for CPU runs.  Nothing
+    chooses from the device: a CPU process that keeps the default fails
+    when it lowers a kernel instead of silently interpreting it.
     """
-    global _PALLAS_SLABS
-    _PALLAS_SLABS = enable
+    global _DATAPLANE
+    if kind not in DATAPLANES:
+        raise ValueError(f"unknown data plane {kind!r}; one of {DATAPLANES}")
+    _DATAPLANE = kind
 
 
-def _pallas_slabs_enabled() -> bool:
-    if _PALLAS_SLABS is not None:
-        return _PALLAS_SLABS
-    return jax.default_backend() == "tpu"
+def dataplane() -> str:
+    """The slab data plane the executors lower with (``set_dataplane``)."""
+    return _DATAPLANE
 
 
 # --------------------------------------------------------------------------
@@ -348,32 +349,31 @@ def plan_gatherv(sizes, root: int, tree: GatherTree | None = None,
 # --------------------------------------------------------------------------
 
 def _slab_ops(reduce: bool = False):
-    """(extract, merge, step) triple: Pallas kernels on TPU, the jnp
-    oracles from ``repro.kernels.ragged_gather.ref`` elsewhere — one
-    definition of the slab semantics per backend (see
-    ``use_pallas_dataplane``).  ``step`` is the FUSED merge-then-extract
-    kernel the executors run between consecutive ppermutes.
-    ``reduce=True`` swaps in the fused-ADD variants (``slab_merge_add`` /
-    ``slab_step_reduce``): received slabs fold into the accumulator
-    instead of overwriting it — the only semantic difference between the
-    byte-moving and the reducing data planes."""
-    if _pallas_slabs_enabled():
-        from repro.kernels.ragged_gather.ops import (slab_extract,
-                                                     slab_merge,
-                                                     slab_merge_add,
-                                                     slab_step,
-                                                     slab_step_reduce)
+    """``(extract, merge, step, view)`` of the selected data plane
+    (``set_dataplane``): the Pallas kernels, compiled or interpreted, or
+    the jnp oracles of ``repro.kernels.ragged_gather.ref`` — one
+    definition of the slab semantics per backend.  ``step`` is the FUSED
+    merge-then-extract op the executors run between consecutive
+    ppermutes; ``view`` maps the (N, F) buffer to the layout the ops
+    take (the kernels' DMA row view).  ``reduce=True`` swaps in the
+    fused-ADD variants (``slab_merge_add`` / ``slab_step_reduce``):
+    received slabs fold into the accumulator instead of overwriting it —
+    the only semantic difference between the byte-moving and the
+    reducing data planes."""
+    if _DATAPLANE == "xla":
+        from repro.kernels.ragged_gather import ref
         if reduce:
-            return slab_extract, slab_merge_add, slab_step_reduce
-        return slab_extract, slab_merge, slab_step
-    from repro.kernels.ragged_gather.ref import (slab_extract_ref,
-                                                 slab_merge_add_ref,
-                                                 slab_merge_ref,
-                                                 slab_step_reduce_ref,
-                                                 slab_step_ref)
-    if reduce:
-        return slab_extract_ref, slab_merge_add_ref, slab_step_reduce_ref
-    return slab_extract_ref, slab_merge_ref, slab_step_ref
+            return (ref.slab_extract_ref, ref.slab_merge_add_ref,
+                    ref.slab_step_reduce_ref, lambda buf: buf)
+        return (ref.slab_extract_ref, ref.slab_merge_ref, ref.slab_step_ref,
+                lambda buf: buf)
+    from repro.kernels.ragged_gather import ops
+    kw = {"interpret": _DATAPLANE == "interpret"}
+    merge, step = ((ops.slab_merge_add, ops.slab_step_reduce) if reduce
+                   else (ops.slab_merge, ops.slab_step))
+    return (functools.partial(ops.slab_extract, **kw),
+            functools.partial(merge, **kw), functools.partial(step, **kw),
+            ops.row_view)
 
 
 def _apply_steps(buf: jax.Array, steps, r, axis_name: str,
@@ -385,23 +385,23 @@ def _apply_steps(buf: jax.Array, steps, r, axis_name: str,
     device's receive offset (same flat offset: zero-copy invariant).
 
     Between consecutive ppermutes, the step-``k`` merge and the
-    step-``k+1`` extract are FUSED into one kernel invocation (the
-    ``step`` backend op): one pass allocates the new buffer, folds the
-    received slab in, and reads the next outgoing slab from the merged
+    step-``k+1`` extract are FUSED into one op (``step``): it folds the
+    received slab in and reads the next outgoing slab from the merged
     state — the extract MUST see the merge result, because a forwarded
-    slab may contain rows that just arrived.  That turns the
-    3-local-passes-per-step pipeline (extract / permute / merge) into a
+    slab may contain rows that just arrived.  So a plan runs as a
     leading extract, one fused local op per ppermute, and a trailing
-    merge.  Slab ops go through the pluggable backend (Pallas on TPU).
+    merge, all through the selected data plane (``set_dataplane``).
 
-    ``reduce=True`` runs the same loop with the fused-ADD backend ops:
-    each received slab is summed into the receiver's rows.  ppermute
-    hands non-recipients a zero slab, but their ``recv_valid`` table
-    entry is 0, so the masked add leaves their accumulator bit-exact.
+    ``reduce=True`` runs the same loop with the fused-ADD ops: each
+    received slab is summed into the receiver's rows.  ppermute hands
+    non-recipients a zero slab, but their ``recv_valid`` table entry is
+    0, so the masked add leaves their accumulator bit-exact.
     """
     if not steps:
         return buf
-    extract, merge, step = _slab_ops(reduce)
+    extract, merge, step, view = _slab_ops(reduce)
+    shape = buf.shape
+    buf = view(buf)
     _, payload0, send0, _, _ = steps[0]
     out = extract(buf, jnp.asarray(send0)[r], payload0)
     for k, (perm, payload, send_start, recv_start, recv_valid) in \
@@ -415,7 +415,7 @@ def _apply_steps(buf: jax.Array, steps, r, axis_name: str,
                             npayload)
         else:
             buf = merge(buf, got, r0, nv)
-    return buf
+    return buf.reshape(shape)
 
 
 def gatherv_shard(x_local: jax.Array, plan: GathervPlan, axis_name: str) -> jax.Array:
@@ -614,10 +614,10 @@ def run_gatherv(mesh: Mesh, axis_name, blocks: list[np.ndarray],
 
     @jax.jit
     def run(xg):
-        return shard_map_unchecked(
+        return jax.shard_map(
             lambda xl: gatherv_shard(xl, plan, axis_name),
             mesh=mesh, in_specs=P(axis_name), out_specs=P(axis_name),
-        )(xg)
+            check_vma=False)(xg)
 
     xg = jax.device_put(x, NamedSharding(mesh, P(axis_name)))
     out = _run_traced("gatherv", plan, F * blocks[0].dtype.itemsize,
@@ -639,10 +639,10 @@ def run_scatterv(mesh: Mesh, axis_name, data: np.ndarray,
 
     @jax.jit
     def run(xg):
-        return shard_map_unchecked(
+        return jax.shard_map(
             lambda xl: scatterv_shard(xl, plan, axis_name),
             mesh=mesh, in_specs=P(axis_name), out_specs=P(axis_name),
-        )(xg)
+            check_vma=False)(xg)
 
     xg = jax.device_put(xin, NamedSharding(mesh, P(axis_name)))
     out = _run_traced("scatterv", plan, F * data.dtype.itemsize,
@@ -920,10 +920,10 @@ def run_allgatherv(mesh: Mesh, axis_name, blocks: list[np.ndarray],
 
     @jax.jit
     def run(xg):
-        return shard_map_unchecked(
+        return jax.shard_map(
             lambda xl: allgatherv_shard(xl, plan, axis_name),
             mesh=mesh, in_specs=P(axis_name), out_specs=P(axis_name),
-        )(xg)
+            check_vma=False)(xg)
 
     xg = jax.device_put(x, NamedSharding(mesh, P(axis_name)))
     out = _run_traced("allgatherv", plan, F * blocks[0].dtype.itemsize,
@@ -958,10 +958,10 @@ def run_alltoallv(mesh: Mesh, axis_name: str,
 
     @jax.jit
     def run(xg):
-        return shard_map_unchecked(
+        return jax.shard_map(
             lambda xl: alltoallv_shard(xl, plan, axis_name),
             mesh=mesh, in_specs=P(axis_name), out_specs=P(axis_name),
-        )(xg)
+            check_vma=False)(xg)
 
     xg = jax.device_put(x, NamedSharding(mesh, P(axis_name)))
     out = _run_traced("alltoallv", plan, F * dtype.itemsize,
@@ -1256,10 +1256,10 @@ def run_reduce_scatterv(mesh: Mesh, axis_name, contribs: list[np.ndarray],
 
     @jax.jit
     def run(xg):
-        return shard_map_unchecked(
+        return jax.shard_map(
             lambda xl: reduce_scatterv_shard(xl, plan, axis_name),
             mesh=mesh, in_specs=P(axis_name), out_specs=P(axis_name),
-        )(xg)
+            check_vma=False)(xg)
 
     xg = jax.device_put(x, NamedSharding(mesh, P(axis_name)))
     out = _run_traced("reduce_scatterv", plan,
@@ -1292,10 +1292,10 @@ def run_allreducev(mesh: Mesh, axis_name, contribs: list[np.ndarray],
 
     @jax.jit
     def run(xg):
-        return shard_map_unchecked(
+        return jax.shard_map(
             lambda xl: allreducev_shard(xl, plan, axis_name),
             mesh=mesh, in_specs=P(axis_name), out_specs=P(axis_name),
-        )(xg)
+            check_vma=False)(xg)
 
     xg = jax.device_put(x, NamedSharding(mesh, P(axis_name)))
     out = _run_traced("allreducev", plan, F * contribs[0].dtype.itemsize,

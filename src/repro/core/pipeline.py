@@ -183,13 +183,19 @@ def execute_steps_numpy(steps, bufs: np.ndarray) -> np.ndarray:
     """
     bufs = np.array(bufs, copy=True)
     for perm, payload, send_start, recv_start, recv_valid in steps:
-        snap = bufs.copy()
-        for s, d in perm:
-            s0 = int(send_start[s])
-            r0 = int(recv_start[d])
-            nv = int(recv_valid[d])
-            bufs[d, r0: r0 + nv] = snap[s, s0: s0 + nv]
+        for d, r0, rows in _sent_rows(bufs, perm, send_start, recv_start,
+                                      recv_valid):
+            bufs[d, r0: r0 + len(rows)] = rows
     return bufs
+
+
+def _sent_rows(bufs, perm, send_start, recv_start, recv_valid):
+    """Copies of every slab one step sends, taken before any lands:
+    ppermute semantics, without snapshotting the whole buffer."""
+    return [(d, int(recv_start[d]),
+             bufs[s, int(send_start[s]): int(send_start[s])
+                  + int(recv_valid[d])].copy())
+            for s, d in perm]
 
 
 def execute_alltoallv_plan_numpy(plan, blocks) -> list[np.ndarray]:
@@ -237,12 +243,9 @@ def execute_reduce_steps_numpy(steps, bufs: np.ndarray) -> np.ndarray:
     """
     bufs = np.array(bufs, copy=True)
     for perm, payload, send_start, recv_start, recv_valid in steps:
-        snap = bufs.copy()
-        for s, d in perm:
-            s0 = int(send_start[s])
-            r0 = int(recv_start[d])
-            nv = int(recv_valid[d])
-            bufs[d, r0: r0 + nv] += snap[s, s0: s0 + nv]
+        for d, r0, rows in _sent_rows(bufs, perm, send_start, recv_start,
+                                      recv_valid):
+            bufs[d, r0: r0 + len(rows)] += rows
     return bufs
 
 

@@ -6,7 +6,7 @@ checkpoint/restart bitwise-verifiable (tests/test_checkpoint.py) and what
 a 1000-node deployment needs (no shared iterator state to lose).
 
 ``RaggedBatcher`` produces variable-length sequence batches — the
-irregular-scatter consumer of DESIGN.md §3 (host -> devices scatterv).
+irregular-scatter consumer (host -> devices scatterv).
 """
 from __future__ import annotations
 

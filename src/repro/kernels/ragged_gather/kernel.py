@@ -14,28 +14,31 @@
   Out-of-range destinations land on a caller-provided trash row.
 * ``slab_extract_kernel`` / ``slab_merge_kernel`` — the per-ppermute
   slab copies of the gatherv/scatterv data plane: read ``rows``
-  contiguous rows at a DYNAMIC (traced, per-device) offset, and
-  mask-merge a received slab back at its receive offset.  The offsets
-  arrive as scalar-prefetch arguments, so inside ``shard_map`` each
-  device runs the same program with its own table-looked-up starts.
+  contiguous rows at a DYNAMIC (traced, per-device) offset, and write
+  the valid prefix of a received slab back at its receive offset.  The
+  offsets arrive as scalar-prefetch arguments, so inside ``shard_map``
+  each device runs the same program with its own table-looked-up
+  starts.  The buffer stays in HBM and is updated in place by DMA.
 * ``slab_step_kernel`` — the FUSED step of the executor loop: one
-  invocation copies the buffer, mask-merges the slab received by the
-  previous ppermute at the receive offset, and reads the NEXT outgoing
-  slab from the merged result (the extract must observe the merge — a
-  forwarded range can contain rows that just arrived; the sequential
-  single-step grid makes the in-kernel read-after-write well defined).
-  This replaces the separate merge + extract passes between consecutive
-  ppermutes — one kernel launch and one full-buffer traversal per step
-  instead of two.
+  invocation merges the slab received by the previous ppermute and then
+  reads the NEXT outgoing slab from the merged buffer (the extract must
+  observe the merge — a forwarded range can contain rows that just
+  arrived).
+* ``slab_merge_add_kernel`` / ``slab_step_reduce_kernel`` — the same
+  with the received rows ADDED into the buffer (reduce data plane),
+  folded through VMEM one row tile at a time.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .ref import fold_add
 
 
 def _kernel(idx_ref, x_ref, o_ref, *, block_rows: int):
@@ -115,54 +118,149 @@ def ragged_scatter_kernel(x: jax.Array, idx: jax.Array, n_out: int, *,
     )(idx, x)
 
 
-def _slab_extract_kernel(start_ref, buf_ref, o_ref, *, rows: int):
-    s0 = start_ref[0]
-    o_ref[...] = buf_ref[pl.ds(s0, rows), :]
+# --------------------------------------------------------------------------
+# slab kernels of the SPMD data plane
+# --------------------------------------------------------------------------
+#
+# The capacity buffer stays in HBM (``pl.ANY``) and is aliased from input
+# to output, so no step copies it whole: only slab rows move, by DMA.  The
+# TPU tiles the last two dimensions of an array, so a DMA may start only
+# at a multiple of 8 rows of a 2-D (N, F) buffer; the executor therefore
+# passes the row view (N, F // 128, 128) (``ops.row_view``), in which a
+# row is a major index and a DMA may start at any row.  A row count known
+# only at run time (the valid prefix of a received slab) moves as one
+# static-size DMA per set bit of the count.
+
+_ANY = pl.BlockSpec(memory_space=pl.ANY)
+_FOLD_TILE_BYTES = 1 << 20   # VMEM bytes per operand tile of the add kernels
+
+
+def _pow2_chunks(rows: int) -> list[int]:
+    """DMA sizes (descending powers of two) whose subsets sum to every
+    count in [0, rows]."""
+    return [1 << b for b in reversed(range(rows.bit_length()))]
+
+
+def _extract(src, s0, out, sem):
+    """out <- src[s0 : s0 + out.shape[0]]."""
+    copy = pltpu.make_async_copy(src.at[pl.ds(s0, out.shape[0])], out, sem)
+    copy.start()
+    copy.wait()
+
+
+def _copy_prefix(src, dst, d0, n, sems):
+    """dst[d0 : d0 + n] <- src[0 : n] for a traced n <= src rows: one DMA
+    per set bit of n, all started before any is waited on."""
+    copies = []
+    off = jnp.int32(0)
+    for i, size in enumerate(_pow2_chunks(src.shape[0])):
+        bit = n & size
+        copies.append((bit, pltpu.make_async_copy(
+            src.at[pl.ds(off, size)], dst.at[pl.ds(d0 + off, size)],
+            sems.at[i])))
+        off = off + bit
+    for bit, copy in copies:
+        pl.when(bit != 0)(copy.start)
+    for bit, copy in copies:
+        pl.when(bit != 0)(copy.wait)
+
+
+def _fold_prefix(src, dst, d0, n, cur, inc, sems):
+    """dst[d0 + i] += src[i] for i < n (traced), one VMEM tile of
+    ``cur.shape[0]`` rows at a time.  Rows >= n keep dst's bits: the mask
+    selects them unmodified (cur + 0 would flip -0.0 to +0.0)."""
+    rows, tile = src.shape[0], cur.shape[0]
+
+    def body(t, carry):
+        lo = t * tile
+        c0 = jnp.minimum(lo, rows - tile)  # the last tile slides back in
+        read_dst = pltpu.make_async_copy(dst.at[pl.ds(d0 + c0, tile)], cur,
+                                         sems.at[0])
+        read_src = pltpu.make_async_copy(src.at[pl.ds(c0, tile)], inc,
+                                         sems.at[1])
+        read_dst.start()
+        read_src.start()
+        read_dst.wait()
+        read_src.wait()
+        g = c0 + jax.lax.broadcasted_iota(jnp.int32, cur.shape, 0)
+        x = cur[...]
+        # rows a slid-back tile shares with the previous one are folded
+        cur[...] = jnp.where((g >= lo) & (g < n), fold_add(x, inc[...]), x)
+        write = pltpu.make_async_copy(cur, dst.at[pl.ds(d0 + c0, tile)],
+                                      sems.at[0])
+        write.start()
+        write.wait()
+        return carry
+
+    jax.lax.fori_loop(0, pl.cdiv(n, tile), body, 0)
+
+
+def _fold_scratch(buf, rows: int):
+    row_bytes = buf.dtype.itemsize * math.prod(buf.shape[1:])
+    tile = max(1, min(rows, _FOLD_TILE_BYTES // row_bytes))
+    shape = (tile,) + buf.shape[1:]
+    return [pltpu.VMEM(shape, buf.dtype), pltpu.VMEM(shape, buf.dtype),
+            pltpu.SemaphoreType.DMA((2,))]
+
+
+def _slab_call(body, scalars, tensors, out_shape, scratch, *,
+               in_place: bool, interpret: bool):
+    """One-program pallas_call over HBM operands.  ``in_place`` aliases
+    the first tensor (the buffer) to the first output."""
+    n_out = len(out_shape) if isinstance(out_shape, tuple) else 1
+    return pl.pallas_call(
+        body,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars), grid=(1,),
+            in_specs=[_ANY] * len(tensors),
+            out_specs=[_ANY] * n_out if n_out > 1 else _ANY,
+            scratch_shapes=scratch),
+        out_shape=out_shape,
+        input_output_aliases={len(scalars): 0} if in_place else {},
+        interpret=interpret,
+    )(*scalars, *tensors)
+
+
+def _slab_extract_kernel(start_ref, buf, out, sem):
+    _extract(buf, start_ref[0], out, sem)
 
 
 def slab_extract_kernel(buf: jax.Array, start: jax.Array, rows: int, *,
                         interpret: bool = False) -> jax.Array:
-    """Contiguous (rows, F) slab of ``buf`` at dynamic row ``start``.
-
-    ``start`` is a (1,) int32 array — typically a traced per-device value
-    inside ``shard_map`` — prefetched to SMEM before the copy runs.
-    """
-    f = buf.shape[1]
-    return pl.pallas_call(
-        functools.partial(_slab_extract_kernel, rows=rows),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,           # start lives in SMEM
-            grid=(1,),
-            in_specs=[pl.BlockSpec(buf.shape, lambda t, s: (0, 0))],
-            out_specs=pl.BlockSpec((rows, f), lambda t, s: (0, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((rows, f), buf.dtype),
-        interpret=interpret,
-    )(start, buf)
+    """The ``rows``-row slab of ``buf`` at dynamic row ``start``, a (1,)
+    int32 array (typically a traced per-device value inside
+    ``shard_map``) prefetched to SMEM."""
+    return _slab_call(
+        _slab_extract_kernel, (start,), (buf,),
+        jax.ShapeDtypeStruct((rows,) + buf.shape[1:], buf.dtype),
+        [pltpu.SemaphoreType.DMA(())], in_place=False, interpret=interpret)
 
 
-def _slab_merge_kernel(start_ref, valid_ref, buf_ref, slab_ref, o_ref, *,
-                       rows: int):
-    o_ref[...] = buf_ref[...]
-    s0 = start_ref[0]
-    nv = valid_ref[0]
-    cur = o_ref[pl.ds(s0, rows), :]
-    mask = (jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) < nv)
-    o_ref[pl.ds(s0, rows), :] = jnp.where(mask, slab_ref[...], cur)
+def _slab_merge_kernel(start_ref, valid_ref, buf_in, slab, buf, sems):
+    del buf_in  # aliased to buf
+    _copy_prefix(slab, buf, start_ref[0], valid_ref[0], sems)
 
 
-def _slab_step_kernel(recv_ref, valid_ref, send_ref, buf_ref, slab_ref,
-                      o_buf_ref, o_slab_ref, *, rows_in: int, rows_out: int):
-    o_buf_ref[...] = buf_ref[...]
-    r0 = recv_ref[0]
-    nv = valid_ref[0]
-    cur = o_buf_ref[pl.ds(r0, rows_in), :]
-    mask = (jax.lax.broadcasted_iota(jnp.int32, (rows_in, 1), 0) < nv)
-    o_buf_ref[pl.ds(r0, rows_in), :] = jnp.where(mask, slab_ref[...], cur)
+def slab_merge_kernel(buf: jax.Array, slab: jax.Array, start: jax.Array,
+                      valid: jax.Array, *,
+                      interpret: bool = False) -> jax.Array:
+    """Write the ``valid``-row prefix of ``slab`` into ``buf`` at dynamic
+    row ``start`` (rows >= valid keep buf's data), in place.  ``start``
+    and ``valid`` are (1,) int32 arrays (traced per-device values)."""
+    return _slab_call(
+        _slab_merge_kernel, (start, valid), (buf, slab),
+        jax.ShapeDtypeStruct(buf.shape, buf.dtype),
+        [pltpu.SemaphoreType.DMA((len(_pow2_chunks(slab.shape[0])),))],
+        in_place=True, interpret=interpret)
+
+
+def _slab_step_kernel(recv_ref, valid_ref, send_ref, buf_in, slab, buf, out,
+                      sems):
+    del buf_in  # aliased to buf
+    _copy_prefix(slab, buf, recv_ref[0], valid_ref[0], sems)
     # extract AFTER the merge landed: the outgoing slab may overlap the
     # range that was just received (tree forwarding)
-    s0 = send_ref[0]
-    o_slab_ref[...] = o_buf_ref[pl.ds(s0, rows_out), :]
+    _extract(buf, send_ref[0], out, sems.at[0])
 
 
 def slab_step_kernel(buf: jax.Array, slab: jax.Array, recv_start: jax.Array,
@@ -175,73 +273,40 @@ def slab_step_kernel(buf: jax.Array, slab: jax.Array, recv_start: jax.Array,
     ``rows_out``-row slab of the MERGED buffer at dynamic row
     ``send_start``.  All three scalars are (1,) int32 arrays (traced
     per-device values looked up from the step tables)."""
-    rows_in, f = slab.shape
-    return pl.pallas_call(
-        functools.partial(_slab_step_kernel, rows_in=rows_in,
-                          rows_out=rows_out),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,           # recv, valid, send live in SMEM
-            grid=(1,),
-            in_specs=[pl.BlockSpec(buf.shape, lambda t, r, v, s: (0, 0)),
-                      pl.BlockSpec((rows_in, f), lambda t, r, v, s: (0, 0))],
-            out_specs=[pl.BlockSpec(buf.shape, lambda t, r, v, s: (0, 0)),
-                       pl.BlockSpec((rows_out, f),
-                                    lambda t, r, v, s: (0, 0))],
-        ),
-        out_shape=(jax.ShapeDtypeStruct(buf.shape, buf.dtype),
-                   jax.ShapeDtypeStruct((rows_out, f), buf.dtype)),
-        interpret=interpret,
-    )(recv_start, recv_valid, send_start, buf, slab)
+    return _slab_call(
+        _slab_step_kernel, (recv_start, recv_valid, send_start), (buf, slab),
+        (jax.ShapeDtypeStruct(buf.shape, buf.dtype),
+         jax.ShapeDtypeStruct((rows_out,) + buf.shape[1:], buf.dtype)),
+        [pltpu.SemaphoreType.DMA((len(_pow2_chunks(slab.shape[0])),))],
+        in_place=True, interpret=interpret)
 
 
-def _slab_merge_add_kernel(start_ref, valid_ref, buf_ref, slab_ref, o_ref, *,
-                           rows: int):
-    o_ref[...] = buf_ref[...]
-    s0 = start_ref[0]
-    nv = valid_ref[0]
-    cur = o_ref[pl.ds(s0, rows), :]
-    mask = (jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) < nv)
-    # masked rows select cur outright (cur + 0 would flip -0.0 to +0.0)
-    o_ref[pl.ds(s0, rows), :] = jnp.where(mask, cur + slab_ref[...], cur)
+def _slab_merge_add_kernel(start_ref, valid_ref, buf_in, slab, buf, cur, inc,
+                           sems):
+    del buf_in  # aliased to buf
+    _fold_prefix(slab, buf, start_ref[0], valid_ref[0], cur, inc, sems)
 
 
 def slab_merge_add_kernel(buf: jax.Array, slab: jax.Array, start: jax.Array,
                           valid: jax.Array, *,
                           interpret: bool = False) -> jax.Array:
     """ADD the ``valid``-row prefix of ``slab`` into ``buf`` at dynamic
-    row ``start`` (rows >= valid keep buf's data bit-exactly: the mask
-    selects ``cur`` unmodified).  The reduction dual of
-    ``slab_merge_kernel``."""
-    rows, f = slab.shape
-    return pl.pallas_call(
-        functools.partial(_slab_merge_add_kernel, rows=rows),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,           # start, valid live in SMEM
-            grid=(1,),
-            in_specs=[pl.BlockSpec(buf.shape, lambda t, s, v: (0, 0)),
-                      pl.BlockSpec((rows, f), lambda t, s, v: (0, 0))],
-            out_specs=pl.BlockSpec(buf.shape, lambda t, s, v: (0, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct(buf.shape, buf.dtype),
-        interpret=interpret,
-    )(start, valid, buf, slab)
+    row ``start``, in place (rows >= valid keep buf's data bit-exactly).
+    The reduction dual of ``slab_merge_kernel``."""
+    return _slab_call(
+        _slab_merge_add_kernel, (start, valid), (buf, slab),
+        jax.ShapeDtypeStruct(buf.shape, buf.dtype),
+        _fold_scratch(buf, slab.shape[0]), in_place=True,
+        interpret=interpret)
 
 
-def _slab_step_reduce_kernel(recv_ref, valid_ref, send_ref, buf_ref,
-                             slab_ref, o_buf_ref, o_slab_ref, *,
-                             rows_in: int, rows_out: int):
-    o_buf_ref[...] = buf_ref[...]
-    r0 = recv_ref[0]
-    nv = valid_ref[0]
-    cur = o_buf_ref[pl.ds(r0, rows_in), :]
-    mask = (jax.lax.broadcasted_iota(jnp.int32, (rows_in, 1), 0) < nv)
-    # masked rows select cur outright (cur + 0 would flip -0.0 to +0.0)
-    o_buf_ref[pl.ds(r0, rows_in), :] = jnp.where(mask, cur + slab_ref[...],
-                                                 cur)
+def _slab_step_reduce_kernel(recv_ref, valid_ref, send_ref, buf_in, slab,
+                             buf, out, cur, inc, sems):
+    del buf_in  # aliased to buf
+    _fold_prefix(slab, buf, recv_ref[0], valid_ref[0], cur, inc, sems)
     # extract AFTER the fold landed: a root-ward forward carries the
     # partial sum including the contribution that just arrived
-    s0 = send_ref[0]
-    o_slab_ref[...] = o_buf_ref[pl.ds(s0, rows_out), :]
+    _extract(buf, send_ref[0], out, sems.at[0])
 
 
 def slab_step_reduce_kernel(buf: jax.Array, slab: jax.Array,
@@ -250,46 +315,14 @@ def slab_step_reduce_kernel(buf: jax.Array, slab: jax.Array,
                             interpret: bool = False
                             ) -> tuple[jax.Array, jax.Array]:
     """Fused reduce-dataplane step: ADD the ``recv_valid``-row prefix of
-    ``slab`` into ``buf`` at dynamic row ``recv_start`` (merge-received +
-    reduce-into-accumulator), and return ``(updated_buf, next_slab)``
-    where ``next_slab`` is the ``rows_out``-row slab of the UPDATED
-    buffer at dynamic row ``send_start`` (extract-next) — one kernel
-    launch and one buffer traversal per reduction step."""
-    rows_in, f = slab.shape
-    return pl.pallas_call(
-        functools.partial(_slab_step_reduce_kernel, rows_in=rows_in,
-                          rows_out=rows_out),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,           # recv, valid, send live in SMEM
-            grid=(1,),
-            in_specs=[pl.BlockSpec(buf.shape, lambda t, r, v, s: (0, 0)),
-                      pl.BlockSpec((rows_in, f), lambda t, r, v, s: (0, 0))],
-            out_specs=[pl.BlockSpec(buf.shape, lambda t, r, v, s: (0, 0)),
-                       pl.BlockSpec((rows_out, f),
-                                    lambda t, r, v, s: (0, 0))],
-        ),
-        out_shape=(jax.ShapeDtypeStruct(buf.shape, buf.dtype),
-                   jax.ShapeDtypeStruct((rows_out, f), buf.dtype)),
-        interpret=interpret,
-    )(recv_start, recv_valid, send_start, buf, slab)
-
-
-def slab_merge_kernel(buf: jax.Array, slab: jax.Array, start: jax.Array,
-                      valid: jax.Array, *,
-                      interpret: bool = False) -> jax.Array:
-    """Merge the ``valid``-row prefix of ``slab`` into ``buf`` at dynamic
-    row ``start`` (rows >= valid keep buf's data).  ``start`` and
-    ``valid`` are (1,) int32 arrays (traced per-device values)."""
-    rows, f = slab.shape
-    return pl.pallas_call(
-        functools.partial(_slab_merge_kernel, rows=rows),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,           # start, valid live in SMEM
-            grid=(1,),
-            in_specs=[pl.BlockSpec(buf.shape, lambda t, s, v: (0, 0)),
-                      pl.BlockSpec((rows, f), lambda t, s, v: (0, 0))],
-            out_specs=pl.BlockSpec(buf.shape, lambda t, s, v: (0, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct(buf.shape, buf.dtype),
-        interpret=interpret,
-    )(start, valid, buf, slab)
+    ``slab`` into ``buf`` at dynamic row ``recv_start``, and return
+    ``(updated_buf, next_slab)`` where ``next_slab`` is the
+    ``rows_out``-row slab of the UPDATED buffer at dynamic row
+    ``send_start``."""
+    return _slab_call(
+        _slab_step_reduce_kernel, (recv_start, recv_valid, send_start),
+        (buf, slab),
+        (jax.ShapeDtypeStruct(buf.shape, buf.dtype),
+         jax.ShapeDtypeStruct((rows_out,) + buf.shape[1:], buf.dtype)),
+        _fold_scratch(buf, slab.shape[0]), in_place=True,
+        interpret=interpret)

@@ -1,6 +1,9 @@
-"""Jit'd wrappers for the ragged pack/unpack/slab kernels (gatherv pack,
+"""Wrappers for the ragged pack/unpack/slab kernels (gatherv pack,
 scatterv unpack, per-ppermute slab copies, MoE dispatch).
-interpret=True on CPU; compiled Pallas on TPU."""
+
+Every wrapper compiles its kernel for the TPU unless the caller passes
+``interpret=True``, which the CPU tests do; nothing here asks which
+device is present."""
 from __future__ import annotations
 
 import functools
@@ -15,14 +18,18 @@ from .kernel import (ragged_gather_kernel, ragged_scatter_kernel,
 from .ref import build_pack_index
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def row_view(buf):
+    """The (N, F // 128, 128) view of an (N, F) buffer, or (N, 1, F) when
+    F is not a multiple of 128.  The TPU tiles an array's last two
+    dimensions, so only in this view can the slab kernels' DMAs start at
+    any row; the executor hands them this view."""
+    n, f = buf.shape
+    lanes = 128 if f % 128 == 0 else f
+    return buf.reshape(n, f // lanes, lanes)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def ragged_gather(x, idx, *, block_rows: int = 128, interpret: bool | None = None):
-    if interpret is None:
-        interpret = not _on_tpu()
+def ragged_gather(x, idx, *, block_rows: int = 128, interpret: bool = False):
     pad = (-idx.shape[0]) % block_rows
     idx_p = jnp.pad(idx, (0, pad))
     out = ragged_gather_kernel(x, idx_p, block_rows=block_rows,
@@ -33,7 +40,7 @@ def ragged_gather(x, idx, *, block_rows: int = 128, interpret: bool | None = Non
 @functools.partial(jax.jit, static_argnames=("total_pad", "block_rows",
                                              "interpret"))
 def pack_blocks(blocks, sizes, total_pad: int, *, block_rows: int = 128,
-                interpret: bool | None = None):
+                interpret: bool = False):
     """Pack padded (N, cap, F) blocks into (total_pad, F) rank order —
     the paper's zero-copy send-buffer consolidation on TPU."""
     n, cap, f = blocks.shape
@@ -47,12 +54,10 @@ def pack_blocks(blocks, sizes, total_pad: int, *, block_rows: int = 128,
 @functools.partial(jax.jit, static_argnames=("n_out", "block_rows",
                                              "interpret"))
 def ragged_scatter(x, idx, n_out: int, *, block_rows: int = 128,
-                   interpret: bool | None = None):
+                   interpret: bool = False):
     """out[idx[i]] = x[i] over a zero (n_out, F) buffer — the unpack dual
     of :func:`ragged_gather`.  Rows whose idx is out of [0, n_out) are
     dropped onto an internal trash row."""
-    if interpret is None:
-        interpret = not _on_tpu()
     pad = (-idx.shape[0]) % block_rows
     idx_p = jnp.pad(idx, (0, pad), constant_values=n_out)
     # out-of-range destinations -> internal trash row n_out (sliced off)
@@ -67,7 +72,7 @@ def ragged_scatter(x, idx, n_out: int, *, block_rows: int = 128,
 @functools.partial(jax.jit, static_argnames=("cap", "block_rows",
                                              "interpret"))
 def unpack_blocks(packed, sizes, cap: int, *, block_rows: int = 128,
-                  interpret: bool | None = None):
+                  interpret: bool = False):
     """Unpack a contiguous (total_pad, F) rank-ordered buffer into padded
     (N, cap, F) blocks — the scatterv-side inverse of
     :func:`pack_blocks`, reusing the SAME index map: pack reads flat row
@@ -81,67 +86,53 @@ def unpack_blocks(packed, sizes, cap: int, *, block_rows: int = 128,
     return flat[: n * cap].reshape(n, cap, f)
 
 
-def slab_extract(buf, start, rows: int, *, interpret: bool | None = None):
-    """Contiguous (rows, F) slab of ``buf`` at traced row ``start`` via the
-    Pallas copy kernel (data-plane send-side).  NOT jit-wrapped: it is
-    called inside ``shard_map`` bodies that are already traced."""
-    if interpret is None:
-        interpret = not _on_tpu()
-    s = jnp.asarray(start, jnp.int32).reshape(1)
-    return slab_extract_kernel(buf, s, rows, interpret=interpret)
+def _scalar(v):
+    return jnp.asarray(v, jnp.int32).reshape(1)
 
 
-def slab_merge(buf, slab, start, valid, *, interpret: bool | None = None):
-    """Merge the ``valid``-row prefix of ``slab`` into ``buf`` at traced
-    row ``start`` via the Pallas copy kernel (data-plane receive-side)."""
-    if interpret is None:
-        interpret = not _on_tpu()
-    s = jnp.asarray(start, jnp.int32).reshape(1)
-    v = jnp.asarray(valid, jnp.int32).reshape(1)
-    return slab_merge_kernel(buf, slab, s, v, interpret=interpret)
+# The slab wrappers are NOT jit-wrapped: they are called inside traced
+# ``shard_map`` bodies.  ``buf`` is (N, ...) with rows on axis 0 — the
+# ``row_view`` at TPU widths — and each result matches its
+# ``ref.*_ref`` oracle bitwise (differentially tested).
+
+def slab_extract(buf, start, rows: int, *, interpret: bool = False):
+    """Contiguous ``rows``-row slab of ``buf`` at traced row ``start``
+    (data-plane send side)."""
+    return slab_extract_kernel(buf, _scalar(start), rows, interpret=interpret)
+
+
+def slab_merge(buf, slab, start, valid, *, interpret: bool = False):
+    """Write the ``valid``-row prefix of ``slab`` into ``buf`` at traced
+    row ``start`` (data-plane receive side)."""
+    return slab_merge_kernel(buf, slab, _scalar(start), _scalar(valid),
+                             interpret=interpret)
 
 
 def slab_step(buf, got, recv_start, recv_valid, send_start, rows_out: int, *,
-              interpret: bool | None = None):
-    """Fused dataplane step via one Pallas invocation: merge the received
-    slab ``got`` at traced row ``recv_start`` (``recv_valid`` live rows),
-    then extract the next ``rows_out``-row outgoing slab of the MERGED
-    buffer at traced row ``send_start``.  Returns ``(buf, next_slab)``.
-    Matches ``ref.slab_step_ref`` row-identically (differentially
-    tested).  NOT jit-wrapped: called inside traced ``shard_map`` bodies.
-    """
-    if interpret is None:
-        interpret = not _on_tpu()
-    r = jnp.asarray(recv_start, jnp.int32).reshape(1)
-    v = jnp.asarray(recv_valid, jnp.int32).reshape(1)
-    s = jnp.asarray(send_start, jnp.int32).reshape(1)
-    return slab_step_kernel(buf, got, r, v, s, rows_out,
-                            interpret=interpret)
+              interpret: bool = False):
+    """Fused step: merge the received slab ``got`` at traced row
+    ``recv_start`` (``recv_valid`` live rows), then extract the next
+    ``rows_out``-row outgoing slab of the MERGED buffer at traced row
+    ``send_start``.  Returns ``(buf, next_slab)``."""
+    return slab_step_kernel(buf, got, _scalar(recv_start),
+                            _scalar(recv_valid), _scalar(send_start),
+                            rows_out, interpret=interpret)
 
 
-def slab_merge_add(buf, slab, start, valid, *, interpret: bool | None = None):
+def slab_merge_add(buf, slab, start, valid, *, interpret: bool = False):
     """ADD the ``valid``-row prefix of ``slab`` into ``buf`` at traced row
-    ``start`` via the Pallas kernel (reduce-dataplane receive-side)."""
-    if interpret is None:
-        interpret = not _on_tpu()
-    s = jnp.asarray(start, jnp.int32).reshape(1)
-    v = jnp.asarray(valid, jnp.int32).reshape(1)
-    return slab_merge_add_kernel(buf, slab, s, v, interpret=interpret)
+    ``start`` (reduce data plane, receive side)."""
+    return slab_merge_add_kernel(buf, slab, _scalar(start), _scalar(valid),
+                                 interpret=interpret)
 
 
 def slab_step_reduce(buf, got, recv_start, recv_valid, send_start,
-                     rows_out: int, *, interpret: bool | None = None):
-    """Fused reduce-dataplane step via one Pallas invocation: fold the
-    received slab ``got`` into the accumulator at traced row
-    ``recv_start`` (``recv_valid`` live rows, ADD not overwrite), then
-    extract the next ``rows_out``-row outgoing partial sum of the UPDATED
-    buffer at traced row ``send_start``.  Returns ``(buf, next_slab)``.
-    Matches ``ref.slab_step_reduce_ref`` bitwise (differentially tested).
-    NOT jit-wrapped: called inside traced ``shard_map`` bodies."""
-    if interpret is None:
-        interpret = not _on_tpu()
-    r = jnp.asarray(recv_start, jnp.int32).reshape(1)
-    v = jnp.asarray(recv_valid, jnp.int32).reshape(1)
-    s = jnp.asarray(send_start, jnp.int32).reshape(1)
-    return slab_step_reduce_kernel(buf, got, r, v, s, rows_out,
-                                   interpret=interpret)
+                     rows_out: int, *, interpret: bool = False):
+    """Fused reduce step: fold the received slab ``got`` into the
+    accumulator at traced row ``recv_start`` (``recv_valid`` live rows,
+    ADD not overwrite), then extract the next ``rows_out``-row outgoing
+    partial sum of the UPDATED buffer at traced row ``send_start``.
+    Returns ``(buf, next_slab)``."""
+    return slab_step_reduce_kernel(buf, got, _scalar(recv_start),
+                                   _scalar(recv_valid), _scalar(send_start),
+                                   rows_out, interpret=interpret)

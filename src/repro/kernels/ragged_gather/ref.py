@@ -5,6 +5,17 @@ import jax
 import jax.numpy as jnp
 
 
+def fold_add(cur, inc):
+    """``cur + inc`` in ``cur``'s dtype.  Floats narrower than 32 bits
+    are summed in float32 and rounded once, as the TPU's vector unit,
+    XLA and ml_dtypes' NumPy arrays all do, so the kernels, this
+    reference and the NumPy oracle round every fold identically."""
+    if jnp.issubdtype(cur.dtype, jnp.floating) and cur.dtype.itemsize < 4:
+        return (cur.astype(jnp.float32) + inc.astype(jnp.float32)
+                ).astype(cur.dtype)
+    return cur + inc
+
+
 def ragged_gather_ref(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     """out[i] = x[idx[i]].  idx rows out of range read row 0 (callers use a
     zero row-0 sentinel for padding)."""
@@ -72,8 +83,8 @@ def slab_merge_add_ref(buf: jnp.ndarray, slab: jnp.ndarray, start,
                                 (rows, buf.shape[1]))
     mask = (jnp.arange(rows, dtype=jnp.int32) < valid)[:, None]
     # masked rows select cur outright (cur + 0 would flip -0.0 to +0.0)
-    return jax.lax.dynamic_update_slice(buf, jnp.where(mask, cur + slab, cur),
-                                        (start, jnp.int32(0)))
+    return jax.lax.dynamic_update_slice(
+        buf, jnp.where(mask, fold_add(cur, slab), cur), (start, jnp.int32(0)))
 
 
 def slab_step_reduce_ref(buf: jnp.ndarray, got: jnp.ndarray, recv_start,

@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
-
 
 def _kernel(a_ref, b_ref, h0_ref, o_ref, hlast_ref, carry_ref, *,
             chunk: int, n_chunks: int):
@@ -71,7 +69,7 @@ def rglru_scan_kernel(a, b, h0, *, block_b: int = 8, block_d: int = 128,
             jax.ShapeDtypeStruct((B, D), a.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((block_b, block_d), jnp.float32)],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, h0)

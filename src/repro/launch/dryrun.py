@@ -28,8 +28,9 @@ import jax
 
 from repro.analysis import collective_bytes_from_hlo
 from repro.analysis.hloflow import analyze_hlo
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCH_IDS, SHAPES, get_config, shape_applicable
-from repro.launch.mesh import as_shardings, make_production_mesh, mesh_context
+from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import build_cell
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
@@ -64,12 +65,12 @@ def run_cell(arch: str, shape: str, mesh_kind: str, force: bool = False,
            "ok": False}
     t0 = time.time()
     try:
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             step, args, in_specs, out_specs, donate, meta = build_cell(
                 arch, shape, mesh, variant=variant)
             rec.update(meta)
-            jitted = jax.jit(step, in_shardings=as_shardings(mesh, in_specs),
-                             out_shardings=as_shardings(mesh, out_specs),
+            jitted = jax.jit(step, in_shardings=in_specs,
+                             out_shardings=out_specs,
                              donate_argnums=donate)
             t1 = time.time()
             lowered = jitted.lower(*args)
@@ -133,6 +134,7 @@ def iter_cells(archs=None, shapes=None, meshes=None):
 
 
 def main() -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", action="append")
     ap.add_argument("--shape", action="append")

@@ -14,34 +14,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     return jax.make_mesh(shape, axes)
 
 
-def mesh_context(mesh):
-    """Context manager installing ``mesh`` as the ambient sharding context.
-
-    ``jax.set_mesh`` on new jax; older jax uses the Mesh object itself
-    (the legacy thread-resources context manager).
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
 def dp_axes(mesh) -> tuple[str, ...]:
     """Batch/FSDP axes: everything that is not tensor-parallel."""
     return tuple(a for a in mesh.axis_names if a != "model")
 
-
-def as_shardings(mesh, spec_tree):
-    """PartitionSpec pytree -> jit-able shardings for the installed jax.
-
-    New jax accepts raw PartitionSpecs in in/out_shardings under
-    ``jax.set_mesh``; older jax requires concrete ``NamedSharding``s.
-    """
-    if hasattr(jax, "set_mesh"):
-        return spec_tree
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    return jax.tree.map(
-        lambda s: NamedSharding(mesh, s) if isinstance(s, PartitionSpec)
-        else s,
-        spec_tree,
-        is_leaf=lambda s: isinstance(s, PartitionSpec))

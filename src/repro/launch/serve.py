@@ -24,6 +24,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.models import init_cache, init_params
 from repro.obs import trace as obs_trace
@@ -66,6 +67,7 @@ def route_step(tokens: np.ndarray, experts: int, top_k: int,
 
 
 def main() -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="recurrentgemma-2b")
     ap.add_argument("--reduced", action="store_true")

@@ -17,6 +17,7 @@ import time
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.data import SyntheticLM
 from repro.optim import AdamWConfig
@@ -25,6 +26,7 @@ from repro.train import init_train_state, make_train_step
 
 
 def main() -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="xlstm-125m")
     ap.add_argument("--steps", type=int, default=300)

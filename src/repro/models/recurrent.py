@@ -2,7 +2,7 @@
 (xLSTM).  Training paths are TPU-adapted: RG-LRU uses an associative scan
 (log-depth), mLSTM uses its parallel stabilized attention form, sLSTM is a
 true recurrence (lax.scan) — the xLSTM paper uses a custom CUDA kernel
-there; on TPU the sequential scan is the honest equivalent (DESIGN.md §9).
+there; on TPU the sequential scan is the honest equivalent.
 """
 from __future__ import annotations
 

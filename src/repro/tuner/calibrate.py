@@ -292,8 +292,6 @@ class MeshTimingBackend:
         import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from repro.compat import shard_map
-
         rows = max(1, nbytes // 4)  # float32 rows of width 1
 
         def body(x):
@@ -302,8 +300,9 @@ class MeshTimingBackend:
                 x = jax.lax.ppermute(x, self.axis, perm)
             return x
 
-        fn = jax.jit(shard_map(body, mesh=self.mesh,
-                               in_specs=P(self.axis), out_specs=P(self.axis)))
+        fn = jax.jit(jax.shard_map(body, mesh=self.mesh,
+                                   in_specs=P(self.axis),
+                                   out_specs=P(self.axis)))
         x = jax.device_put(
             jnp.zeros((self._p * rows, 1), jnp.float32),
             NamedSharding(self.mesh, P(self.axis)))

@@ -401,11 +401,10 @@ class PlannerService:
         import jax
         from jax.sharding import PartitionSpec as P
 
-        from repro.compat import shard_map_unchecked
         from repro.core import jax_collectives as jc
 
         plan = rec.plan
-        ckey = (rec.serial, kind, F, dtype_str)
+        ckey = (rec.serial, kind, F, dtype_str, jc.dataplane())
         fn = self._compiled.get(ckey)
         if fn is not None:
             self._compiled.move_to_end(ckey)
@@ -421,9 +420,10 @@ class PlannerService:
                 "alltoallv": jc.alltoallv_shard,
                 "reduce_scatterv": jc.reduce_scatterv_shard,
                 "allreducev": jc.allreducev_shard}[kind]
-        fn = jax.jit(shard_map_unchecked(
+        fn = jax.jit(jax.shard_map(
             lambda xl: body(xl, plan, self.axis),
-            mesh=self.mesh, in_specs=P(self.axis), out_specs=P(self.axis)))
+            mesh=self.mesh, in_specs=P(self.axis), out_specs=P(self.axis),
+            check_vma=False))
         self._compiled[ckey] = fn
         while len(self._compiled) > self.max_compiled:
             self._compiled.popitem(last=False)

@@ -12,6 +12,7 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import numpy as np
 
 import jax
+from jax.sharding import AxisType
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -19,7 +20,7 @@ from repro.core import build_gather_tree
 from repro.core.distributions import NAMES, block_sizes
 from repro.core.jax_collectives import (
     RaggedGathervPlanner, gatherv_shard, plan_gatherv, run_gatherv,
-    run_scatterv, shard_map, tree_metadata_exchange,
+    run_scatterv, set_dataplane, tree_metadata_exchange,
 )
 from repro.analysis import collective_bytes_from_hlo
 
@@ -27,7 +28,7 @@ PP = 8
 
 
 def mesh1d():
-    return jax.make_mesh((PP,), ("x",))
+    return jax.make_mesh((PP,), ("x",), axis_types=(AxisType.Auto,))
 
 
 def rand_blocks(sizes, F, rng, dtype=np.float32):
@@ -100,7 +101,7 @@ def check_metadata_exchange():
             def body(ml):
                 est, groot, total = tree_metadata_exchange(ml[0], "x", PP)
                 return est[None], groot[None], total[None]
-            return shard_map(
+            return jax.shard_map(
                 body, mesh=mesh, in_specs=P("x"), out_specs=P("x"))(m)
 
         m = jax.device_put(np.asarray(sizes, np.int32),
@@ -131,7 +132,7 @@ def check_hlo_collectives():
     mesh = mesh1d()
     sizes = block_sizes("decreasing", PP, 64, seed=4)
     plan = plan_gatherv(sizes, 3)
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         lambda xl: gatherv_shard(xl, plan, "x"),
         mesh=mesh, in_specs=P("x"), out_specs=P("x")))
     x = jnp.zeros((plan.p * plan.cap, 4), jnp.float32)
@@ -144,6 +145,7 @@ def check_hlo_collectives():
 
 if __name__ == "__main__":
     assert jax.device_count() == PP, jax.devices()
+    set_dataplane("xla")  # CPU devices: the jnp slab reference
     check_gatherv_oracle()
     check_gatherv_bucketing()
     check_scatterv_oracle()

@@ -11,18 +11,19 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import numpy as np
 
 import jax
+from jax.sharding import AxisType
 
 from repro.core.composed import independent_scatter_bytes
 from repro.core.distributions import NAMES, block_sizes
 from repro.core.jax_collectives import (
-    plan_alltoallv, run_allgatherv, run_alltoallv,
+    plan_alltoallv, run_allgatherv, run_alltoallv, set_dataplane,
 )
 
 PP = 8
 
 
 def mesh1d():
-    return jax.make_mesh((PP,), ("x",))
+    return jax.make_mesh((PP,), ("x",), axis_types=(AxisType.Auto,))
 
 
 def check_allgatherv_oracle():
@@ -109,13 +110,13 @@ def check_plan_vs_hlo_step_count():
     from jax.sharding import NamedSharding, PartitionSpec as P
     import jax.numpy as jnp
     from repro.analysis import collective_bytes_from_hlo
-    from repro.core.jax_collectives import alltoallv_shard, shard_map
+    from repro.core.jax_collectives import alltoallv_shard
 
     mesh = mesh1d()
     rng = np.random.default_rng(5)
     S = rng.integers(1, 9, (PP, PP))
     plan = plan_alltoallv(S)
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         lambda xl: alltoallv_shard(xl, plan, "x"),
         mesh=mesh, in_specs=P("x"), out_specs=P("x")))
     x = jnp.zeros((PP * plan.cap, 4), jnp.float32)
@@ -128,6 +129,7 @@ def check_plan_vs_hlo_step_count():
 
 if __name__ == "__main__":
     assert jax.device_count() == PP, jax.devices()
+    set_dataplane("xla")  # CPU devices: the jnp slab reference
     check_allgatherv_oracle()
     check_alltoallv_oracle()
     check_alltoallv_bucketing()

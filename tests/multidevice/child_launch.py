@@ -8,7 +8,6 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import jax
 
 from repro.analysis.hloflow import analyze_hlo
-from repro.launch.mesh import as_shardings, mesh_context
 from repro.launch.specs import build_cell
 
 mesh = jax.make_mesh((4, 2), ("data", "model"))
@@ -18,11 +17,11 @@ for arch, shape, variant in [
     ("xlstm-125m", "long_500k", "baseline"),
     ("recurrentgemma-2b", "decode_32k", "kv_int8"),
 ]:
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         step, args, in_specs, out_specs, donate, meta = build_cell(
             arch, shape, mesh, variant=variant)
-        compiled = jax.jit(step, in_shardings=as_shardings(mesh, in_specs),
-                           out_shardings=as_shardings(mesh, out_specs),
+        compiled = jax.jit(step, in_shardings=in_specs,
+                           out_shardings=out_specs,
                            donate_argnums=donate).lower(*args).compile()
     ma = compiled.memory_analysis()
     flow = analyze_hlo(compiled.as_text())

@@ -45,13 +45,14 @@ except Exception as e:  # pragma: no cover - environment-dependent
 import numpy as np  # noqa: E402
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
 
-from repro.compat import shard_map_unchecked  # noqa: E402
 from repro.core import jax_collectives as jc  # noqa: E402
 from repro.core.baselines import two_level_tree  # noqa: E402
 from repro.core.composed import alltoallv_schedule  # noqa: E402
 from repro.core.costmodel import (CostParams, HierarchicalCostParams,  # noqa: E402
                                   HostTopology)
 from repro.tuner import PlannerService, mesh_fingerprint  # noqa: E402
+
+jc.set_dataplane("xla")  # CPU devices: the jnp slab reference
 
 AXIS = ("host", "device")  # tuple axis: flattened host-major by JAX
 PP = NUM_PROCESSES * DEVICES_PER_PROCESS
@@ -70,8 +71,9 @@ def global_array(mesh, full: np.ndarray):
 
 
 def run_body(mesh, body, full_in: np.ndarray):
-    fn = jax.jit(shard_map_unchecked(
-        body, mesh=mesh, in_specs=P(AXIS), out_specs=P(AXIS)))
+    fn = jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=P(AXIS), out_specs=P(AXIS),
+        check_vma=False))
     out = fn(global_array(mesh, full_in))
     rows = out.shape[0] // PP
     shards = {}

@@ -11,6 +11,7 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import numpy as np
 
 import jax
+from jax.sharding import AxisType
 
 from repro.core import jax_collectives as jc
 from repro.core.distributions import block_sizes
@@ -19,7 +20,7 @@ PP = 8
 
 
 def mesh1d():
-    return jax.make_mesh((PP,), ("x",))
+    return jax.make_mesh((PP,), ("x",), axis_types=(AxisType.Auto,))
 
 
 def check_pipelined_equals_monolithic():
@@ -73,15 +74,25 @@ def check_pipelined_equals_monolithic():
 
 
 def check_pallas_slab_backend():
-    """Force the Pallas slab kernels (interpret mode on CPU) through the
-    full shard_map data plane and compare against the jnp backend."""
+    """Run the Pallas slab kernels (interpret mode on CPU) through the
+    full shard_map data plane of all six ops and compare against the jnp
+    backend."""
     mesh = mesh1d()
     rng = np.random.default_rng(1)
     sizes = block_sizes("random", PP, 15, seed=5)
     blocks = [rng.standard_normal((s, 4)).astype(np.float32) for s in sizes]
     want = np.concatenate(blocks, axis=0)
+    S_mat = rng.integers(0, 6, (PP, PP))
+    ab = [[rng.standard_normal((int(S_mat[i][j]), 4)).astype(np.float32)
+           for j in range(PP)] for i in range(PP)]
+    contribs = [rng.standard_normal((sum(sizes), 4)).astype(np.float32)
+                for _ in range(PP)]
+    jc.set_dataplane("xla")
+    t_ref, _ = jc.run_alltoallv(mesh, "x", ab)
+    rs_ref, _ = jc.run_reduce_scatterv(mesh, "x", contribs, sizes)
+    ar_ref, _ = jc.run_allreducev(mesh, "x", contribs, sizes)
     try:
-        jc.use_pallas_dataplane(True)
+        jc.set_dataplane("interpret")
         for S in (1, 3):
             out, _ = jc.run_gatherv(mesh, "x", blocks, root=0, segments=S)
             np.testing.assert_array_equal(out, want)
@@ -92,9 +103,18 @@ def check_pallas_slab_backend():
             ag, _ = jc.run_allgatherv(mesh, "x", blocks, segments=S)
             for j in range(PP):
                 np.testing.assert_array_equal(ag[j], want)
+        t, _ = jc.run_alltoallv(mesh, "x", ab)
+        for a, b in zip(t, t_ref):
+            np.testing.assert_array_equal(a, b)
+        rs, _ = jc.run_reduce_scatterv(mesh, "x", contribs, sizes)
+        for a, b in zip(rs, rs_ref):
+            np.testing.assert_array_equal(a, b)
+        ar, _ = jc.run_allreducev(mesh, "x", contribs, sizes)
+        np.testing.assert_array_equal(ar, ar_ref)
     finally:
-        jc.use_pallas_dataplane(None)
-    print("pallas slab backend OK (gatherv/scatterv/allgatherv, S in {1,3})")
+        jc.set_dataplane("xla")
+    print("pallas slab backend OK (six ops; gatherv/scatterv/allgatherv "
+          "at S in {1,3})")
 
 
 def check_pipelined_hlo_payloads_shrink():
@@ -113,6 +133,7 @@ def check_pipelined_hlo_payloads_shrink():
 
 if __name__ == "__main__":
     assert jax.device_count() == PP, jax.devices()
+    jc.set_dataplane("xla")  # CPU devices: the jnp slab reference
     check_pipelined_equals_monolithic()
     check_pallas_slab_backend()
     check_pipelined_hlo_payloads_shrink()

@@ -11,18 +11,20 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import numpy as np
 
 import jax
+from jax.sharding import AxisType
 
 from repro.core.composed import (
     reduce_scatterv_direct_schedule, reduce_scatterv_halving_schedule,
 )
 from repro.core.distributions import NAMES, block_sizes
-from repro.core.jax_collectives import run_allreducev, run_reduce_scatterv
+from repro.core.jax_collectives import (run_allreducev, run_reduce_scatterv,
+                                        set_dataplane)
 
 PP = 8
 
 
 def mesh1d():
-    return jax.make_mesh((PP,), ("x",))
+    return jax.make_mesh((PP,), ("x",), axis_types=(AxisType.Auto,))
 
 
 def _contribs(rng, total, F=3):
@@ -123,6 +125,7 @@ def check_service_execution():
 
 if __name__ == "__main__":
     assert jax.device_count() == PP, jax.devices()
+    set_dataplane("xla")  # CPU devices: the jnp slab reference
     check_reduce_scatterv_oracle()
     check_schedule_variants_agree()
     check_bitwise_repeatable()

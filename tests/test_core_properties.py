@@ -1,6 +1,6 @@
 """Property tests for the paper's core claims (Lemmas 1-3, Theorem 1).
 
-Each hypothesis property maps to a paper statement; see DESIGN.md §8.
+Each hypothesis property maps to a paper statement.
 """
 import math
 

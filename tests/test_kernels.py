@@ -199,6 +199,47 @@ def test_slab_step_traced_offsets_under_jit():
     np.testing.assert_array_equal(np.asarray(nxt), want[0:3])
 
 
+# ------------------------------------------- row view at MoE widths (DMA)
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("op", ["slab_extract", "slab_merge", "slab_step",
+                                "slab_merge_add", "slab_step_reduce"])
+def test_slab_ops_row_view_match_refs(op, dtype):
+    """The layout the executor hands the kernels: the (N, F/128, 128)
+    row view at F=2048, odd row offsets, and slabs longer than one
+    1 MiB fold tile (several tiles, the last one slid back), so the
+    add kernels' tiling and masking run exactly as on the chip."""
+    from repro.kernels.ragged_gather import ops, ref
+
+    F, buf_rows, rows = 2048, 700, 301
+    rng = np.random.default_rng(F + buf_rows)
+    buf = jnp.asarray(rng.standard_normal((buf_rows, F)), dtype)
+    slab = jnp.asarray(rng.standard_normal((rows, F)), dtype)
+    start, valid, send = 37, 299, 211
+    view = ops.row_view
+    assert view(buf).shape == (buf_rows, 16, 128)
+    args = {"slab_extract": (start, rows),
+            "slab_merge": (start, valid),
+            "slab_merge_add": (start, valid),
+            "slab_step": (start, valid, send, 233),
+            "slab_step_reduce": (start, valid, send, 233)}[op]
+    pre = (buf,) if op == "slab_extract" else (buf, slab)
+    got = getattr(ops, op)(*map(view, pre), *args, interpret=True)
+    want = getattr(ref, op + "_ref")(*pre, *args)
+    got = got if isinstance(got, tuple) else (got,)
+    want = want if isinstance(want, tuple) else (want,)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g).reshape(w.shape),
+                                      np.asarray(w))
+
+
+def test_row_view_layout():
+    from repro.kernels.ragged_gather.ops import row_view
+
+    assert row_view(jnp.zeros((5, 4096))).shape == (5, 32, 128)
+    assert row_view(jnp.zeros((5, 12))).shape == (5, 1, 12)
+
+
 # ---------------------------------------------------------- flash attention
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

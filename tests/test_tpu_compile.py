@@ -1,0 +1,157 @@
+"""Compile the slab data plane for a described TPU v5e; no chip needed.
+
+The TPU compiler ships with JAX: ``topologies.get_topology_desc``
+describes a v5e 2x2 host that is not attached, and
+``jit(...).lower(...).compile()`` then refuses what the chip's compiler
+would refuse — slices not aligned to the tiling, blocks larger than
+VMEM, programs larger than HBM — none of which interpret mode sees.
+The shapes are the real ones: the rows, payloads and offsets of a
+4-rank dispatch plan at the row widths of mixtral-8x7b (4096) and
+deepseek-moe-16b (2048), in bf16.
+
+The topology is described in a module-scoped fixture, never at import:
+only one process at a time may load the TPU library, and under
+pytest-xdist every worker imports this file.
+"""
+import os
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+import chip_smoke
+from repro.core import jax_collectives as jc
+from repro.kernels.ragged_gather import ops
+
+HBM_BYTES = 16 * 2**30          # one v5e chip
+WIDTHS = {"mixtral-8x7b": 4096, "deepseek-moe-16b": 2048}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # a compile for a described chip cannot be read back from the cache
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield topologies.get_topology_desc(platform="tpu",
+                                           topology_name="v5e:2x2")
+    except Exception as e:  # pragma: no cover - depends on the install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_on)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    return Mesh(np.asarray(topo.devices).reshape(4), ("x",))
+
+
+@pytest.fixture(scope="module")
+def pallas():
+    prev = jc.dataplane()
+    jc.set_dataplane("pallas")
+    yield
+    jc.set_dataplane(prev)
+
+
+def _plan(model: str, op: str):
+    """The plan the smoke run executes for ``op``: the alltoallv of the
+    dispatch matrix, the others over the rows each expert chip holds."""
+    S = chip_smoke.dispatch_matrix(model, 4, chip_smoke.TOKENS_PER_CHIP)
+    sizes = S.sum(axis=0)
+    return {"gatherv": lambda: jc.plan_gatherv(sizes, 0),
+            "scatterv": lambda: jc.plan_gatherv(sizes, 0),
+            "allgatherv": lambda: jc.plan_allgatherv(sizes),
+            "alltoallv": lambda: jc.plan_alltoallv(S),
+            "reduce_scatterv": lambda: jc.plan_reduce_scatterv(sizes),
+            "allreducev": lambda: jc.plan_allreducev(sizes)}[op]()
+
+
+def _fits(compiled) -> int:
+    ma = compiled.memory_analysis()
+    used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert used <= HBM_BYTES, used
+    return used
+
+
+@pytest.mark.parametrize("F", sorted(WIDTHS.values()))
+@pytest.mark.parametrize("op", ["slab_extract", "slab_merge", "slab_step",
+                                "slab_merge_add", "slab_step_reduce"])
+def test_slab_kernel_compiles_at_moe_width(op, F, one_chip, pallas):
+    """Each slab kernel at the buffer, payloads and offsets of the first
+    transfer of the mixtral dispatch plan whose send and receive rows
+    are both unaligned."""
+    plan = _plan("mixtral-8x7b", "alltoallv")
+    k, src, dst = next((k, s, d) for k, step in enumerate(plan.steps[:-1])
+                       for s, d in step[0]
+                       if step[2][s] % 8 and step[3][d] % 8)
+    (perm, payload, send, recv, valid), nxt = plan.steps[k:k + 2]
+    row = ops.row_view(jnp.zeros((1, F))).shape[1:]
+
+    def shape(rows):
+        return jax.ShapeDtypeStruct((rows,) + row, jnp.bfloat16,
+                                    sharding=one_chip)
+
+    buf, slab = shape(plan.buf_rows), shape(payload)
+    fn = getattr(ops, op)
+    body = {
+        "slab_extract": lambda b, s: fn(b, int(send[src]), payload,
+                                        interpret=False),
+        "slab_merge": lambda b, s: fn(b, s, int(recv[dst]), int(valid[dst]),
+                                      interpret=False),
+        "slab_merge_add": lambda b, s: fn(b, s, int(recv[dst]),
+                                          int(valid[dst]), interpret=False),
+        "slab_step": lambda b, s: fn(b, s, int(recv[dst]), int(valid[dst]),
+                                     int(nxt[2][dst]), nxt[1],
+                                     interpret=False),
+        "slab_step_reduce": lambda b, s: fn(b, s, int(recv[dst]),
+                                            int(valid[dst]),
+                                            int(nxt[2][dst]), nxt[1],
+                                            interpret=False),
+    }[op]
+    compiled = jax.jit(body).lower(buf, slab).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+
+
+@pytest.mark.parametrize("model", sorted(WIDTHS))
+@pytest.mark.parametrize("op", ["gatherv", "scatterv", "allgatherv",
+                                "alltoallv", "reduce_scatterv",
+                                "allreducev"])
+def test_executor_compiles_on_2x2_mesh(op, model, mesh4, pallas):
+    """The whole SPMD executor on the four described chips: the Pallas
+    kernels are in the program, one collective-permute per plan step at
+    least, and it fits one chip's HBM."""
+    plan = _plan(model, op)
+    F = WIDTHS[model]
+    rows = (plan.buf_rows if op == "scatterv" else
+            plan.in_rows if op in ("reduce_scatterv", "allreducev") else
+            plan.cap)
+    shard = getattr(jc, op + "_shard")
+    fn = jax.jit(jax.shard_map(lambda xl: shard(xl, plan, "x"), mesh=mesh4,
+                               in_specs=P("x"), out_specs=P("x"),
+                               check_vma=False))
+    x = jax.ShapeDtypeStruct((4 * rows, F), jnp.bfloat16,
+                             sharding=NamedSharding(mesh4, P("x")))
+    compiled = fn.lower(x).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    permutes = len(re.findall(r" collective-permute(?:-start)?\(", hlo))
+    assert permutes >= len(plan.steps), permutes
+    _fits(compiled)
