@@ -359,14 +359,19 @@ def _slab_ops(reduce: bool = False):
     fused-ADD variants (``slab_merge_add`` / ``slab_step_reduce``):
     received slabs fold into the accumulator instead of overwriting it —
     the only semantic difference between the byte-moving and the
-    reducing data planes."""
+    reducing data planes.  The oracles run under ``jax.named_scope`` of
+    the kernel they stand for (``KERNEL_NAMES``), so an op's
+    ``op_name`` is the same on either data plane."""
     if _DATAPLANE == "xla":
         from repro.kernels.ragged_gather import ref
         if reduce:
-            return (ref.slab_extract_ref, ref.slab_merge_add_ref,
-                    ref.slab_step_reduce_ref, lambda buf: buf)
-        return (ref.slab_extract_ref, ref.slab_merge_ref, ref.slab_step_ref,
-                lambda buf: buf)
+            return (_scoped("slab_extract", ref.slab_extract_ref),
+                    _scoped("slab_merge_add", ref.slab_merge_add_ref),
+                    _scoped("slab_step_reduce", ref.slab_step_reduce_ref),
+                    lambda buf: buf)
+        return (_scoped("slab_extract", ref.slab_extract_ref),
+                _scoped("slab_merge", ref.slab_merge_ref),
+                _scoped("slab_step", ref.slab_step_ref), lambda buf: buf)
     from repro.kernels.ragged_gather import ops
     kw = {"interpret": _DATAPLANE == "interpret"}
     merge, step = ((ops.slab_merge_add, ops.slab_step_reduce) if reduce
@@ -374,6 +379,26 @@ def _slab_ops(reduce: bool = False):
     return (functools.partial(ops.slab_extract, **kw),
             functools.partial(merge, **kw), functools.partial(step, **kw),
             ops.row_view)
+
+
+def _scoped(name: str, fn):
+    """``fn`` run under ``jax.named_scope(name)``."""
+    def run(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+    return run
+
+
+# Device scopes of the executors' phases (``jax.named_scope``).  A scope
+# lands in the ``op_name`` metadata of every op traced inside it, which a
+# profiler shows as the op's ``tf_op``; it changes no op of the program.
+FILL = "ragged.fill"                  # capacity buffer: zeros + own input
+RELAYOUT_IN = "ragged.relayout_in"    # entry ``row_view`` of the buffer
+STEP = "ragged.step"                  # each step's slab op
+PPERMUTE = "ragged.ppermute"          # each step's ``lax.ppermute``
+RELAYOUT_OUT = "ragged.relayout_out"  # exit reshape to (N, F)
+UNPACK = "ragged.unpack"              # the output taken from the buffer
+SCOPES = (FILL, RELAYOUT_IN, STEP, PPERMUTE, RELAYOUT_OUT, UNPACK)
 
 
 def _apply_steps(buf: jax.Array, steps, r, axis_name: str,
@@ -401,21 +426,26 @@ def _apply_steps(buf: jax.Array, steps, r, axis_name: str,
         return buf
     extract, merge, step, view = _slab_ops(reduce)
     shape = buf.shape
-    buf = view(buf)
+    with jax.named_scope(RELAYOUT_IN):
+        buf = view(buf)
     _, payload0, send0, _, _ = steps[0]
-    out = extract(buf, jnp.asarray(send0)[r], payload0)
+    with jax.named_scope(STEP):
+        out = extract(buf, jnp.asarray(send0)[r], payload0)
     for k, (perm, payload, send_start, recv_start, recv_valid) in \
             enumerate(steps):
-        got = jax.lax.ppermute(out, axis_name, perm)
-        r0 = jnp.asarray(recv_start)[r]
-        nv = jnp.asarray(recv_valid)[r]
-        if k + 1 < len(steps):
-            _, npayload, nsend, _, _ = steps[k + 1]
-            buf, out = step(buf, got, r0, nv, jnp.asarray(nsend)[r],
-                            npayload)
-        else:
-            buf = merge(buf, got, r0, nv)
-    return buf.reshape(shape)
+        with jax.named_scope(PPERMUTE):
+            got = jax.lax.ppermute(out, axis_name, perm)
+        with jax.named_scope(STEP):
+            r0 = jnp.asarray(recv_start)[r]
+            nv = jnp.asarray(recv_valid)[r]
+            if k + 1 < len(steps):
+                _, npayload, nsend, _, _ = steps[k + 1]
+                buf, out = step(buf, got, r0, nv, jnp.asarray(nsend)[r],
+                                npayload)
+            else:
+                buf = merge(buf, got, r0, nv)
+    with jax.named_scope(RELAYOUT_OUT):
+        return buf.reshape(shape)
 
 
 def gatherv_shard(x_local: jax.Array, plan: GathervPlan, axis_name: str) -> jax.Array:
@@ -426,10 +456,12 @@ def gatherv_shard(x_local: jax.Array, plan: GathervPlan, axis_name: str) -> jax.
     r = jax.lax.axis_index(axis_name)
     F = x_local.shape[1]
     offs = jnp.asarray(plan.offsets, jnp.int32)
-    buf = jnp.zeros((plan.buf_rows, F), x_local.dtype)
-    # write own (padded) block at its global offset; spill rows are later
-    # overwritten by received ranges (see module docstring invariant)
-    buf = jax.lax.dynamic_update_slice(buf, x_local, (offs[r], jnp.int32(0)))
+    with jax.named_scope(FILL):
+        buf = jnp.zeros((plan.buf_rows, F), x_local.dtype)
+        # write own (padded) block at its global offset; spill rows are
+        # later overwritten by received ranges (module docstring invariant)
+        buf = jax.lax.dynamic_update_slice(buf, x_local,
+                                           (offs[r], jnp.int32(0)))
     return _apply_steps(buf, plan.steps, r, axis_name)
 
 
@@ -466,9 +498,9 @@ def scatterv_shard(buf_root: jax.Array, plan: GathervPlan, axis_name: str) -> ja
     F = buf_root.shape[1]
     offs = jnp.asarray(plan.offsets, jnp.int32)
     buf = _apply_steps(buf_root, _reversed_step_tables(plan), r, axis_name)
-    own = jax.lax.dynamic_slice(buf, (offs[r], jnp.int32(0)),
-                                (plan.cap, F))
-    return own
+    with jax.named_scope(UNPACK):
+        return jax.lax.dynamic_slice(buf, (offs[r], jnp.int32(0)),
+                                     (plan.cap, F))
 
 
 # --------------------------------------------------------------------------
@@ -567,32 +599,31 @@ def call_with_deadline(op: str, thunk):
 
 
 def _run_traced(op: str, plan, row_bytes: int, fn, xg) -> np.ndarray:
-    """Execute a jitted driver with the telemetry plane around it.
+    """Execute a jitted driver inside the ``run/<op>`` span.
 
-    Wall-clock timing + default-registry counters always (single dict
-    update, cheap enough to leave on); a trace span with the plan shape
-    and bytes moved only when ``repro.obs.trace`` is enabled — the off
-    path is one ``None`` check.  Execution goes through
-    :func:`call_with_deadline`, so an armed step deadline (or an
-    installed chaos fault hook) gets bounded retry and escalates as
-    :class:`CollectiveTimeout` instead of hanging.
+    The span always lands in a running profiler's trace; when
+    ``repro.obs.trace`` is enabled it is also recorded with the plan
+    shape and bytes moved.  The ``run_<op>`` counter counts every call.
+    Execution goes through :func:`call_with_deadline`, so an armed step
+    deadline (or an installed chaos fault hook) gets bounded retry and
+    escalates as :class:`CollectiveTimeout` instead of hanging.
     """
-    tr = obs_trace.current()
-    t0 = time.perf_counter()
-    out, _, attempts = call_with_deadline(op, lambda: np.asarray(fn(xg)))
-    dt = time.perf_counter() - t0
-    _OBS_REGISTRY.counter("run_" + op).inc()
-    _OBS_REGISTRY.histogram("run_seconds").observe(dt)
-    if tr is not None:
+    args = {}
+    if obs_trace.current() is not None:
         args = {"op": op, "p": plan.p,
                 "segments": getattr(plan, "segments", 1),
                 "num_stages": getattr(plan, "num_stages", 0),
-                "measured_s": dt, "row_bytes": int(row_bytes),
-                "attempts": attempts}
+                "row_bytes": int(row_bytes)}
         for cls, nb in obs_trace.plan_link_bytes(
                 plan.steps, row_bytes=int(row_bytes)).items():
             args[f"bytes_{cls}"] = nb
-        tr.add_complete("run/" + op, "collective", t0, dt, **args)
+    with obs_trace.span("run/" + op, "collective", **args) as sp:
+        t0 = time.perf_counter()
+        out, _, attempts = call_with_deadline(op,
+                                              lambda: np.asarray(fn(xg)))
+        sp.args.update(measured_s=time.perf_counter() - t0,
+                       attempts=attempts)
+    _OBS_REGISTRY.counter("run_" + op).inc()
     return out
 
 
@@ -868,8 +899,10 @@ def allgatherv_shard(x_local: jax.Array, plan: ComposedPlan,
     r = jax.lax.axis_index(axis_name)
     F = x_local.shape[1]
     starts = jnp.asarray(plan.in_starts, jnp.int32)
-    buf = jnp.zeros((plan.buf_rows, F), x_local.dtype)
-    buf = jax.lax.dynamic_update_slice(buf, x_local, (starts[r], jnp.int32(0)))
+    with jax.named_scope(FILL):
+        buf = jnp.zeros((plan.buf_rows, F), x_local.dtype)
+        buf = jax.lax.dynamic_update_slice(buf, x_local,
+                                           (starts[r], jnp.int32(0)))
     return _apply_steps(buf, plan.steps, r, axis_name)
 
 
@@ -882,20 +915,25 @@ def alltoallv_shard(x_local: jax.Array, plan: ComposedPlan,
     r = jax.lax.axis_index(axis_name)
     F = x_local.shape[1]
     starts = jnp.asarray(plan.in_starts, jnp.int32)
-    buf = jnp.zeros((plan.buf_rows, F), x_local.dtype)
-    buf = jax.lax.dynamic_update_slice(buf, x_local, (starts[r], jnp.int32(0)))
+    with jax.named_scope(FILL):
+        buf = jnp.zeros((plan.buf_rows, F), x_local.dtype)
+        buf = jax.lax.dynamic_update_slice(buf, x_local,
+                                           (starts[r], jnp.int32(0)))
     buf = _apply_steps(buf, plan.steps, r, axis_name)
-    out = jnp.zeros((plan.out_rows, F), x_local.dtype)
-    mask_rows = jnp.arange(plan.chunk, dtype=jnp.int32)[:, None]
-    for src_start, dst_start, valid in plan.extract:
-        s0 = jnp.asarray(src_start)[r]
-        d0 = jnp.asarray(dst_start)[r]
-        nv = jnp.asarray(valid)[r]
-        blk = jax.lax.dynamic_slice(buf, (s0, jnp.int32(0)), (plan.chunk, F))
-        cur = jax.lax.dynamic_slice(out, (d0, jnp.int32(0)), (plan.chunk, F))
-        upd = jnp.where(mask_rows < nv, blk, cur)
-        out = jax.lax.dynamic_update_slice(out, upd, (d0, jnp.int32(0)))
-    return out
+    with jax.named_scope(UNPACK):
+        out = jnp.zeros((plan.out_rows, F), x_local.dtype)
+        mask_rows = jnp.arange(plan.chunk, dtype=jnp.int32)[:, None]
+        for src_start, dst_start, valid in plan.extract:
+            s0 = jnp.asarray(src_start)[r]
+            d0 = jnp.asarray(dst_start)[r]
+            nv = jnp.asarray(valid)[r]
+            blk = jax.lax.dynamic_slice(buf, (s0, jnp.int32(0)),
+                                        (plan.chunk, F))
+            cur = jax.lax.dynamic_slice(out, (d0, jnp.int32(0)),
+                                        (plan.chunk, F))
+            upd = jnp.where(mask_rows < nv, blk, cur)
+            out = jax.lax.dynamic_update_slice(out, upd, (d0, jnp.int32(0)))
+        return out
 
 
 def run_allgatherv(mesh: Mesh, axis_name, blocks: list[np.ndarray],
@@ -1207,12 +1245,14 @@ def reduce_scatterv_shard(x_local: jax.Array, plan: ReduceScattervPlan,
     r = jax.lax.axis_index(axis_name)
     F = x_local.shape[1]
     offs = jnp.asarray(plan.offsets, jnp.int32)
-    buf = jnp.zeros((plan.buf_rows, F), x_local.dtype)
-    buf = jax.lax.dynamic_update_slice(buf, x_local,
-                                       (jnp.int32(0), jnp.int32(0)))
+    with jax.named_scope(FILL):
+        buf = jnp.zeros((plan.buf_rows, F), x_local.dtype)
+        buf = jax.lax.dynamic_update_slice(buf, x_local,
+                                           (jnp.int32(0), jnp.int32(0)))
     buf = _apply_steps(buf, plan.steps, r, axis_name, reduce=True)
-    return jax.lax.dynamic_slice(buf, (offs[r], jnp.int32(0)),
-                                 (plan.cap, F))
+    with jax.named_scope(UNPACK):
+        return jax.lax.dynamic_slice(buf, (offs[r], jnp.int32(0)),
+                                     (plan.cap, F))
 
 
 def allreducev_shard(x_local: jax.Array, plan: AllreducevPlan,
@@ -1225,9 +1265,10 @@ def allreducev_shard(x_local: jax.Array, plan: AllreducevPlan,
     with overwrite semantics."""
     r = jax.lax.axis_index(axis_name)
     F = x_local.shape[1]
-    buf = jnp.zeros((plan.buf_rows, F), x_local.dtype)
-    buf = jax.lax.dynamic_update_slice(buf, x_local,
-                                       (jnp.int32(0), jnp.int32(0)))
+    with jax.named_scope(FILL):
+        buf = jnp.zeros((plan.buf_rows, F), x_local.dtype)
+        buf = jax.lax.dynamic_update_slice(buf, x_local,
+                                           (jnp.int32(0), jnp.int32(0)))
     buf = _apply_steps(buf, plan.rs.steps, r, axis_name, reduce=True)
     return _apply_steps(buf, plan.ag.steps, r, axis_name)
 

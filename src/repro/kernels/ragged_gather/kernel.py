@@ -27,6 +27,11 @@
 * ``slab_merge_add_kernel`` / ``slab_step_reduce_kernel`` — the same
   with the received rows ADDED into the buffer (reduce data plane),
   folded through VMEM one row tile at a time.
+
+Every ``pallas_call`` carries a stable ``name=`` from ``KERNEL_NAMES``.
+The name becomes the HLO custom-call instruction's name, so a device
+trace shows each kernel as ``<name>/custom-call`` instead of under the
+name of the function that called it.
 """
 from __future__ import annotations
 
@@ -39,6 +44,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .ref import fold_add
+
+# The HLO instruction name of each kernel (``pallas_call(name=...)``).
+KERNEL_NAMES = ("slab_extract", "slab_merge", "slab_step", "slab_merge_add",
+                "slab_step_reduce", "ragged_gather", "ragged_scatter")
 
 
 def _kernel(idx_ref, x_ref, o_ref, *, block_rows: int):
@@ -73,6 +82,7 @@ def ragged_gather_kernel(x: jax.Array, idx: jax.Array, *,
         ),
         out_shape=jax.ShapeDtypeStruct((m, f), x.dtype),
         interpret=interpret,
+        name="ragged_gather",
     )(idx, x)
 
 
@@ -115,6 +125,7 @@ def ragged_scatter_kernel(x: jax.Array, idx: jax.Array, n_out: int, *,
         ),
         out_shape=jax.ShapeDtypeStruct((n_out, f), x.dtype),
         interpret=interpret,
+        name="ragged_scatter",
     )(idx, x)
 
 
@@ -203,10 +214,11 @@ def _fold_scratch(buf, rows: int):
             pltpu.SemaphoreType.DMA((2,))]
 
 
-def _slab_call(body, scalars, tensors, out_shape, scratch, *,
+def _slab_call(name, body, scalars, tensors, out_shape, scratch, *,
                in_place: bool, interpret: bool):
-    """One-program pallas_call over HBM operands.  ``in_place`` aliases
-    the first tensor (the buffer) to the first output."""
+    """One-program pallas_call named ``name`` over HBM operands.
+    ``in_place`` aliases the first tensor (the buffer) to the first
+    output."""
     n_out = len(out_shape) if isinstance(out_shape, tuple) else 1
     return pl.pallas_call(
         body,
@@ -218,6 +230,7 @@ def _slab_call(body, scalars, tensors, out_shape, scratch, *,
         out_shape=out_shape,
         input_output_aliases={len(scalars): 0} if in_place else {},
         interpret=interpret,
+        name=name,
     )(*scalars, *tensors)
 
 
@@ -231,7 +244,7 @@ def slab_extract_kernel(buf: jax.Array, start: jax.Array, rows: int, *,
     int32 array (typically a traced per-device value inside
     ``shard_map``) prefetched to SMEM."""
     return _slab_call(
-        _slab_extract_kernel, (start,), (buf,),
+        "slab_extract", _slab_extract_kernel, (start,), (buf,),
         jax.ShapeDtypeStruct((rows,) + buf.shape[1:], buf.dtype),
         [pltpu.SemaphoreType.DMA(())], in_place=False, interpret=interpret)
 
@@ -248,7 +261,7 @@ def slab_merge_kernel(buf: jax.Array, slab: jax.Array, start: jax.Array,
     row ``start`` (rows >= valid keep buf's data), in place.  ``start``
     and ``valid`` are (1,) int32 arrays (traced per-device values)."""
     return _slab_call(
-        _slab_merge_kernel, (start, valid), (buf, slab),
+        "slab_merge", _slab_merge_kernel, (start, valid), (buf, slab),
         jax.ShapeDtypeStruct(buf.shape, buf.dtype),
         [pltpu.SemaphoreType.DMA((len(_pow2_chunks(slab.shape[0])),))],
         in_place=True, interpret=interpret)
@@ -274,7 +287,8 @@ def slab_step_kernel(buf: jax.Array, slab: jax.Array, recv_start: jax.Array,
     ``send_start``.  All three scalars are (1,) int32 arrays (traced
     per-device values looked up from the step tables)."""
     return _slab_call(
-        _slab_step_kernel, (recv_start, recv_valid, send_start), (buf, slab),
+        "slab_step", _slab_step_kernel, (recv_start, recv_valid, send_start),
+        (buf, slab),
         (jax.ShapeDtypeStruct(buf.shape, buf.dtype),
          jax.ShapeDtypeStruct((rows_out,) + buf.shape[1:], buf.dtype)),
         [pltpu.SemaphoreType.DMA((len(_pow2_chunks(slab.shape[0])),))],
@@ -294,7 +308,8 @@ def slab_merge_add_kernel(buf: jax.Array, slab: jax.Array, start: jax.Array,
     row ``start``, in place (rows >= valid keep buf's data bit-exactly).
     The reduction dual of ``slab_merge_kernel``."""
     return _slab_call(
-        _slab_merge_add_kernel, (start, valid), (buf, slab),
+        "slab_merge_add", _slab_merge_add_kernel, (start, valid),
+        (buf, slab),
         jax.ShapeDtypeStruct(buf.shape, buf.dtype),
         _fold_scratch(buf, slab.shape[0]), in_place=True,
         interpret=interpret)
@@ -320,8 +335,8 @@ def slab_step_reduce_kernel(buf: jax.Array, slab: jax.Array,
     ``rows_out``-row slab of the UPDATED buffer at dynamic row
     ``send_start``."""
     return _slab_call(
-        _slab_step_reduce_kernel, (recv_start, recv_valid, send_start),
-        (buf, slab),
+        "slab_step_reduce", _slab_step_reduce_kernel,
+        (recv_start, recv_valid, send_start), (buf, slab),
         (jax.ShapeDtypeStruct(buf.shape, buf.dtype),
          jax.ShapeDtypeStruct((rows_out,) + buf.shape[1:], buf.dtype)),
         _fold_scratch(buf, slab.shape[0]), in_place=True,

@@ -5,8 +5,9 @@ selects, caches, and executes — but a model nobody audits rots silently
 under congestion, throttling, or a degraded link.  This package is the
 audit loop:
 
-* :mod:`~repro.obs.trace` — per-collective, per-stage structured spans
-  with a Chrome-trace/Perfetto JSON exporter (off ⇒ no-op path);
+* :mod:`~repro.obs.trace` — per-lookup and per-collective structured
+  spans, written into a running profiler's trace and, when recording
+  is on, exported as Chrome-trace/Perfetto JSON;
 * :mod:`~repro.obs.metrics` — pure-Python counters / gauges /
   histograms published by the plan cache, the compiled-executable LRU,
   the selection path, and the ``run_*`` drivers;
@@ -23,4 +24,4 @@ from .metrics import (REGISTRY, Counter, Gauge,  # noqa: F401
                       Histogram, Registry)
 from .residuals import DriftDetector, Residual, ResidualLedger  # noqa: F401
 from .trace import (Span, TraceRecorder, current,  # noqa: F401
-                    disable, enable, plan_link_bytes, stage_breakdown)
+                    disable, enable, plan_link_bytes, span)

@@ -11,16 +11,21 @@ directly.
 
 Design constraints, in order:
 
-* **Tracing off is a no-op path.**  There is no global "maybe record"
+* **Recording off is a branch.**  There is no global "maybe record"
   indirection on the hot path: callers fetch the active recorder once
   (``rec = trace.current()``) and skip all span construction when it is
-  ``None``.  The off cost is one module attribute read and a branch.
+  ``None``; :func:`span` keeps only its profiler annotation then.
 * **Tracing on is cheap.**  A span is two ``perf_counter`` reads and one
   list append of a plain tuple-backed object — no locks on the record
   path beyond a single ``list.append`` (atomic under the GIL), no
   string formatting until export.
-* **No dependencies.**  Pure stdlib; the tuner and the SPMD drivers can
-  import it unconditionally.
+* **Spans land in the profiler's trace.**  :func:`span` also enters a
+  ``jax.profiler.TraceAnnotation``, so under a running profiler session
+  the span sits in the ``.xplane.pb`` host plane, on the same timeline as
+  the device ops.  With no session it costs about a microsecond.
+* **No import-time dependencies.**  Stdlib at import (``jax`` is
+  imported on the first :func:`span`); the tuner and the SPMD drivers
+  can import it unconditionally.
 
 The module-level recorder is controlled by :func:`enable` /
 :func:`disable`, or by the ``REPRO_TRACE`` environment variable (any
@@ -243,6 +248,45 @@ def current() -> TraceRecorder | None:
     return _RECORDER
 
 
+class _ProfiledSpan:
+    """Context manager returned by :func:`span`."""
+
+    __slots__ = ("args", "_ann", "_rec")
+
+    def __init__(self, name: str, cat: str, args: dict):
+        import jax
+
+        self.args = args
+        self._ann = jax.profiler.TraceAnnotation(name)
+        rec = _RECORDER
+        self._rec = (None if rec is None
+                     else _SpanHandle(rec, Span(name, cat, 0.0, 0.0, args)))
+
+    def __enter__(self) -> "_ProfiledSpan":
+        self._ann.__enter__()
+        if self._rec is not None:
+            self._rec.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._rec is not None:
+            self._rec.__exit__(*exc)
+        self._ann.__exit__(*exc)
+
+
+def span(name: str, cat: str = "", **args) -> _ProfiledSpan:
+    """``with span("plan/alltoallv", "planner", op=...) as sp: ...``
+
+    Always a ``jax.profiler.TraceAnnotation(name)``, which a running
+    profiler session writes into its host plane; also a recorded
+    :class:`Span` when a recorder is enabled.  ``sp.args`` is mutable:
+    results found inside the span go there (they are dropped when no
+    recorder is enabled).  Externally timed spans and instants keep
+    :meth:`TraceRecorder.add_complete` / :meth:`TraceRecorder.instant`.
+    """
+    return _ProfiledSpan(name, cat, args)
+
+
 def plan_link_bytes(steps, topology=None, row_bytes: int = 1) -> dict:
     """Exact bytes a lowered plan moves per link class.
 
@@ -265,51 +309,6 @@ def plan_link_bytes(steps, topology=None, row_bytes: int = 1) -> dict:
             cls = "ici" if topology.same_host(s, d) else "dcn"
             out[cls] += int(recv_valid[d])
     return {k: v * int(row_bytes) for k, v in out.items()}
-
-
-def stage_breakdown(plan, params) -> list[dict]:
-    """Per-stage predicted timing of a lowered plan.
-
-    Groups the plan's steps by ``stage_ids`` and prices each stage with
-    the same arithmetic as ``plan_pipeline_cost`` prices the whole plan
-    (startups + port-critical bandwidth + amortized spill), so the
-    per-stage predictions SUM to the plan's predicted seconds.  These
-    feed the synthetic per-stage child spans under an execution span —
-    the stage timeline is a model prediction (the XLA program is opaque
-    from the host), and the span schema labels it so.
-    """
-    from repro.core.costmodel import edge_params_fn
-
-    params.validate()
-    ab = edge_params_fn(params)
-    stage_ids = plan.stage_ids or tuple(range(len(plan.steps)))
-    stages: dict[int, list] = {}
-    for sid, step in zip(stage_ids, plan.steps):
-        stages.setdefault(sid, []).append(step)
-    out = []
-    for sid in sorted(stages):
-        steps = stages[sid]
-        sent: dict[int, float] = {}
-        recv: dict[int, float] = {}
-        padded = 0.0
-        alpha_term = 0.0
-        payloads = []
-        for perm, payload, *_ in steps:
-            payloads.append(int(payload))
-            pair_ab = [ab(s, d) for s, d in perm]
-            alpha_term += max(a for a, _ in pair_ab)
-            for (s, d), (_, b) in zip(perm, pair_ab):
-                bt = b * payload
-                padded += bt
-                sent[s] = sent.get(s, 0.0) + bt
-                recv[d] = recv.get(d, 0.0) + bt
-        port = max(max(sent.values(), default=0.0),
-                   max(recv.values(), default=0.0))
-        spill = (padded - port) / plan.p
-        out.append({"stage": sid, "steps": len(steps),
-                    "wave_payloads": payloads,
-                    "predicted_s": alpha_term + port + spill})
-    return out
 
 
 # REPRO_TRACE=1 (anything non-empty except "0") forces tracing on at

@@ -47,10 +47,11 @@ the paper's G2–G4 bounds live.  A residual ledger's CUSUM detector
 firing triggers :meth:`refit_from_residuals`: (α, β) are refit per link
 class from the post-shift observations and ``params_epoch`` is bumped —
 the epoch is part of every :class:`~repro.tuner.cache.PlanKey`, so all
-plans selected under the stale model stop resolving at once.  When
-``repro.obs.trace`` is enabled, planning and execution emit spans
-(predicted per-stage breakdown included) for the Chrome-trace exporter;
-tracing off costs one ``None`` check.
+plans selected under the stale model stop resolving at once.  Every
+plan lookup (``plan/<op>``) and execution (``exec/<op>``) is a
+``repro.obs.trace.span``: in a running profiler's trace always, and
+recorded for the Chrome-trace exporter when ``repro.obs.trace`` is
+enabled.
 """
 from __future__ import annotations
 
@@ -299,17 +300,25 @@ class PlannerService:
     def plan_record(self, op: str, arg, root: int | None = None,
                     dtype: str = "float32", row_bytes: int = 1) -> PlanRecord:
         """Cached plan for one problem; a miss runs enumerate + select +
-        lower and stores the result (write-through when persistent)."""
+        lower and stores the result (write-through when persistent).
+        Every call, hit or miss, is one ``plan/<op>`` span
+        (``repro.obs.trace.span``) with the arg ``hit``."""
         if op not in OPS:
             raise ValueError(f"unknown op {op!r}")
         if op in ("gatherv", "scatterv") and root is None:
             raise ValueError(f"{op} needs a root")
-        key = self._key(op, arg, root, dtype, row_bytes)
-        rec = self.cache.get(key)
-        if rec is not None:
-            return rec
-        tr = obs_trace.current()
-        t_plan = time.perf_counter()
+        with obs_trace.span("plan/" + op, "planner", op=op) as sp:
+            key = self._key(op, arg, root, dtype, row_bytes)
+            rec = self.cache.get(key)
+            sp.args["hit"] = rec is not None
+            if rec is None:
+                rec = self._plan_miss(op, key, root, row_bytes, sp.args)
+        return rec
+
+    def _plan_miss(self, op: str, key: PlanKey, root: int | None,
+                   row_bytes: int, span_args: dict) -> PlanRecord:
+        """Enumerate, select and lower the plan of ``key``, store it, and
+        fill the ``plan/<op>`` span's args with how it was chosen."""
         qarg = key.signature
         # selection params in bytes: scale the per-row β by the row width
         rb = max(1, int(row_bytes))
@@ -358,15 +367,11 @@ class PlannerService:
         self.metrics.counter("plans_planned").inc()
         if sel.measured:
             self.metrics.counter("candidates_raced").inc(len(sel.measured))
-        if tr is not None:
-            tr.add_complete(
-                "plan/" + op, "planner", t_plan,
-                time.perf_counter() - t_plan,
-                op=op, p=key.p, token=key.token(), algo=sel.chosen,
-                cost=sel.cost, epoch=self.params_epoch,
-                row_bytes=rb, candidates=len(cands),
-                raced=[n for n, _ in sel.measured] if sel.measured else [],
-                kept_previous=sel.kept_previous)
+        span_args.update(
+            p=key.p, token=token, algo=sel.chosen, cost=sel.cost,
+            epoch=self.params_epoch, row_bytes=rb, candidates=len(cands),
+            raced=[n for n, _ in sel.measured] if sel.measured else [],
+            kept_previous=sel.kept_previous)
         return rec
 
     def plan(self, op: str, arg, root: int | None = None,
@@ -439,18 +444,18 @@ class PlannerService:
 
     def _run(self, op: str, rec: PlanRecord, fn, x, row_bytes: int,
              arg=None, root: int | None = None) -> np.ndarray:
-        """Execute a compiled plan with the telemetry plane around it:
-        wall-clock timing, metrics, the exec trace span (with predicted
-        per-stage children), and the residual/guideline deposit."""
+        """Execute a compiled plan inside the ``exec/<op>`` span, count it,
+        and deposit its wall time into the residual/guideline plane."""
         fresh = self._just_compiled
-        t0 = time.perf_counter()
-        out = np.asarray(fn(self._put(x)))
-        dt = time.perf_counter() - t0
+        args = {}
+        if obs_trace.current() is not None:
+            args = self._exec_span_args(op, rec, row_bytes, fresh)
+        with obs_trace.span("exec/" + op, "collective", **args) as sp:
+            t0 = time.perf_counter()
+            out = np.asarray(fn(self._put(x)))
+            dt = time.perf_counter() - t0
+            sp.args["measured_s"] = dt
         self.metrics.counter("collectives_executed").inc()
-        self.metrics.histogram("exec_seconds").observe(dt)
-        tr = obs_trace.current()
-        if tr is not None:
-            self._emit_exec_span(tr, op, rec, t0, dt, row_bytes, fresh)
         if not fresh:
             # a freshly jitted executable's first call is dominated by XLA
             # compilation — wall time says nothing about the fabric
@@ -458,35 +463,21 @@ class PlannerService:
                                   arg=arg, root=root)
         return out
 
-    def _emit_exec_span(self, tr, op: str, rec: PlanRecord, t0: float,
-                        dt: float, row_bytes: int, fresh: bool) -> None:
+    def _exec_span_args(self, op: str, rec: PlanRecord, row_bytes: int,
+                        fresh: bool) -> dict:
         rb = max(1, int(row_bytes))
-        sel_params = self._sel_params(rb)
         plan = rec.plan
-        breakdown = obs_trace.stage_breakdown(plan, sel_params)
-        predicted = sum(s["predicted_s"] for s in breakdown)
         args = {"op": op, "algo": rec.algo, "serial": rec.serial,
                 "segments": getattr(plan, "segments", 1),
-                "num_stages": len(breakdown),
-                "predicted_s": predicted, "measured_s": dt,
+                "num_stages": getattr(plan, "num_stages", 0),
+                "predicted_s": plan_pipeline_cost(plan,
+                                                  self._sel_params(rb)),
                 "fresh_compile": fresh, "epoch": self.params_epoch,
                 "row_bytes": rb}
         for cls, nbytes in obs_trace.plan_link_bytes(
                 plan.steps, self.topology, row_bytes=rb).items():
             args[f"bytes_{cls}"] = nbytes
-        tr.add_complete("exec/" + op, "collective", t0, dt, **args)
-        # predicted per-stage children, laid proportionally under the
-        # measured window (the XLA program is opaque from the host — the
-        # stage timeline is the model's breakdown, and labeled so)
-        if len(breakdown) <= 128 and predicted > 0:
-            off = t0
-            for s in breakdown:
-                d = dt * s["predicted_s"] / predicted
-                tr.add_complete(f"stage/{s['stage']}", "stage-predicted",
-                                off, d, tid=1, steps=s["steps"],
-                                wave_payloads=s["wave_payloads"],
-                                predicted_s=s["predicted_s"])
-                off += d
+        return args
 
     def record_execution(self, op: str, rec: PlanRecord, measured_s: float,
                          row_bytes: int = 1, arg=None,

@@ -40,7 +40,6 @@ compilations (the service's compiled-LRU misses).
 """
 from __future__ import annotations
 
-import time
 from collections import OrderedDict
 
 import numpy as np
@@ -241,36 +240,32 @@ class ServingPlanner:
         signature class (with hysteresis) and return the cached class
         plan (a cache hit in steady state).  Returns the
         :class:`~repro.tuner.service.PlanRecord`; feeds the predictor and
-        the serve-span trace."""
-        t0 = time.perf_counter()
-        sig = self._select_class(op, raw)
-        key = (op, sig)
-        misses0 = self.svc.plan_misses
-        rec = self.svc.plan_record(op, sig, root=root, dtype=dtype,
-                                   row_bytes=row_bytes)
-        fresh = self.svc.plan_misses > misses0
-        if fresh:
-            self.hot_misses += 1
-        elif key in self._prefetched and key not in self.classes_seen:
-            self.prefetch_hits += 1
-        self.classes_seen.add(key)
-        self._ladder.add(key)
-        self._plan_ctx[op] = (root, dtype, row_bytes)
-        pred = self._predictors.get(op)
-        if pred is None:
-            pred = self._predictors[op] = SignaturePredictor(*self._pred_args)
-        pred.observe(raw, sig)
-        ovh = self.classifier.price_overhead(raw, sig)
-        if ovh > self.overhead_max:
-            self.overhead_max = ovh
-        self.steps += 1
-        tr = obs_trace.current()
-        if tr is not None:
-            tr.add_complete("serve/plan_step", "serving", t0,
-                            time.perf_counter() - t0, op=op,
-                            algo=rec.algo, fresh=fresh,
-                            padding_overhead=ovh,
-                            epoch=self.svc.params_epoch)
+        the ``serve/plan_step`` span."""
+        with obs_trace.span("serve/plan_step", "serving", op=op) as sp:
+            sig = self._select_class(op, raw)
+            key = (op, sig)
+            misses0 = self.svc.plan_misses
+            rec = self.svc.plan_record(op, sig, root=root, dtype=dtype,
+                                       row_bytes=row_bytes)
+            fresh = self.svc.plan_misses > misses0
+            if fresh:
+                self.hot_misses += 1
+            elif key in self._prefetched and key not in self.classes_seen:
+                self.prefetch_hits += 1
+            self.classes_seen.add(key)
+            self._ladder.add(key)
+            self._plan_ctx[op] = (root, dtype, row_bytes)
+            pred = self._predictors.get(op)
+            if pred is None:
+                pred = self._predictors[op] = SignaturePredictor(
+                    *self._pred_args)
+            pred.observe(raw, sig)
+            ovh = self.classifier.price_overhead(raw, sig)
+            if ovh > self.overhead_max:
+                self.overhead_max = ovh
+            self.steps += 1
+            sp.args.update(algo=rec.algo, fresh=fresh, padding_overhead=ovh,
+                           epoch=self.svc.params_epoch)
         return rec
 
     def prefetch(self, compile_width: int | None = None) -> int:
@@ -278,8 +273,13 @@ class ServingPlanner:
         classes — OFF the hot path, between decode steps.  Returns how
         many plans were newly built.  ``compile_width``: feature width F
         to pre-compile executables for (mesh services only)."""
+        with obs_trace.span("serve/prefetch", "serving") as sp:
+            built = self._prefetch(compile_width)
+            sp.args["built"] = built
+        return built
+
+    def _prefetch(self, compile_width: int | None) -> int:
         built = 0
-        t0 = time.perf_counter()
         for op, pred in self._predictors.items():
             root, dtype, row_bytes = self._plan_ctx[op]
             sigs = pred.predict()
@@ -313,10 +313,6 @@ class ServingPlanner:
                 if compile_width is not None and self.svc.mesh is not None:
                     self.svc._compiled_fn(op, rec, int(compile_width),
                                           dtype)
-        tr = obs_trace.current()
-        if tr is not None and built:
-            tr.add_complete("serve/prefetch", "serving", t0,
-                            time.perf_counter() - t0, built=built)
         return built
 
     @property

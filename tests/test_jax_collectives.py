@@ -14,6 +14,7 @@ from hypothesis import given, settings, strategies as st
 from repro.core import build_gather_tree
 from repro.core.jax_collectives import plan_gatherv
 from repro.core.distributions import NAMES, block_sizes
+from repro.kernels.ragged_gather.kernel import KERNEL_NAMES
 
 CHILD = os.path.join(os.path.dirname(__file__), "multidevice",
                      "child_collectives.py")
@@ -74,3 +75,64 @@ def test_multidevice_collectives(child_env):
         text=True, timeout=600)
     assert res.returncode == 0, f"STDOUT:\n{res.stdout}\nSTDERR:\n{res.stderr}"
     assert "ALL MULTIDEVICE COLLECTIVE CHECKS PASSED" in res.stdout
+
+
+# ------------------------------------------------- executor phase scopes
+
+SCOPE_SIZES = [5, 0, 9, 3]
+SCOPE_S = [[2, 0, 1, 3], [4, 1, 0, 2], [0, 3, 3, 1], [1, 2, 0, 0]]
+
+
+@pytest.mark.parametrize("op", ["gatherv", "scatterv", "allgatherv",
+                                "alltoallv", "reduce_scatterv",
+                                "allreducev"])
+def test_executor_phases_carry_scopes(op):
+    """Each executor, lowered for a 4-rank mesh on the ``"xla"`` data
+    plane, names its phases in the ``op_name`` metadata: the fill of the
+    capacity buffer, every ppermute and every step's slab op, and the
+    output taken from the buffer where the executor takes one.  The
+    process has one CPU device, so the program is lowered against an
+    abstract mesh, which needs no devices."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import AbstractMesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import jax_collectives as jc
+
+    plan = {"gatherv": lambda: jc.plan_gatherv(SCOPE_SIZES, 0),
+            "scatterv": lambda: jc.plan_gatherv(SCOPE_SIZES, 0),
+            "allgatherv": lambda: jc.plan_allgatherv(SCOPE_SIZES),
+            "alltoallv": lambda: jc.plan_alltoallv(SCOPE_S),
+            "reduce_scatterv": lambda: jc.plan_reduce_scatterv(SCOPE_SIZES),
+            "allreducev": lambda: jc.plan_allreducev(SCOPE_SIZES)}[op]()
+    rows = (plan.buf_rows if op == "scatterv" else
+            plan.in_rows if op in ("reduce_scatterv", "allreducev") else
+            plan.cap)
+    mesh = AbstractMesh((4,), ("x",))
+    shard = getattr(jc, op + "_shard")
+    prev = jc.dataplane()
+    jc.set_dataplane("xla")
+    try:
+        fn = jax.jit(jax.shard_map(lambda xl: shard(xl, plan, "x"),
+                                   mesh=mesh, in_specs=P("x"),
+                                   out_specs=P("x"), check_vma=False))
+        x = jax.ShapeDtypeStruct((4 * rows, 8), jnp.float32,
+                                 sharding=NamedSharding(mesh, P("x")))
+        lowered = fn.trace(x).lower(lowering_platforms=("cpu",))
+    finally:
+        jc.set_dataplane(prev)
+    hlo = lowered.compiler_ir("hlo").as_hlo_module().to_string()
+    scopes = {scope for name in re.findall(r'op_name="([^"]*)"', hlo)
+              for scope in name.split("/")}
+    want = {jc.PPERMUTE, jc.STEP}
+    if op != "scatterv":                  # scatterv runs on its input
+        want.add(jc.FILL)
+    if op in ("scatterv", "alltoallv", "reduce_scatterv"):
+        want.add(jc.UNPACK)
+    assert want <= scopes, scopes
+    assert scopes & set(jc.SCOPES) == want, scopes
+    # the slab ops carry the name of the kernel they stand for
+    steps = re.findall(r'op_name="ragged\.step/([^/"]*)/', hlo)
+    assert set(steps) <= set(KERNEL_NAMES) and steps, steps

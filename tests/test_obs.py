@@ -26,7 +26,7 @@ from repro.obs import trace as obs_trace
 from repro.obs.guidelines_monitor import GuidelineMonitor, padded_regular_rhs
 from repro.obs.metrics import Histogram, Registry
 from repro.obs.residuals import DriftDetector, ResidualLedger
-from repro.obs.trace import TraceRecorder, plan_link_bytes, stage_breakdown
+from repro.obs.trace import TraceRecorder, plan_link_bytes
 from repro.runtime.straggler import StragglerPolicy
 from repro.tuner import PlannerService, plan_pipeline_cost
 
@@ -186,31 +186,6 @@ class TestPlanLinkBytes:
         topo = HostTopology(1, 4)
         steps = _steps(4, [(0, 1, 5)])
         assert plan_link_bytes(steps, topo, row_bytes=2) == {"flat": 10}
-
-
-class TestStageBreakdown:
-    @pytest.mark.parametrize("op,arg,root", [
-        ("gatherv", [1000, 5000, 300, 9000, 700, 4000, 50, 2000], 0),
-        ("allgatherv", [128, 4096, 32, 1024, 512, 64, 2048, 256], None),
-    ])
-    def test_sums_to_pipeline_cost(self, op, arg, root):
-        svc = _svc()
-        rec = svc.plan_record(op, arg, root=root, row_bytes=8)
-        sp = svc._sel_params(8)
-        bd = stage_breakdown(rec.plan, sp)
-        assert all(s["steps"] >= 1 and s["predicted_s"] > 0 for s in bd)
-        assert sum(s["predicted_s"] for s in bd) == pytest.approx(
-            plan_pipeline_cost(rec.plan, sp), rel=1e-9)
-
-    def test_alltoallv_composed_plan(self):
-        rng = np.random.default_rng(0)
-        S = rng.integers(0, 4000, (8, 8)).tolist()
-        svc = _svc()
-        rec = svc.plan_record("alltoallv", S, row_bytes=8)
-        sp = svc._sel_params(8)
-        bd = stage_breakdown(rec.plan, sp)
-        assert sum(s["predicted_s"] for s in bd) == pytest.approx(
-            plan_pipeline_cost(rec.plan, sp), rel=1e-9)
 
 
 # -------------------------------------------------------------- metrics
@@ -450,20 +425,40 @@ class TestStragglerHostFeed:
 class TestServiceTelemetry:
     SIZES = [128, 4096, 32, 1024]
 
-    def test_plan_span_on_miss_not_on_hit(self, recorder):
+    def test_plan_span_on_hit_and_miss(self, recorder):
         svc = _svc()
         svc.plan_record("gatherv", self.SIZES, root=0, row_bytes=4)
         svc.plan_record("gatherv", self.SIZES, root=0, row_bytes=4)
         spans = recorder.spans(cat="planner", name_prefix="plan/gatherv")
-        assert len(spans) == 1                 # the hit emits no span
-        args = spans[0].args
-        assert args["op"] == "gatherv" and args["epoch"] == 0
-        assert args["candidates"] > 0 and args["algo"]
-        assert args["cost"] > 0 and args["row_bytes"] == 4
+        assert [s.args["hit"] for s in spans] == [False, True]
+        miss, hit = (s.args for s in spans)
+        assert miss["op"] == "gatherv" and miss["epoch"] == 0
+        assert miss["candidates"] > 0 and miss["algo"]
+        assert miss["cost"] > 0 and miss["row_bytes"] == 4
+        assert hit == {"op": "gatherv", "hit": True}
         snap = svc.metrics.snapshot()["counters"]
         assert snap["plan_cache_misses"] == 1
         assert snap["plan_cache_hits"] == 1
         assert snap["plans_planned"] == 1
+
+    def test_plan_spans_in_profiler_trace(self, tmp_path):
+        """The planner's spans land in the host plane of a profiler trace,
+        with or without a recorder."""
+        import glob
+
+        import jax
+
+        svc = _svc()
+        with jax.profiler.trace(str(tmp_path)):
+            svc.plan_record("gatherv", self.SIZES, root=0, row_bytes=4)
+            svc.plan_record("gatherv", self.SIZES, root=0, row_bytes=4)
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True)
+        pd = jax.profiler.ProfileData.from_file(path)
+        names = [e.name for plane in pd.planes
+                 if plane.name == "/host:CPU"
+                 for line in plane.lines for e in line.events]
+        assert names.count("plan/gatherv") == 2
 
     def test_tracing_off_is_noop(self):
         prev = obs_trace.current()

@@ -13,6 +13,7 @@ The topology is described in a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and under
 pytest-xdist every worker imports this file.
 """
+import functools
 import os
 import re
 
@@ -27,6 +28,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 import chip_smoke
 from repro.core import jax_collectives as jc
 from repro.kernels.ragged_gather import ops
+from repro.kernels.ragged_gather.kernel import KERNEL_NAMES
 
 HBM_BYTES = 16 * 2**30          # one v5e chip
 WIDTHS = {"mixtral-8x7b": 4096, "deepseek-moe-16b": 2048}
@@ -90,13 +92,10 @@ def _fits(compiled) -> int:
     return used
 
 
-@pytest.mark.parametrize("F", sorted(WIDTHS.values()))
-@pytest.mark.parametrize("op", ["slab_extract", "slab_merge", "slab_step",
-                                "slab_merge_add", "slab_step_reduce"])
-def test_slab_kernel_compiles_at_moe_width(op, F, one_chip, pallas):
-    """Each slab kernel at the buffer, payloads and offsets of the first
-    transfer of the mixtral dispatch plan whose send and receive rows
-    are both unaligned."""
+def _slab_program(op: str, F: int, sharding):
+    """``(fn, args)``: slab kernel ``op`` at the buffer, payloads and
+    offsets of the first transfer of the mixtral dispatch plan whose send
+    and receive rows are both unaligned, at row width ``F``."""
     plan = _plan("mixtral-8x7b", "alltoallv")
     k, src, dst = next((k, s, d) for k, step in enumerate(plan.steps[:-1])
                        for s, d in step[0]
@@ -106,9 +105,8 @@ def test_slab_kernel_compiles_at_moe_width(op, F, one_chip, pallas):
 
     def shape(rows):
         return jax.ShapeDtypeStruct((rows,) + row, jnp.bfloat16,
-                                    sharding=one_chip)
+                                    sharding=sharding)
 
-    buf, slab = shape(plan.buf_rows), shape(payload)
     fn = getattr(ops, op)
     body = {
         "slab_extract": lambda b, s: fn(b, int(send[src]), payload,
@@ -125,9 +123,40 @@ def test_slab_kernel_compiles_at_moe_width(op, F, one_chip, pallas):
                                             int(nxt[2][dst]), nxt[1],
                                             interpret=False),
     }[op]
-    compiled = jax.jit(body).lower(buf, slab).compile()
+    return body, (shape(plan.buf_rows), shape(payload))
+
+
+@pytest.mark.parametrize("F", sorted(WIDTHS.values()))
+@pytest.mark.parametrize("op", ["slab_extract", "slab_merge", "slab_step",
+                                "slab_merge_add", "slab_step_reduce"])
+def test_slab_kernel_compiles_at_moe_width(op, F, one_chip, pallas):
+    """Each slab kernel at the buffer, payloads and offsets of the first
+    transfer of the mixtral dispatch plan whose send and receive rows
+    are both unaligned."""
+    body, args = _slab_program(op, F, one_chip)
+    compiled = jax.jit(body).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     _fits(compiled)
+
+
+@pytest.mark.parametrize("name", KERNEL_NAMES)
+def test_kernel_instruction_carries_its_name(name, one_chip, pallas):
+    """Every kernel is an HLO custom-call named after it, which is the
+    name a device trace gives it."""
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    if name == "ragged_gather":
+        body = functools.partial(ops.ragged_gather, interpret=False)
+        args = (arg((512, 128), jnp.float32), arg((256,), jnp.int32))
+    elif name == "ragged_scatter":
+        body = functools.partial(ops.ragged_scatter, n_out=512,
+                                 interpret=False)
+        args = (arg((256, 128), jnp.float32), arg((256,), jnp.int32))
+    else:
+        body, args = _slab_program(name, WIDTHS["mixtral-8x7b"], one_chip)
+    hlo = jax.jit(body).lower(*args).compile().as_text()
+    assert re.search(rf"%{name}(\.\d+)* = [^\n]* custom-call\(", hlo), name
 
 
 @pytest.mark.parametrize("model", sorted(WIDTHS))
@@ -152,6 +181,10 @@ def test_executor_compiles_on_2x2_mesh(op, model, mesh4, pallas):
     compiled = fn.lower(x).compile()
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo
+    # the entry and exit row-view relayouts carry their phase's scope
+    scopes = {scope for name in re.findall(r'op_name="([^"]*)"', hlo)
+              for scope in name.split("/")}
+    assert {jc.RELAYOUT_IN, jc.RELAYOUT_OUT} <= scopes, scopes
     permutes = len(re.findall(r" collective-permute(?:-start)?\(", hlo))
     assert permutes >= len(plan.steps), permutes
     _fits(compiled)
