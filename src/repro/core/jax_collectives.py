@@ -393,18 +393,49 @@ def _scoped(name: str, fn):
 # lands in the ``op_name`` metadata of every op traced inside it, which a
 # profiler shows as the op's ``tf_op``; it changes no op of the program.
 FILL = "ragged.fill"                  # capacity buffer: zeros + own input
-RELAYOUT_IN = "ragged.relayout_in"    # entry ``row_view`` of the buffer
+RELAYOUT_IN = "ragged.relayout_in"    # ``row_view`` of the executor's input
 STEP = "ragged.step"                  # each step's slab op
 PPERMUTE = "ragged.ppermute"          # each step's ``lax.ppermute``
-RELAYOUT_OUT = "ragged.relayout_out"  # exit reshape to (N, F)
+RELAYOUT_OUT = "ragged.relayout_out"  # whole buffer returned as (N, F)
 UNPACK = "ragged.unpack"              # the output taken from the buffer
 SCOPES = (FILL, RELAYOUT_IN, STEP, PPERMUTE, RELAYOUT_OUT, UNPACK)
+
+
+def _fill(x_local: jax.Array, buf_rows: int, start) -> jax.Array:
+    """The capacity buffer in the data plane's row view (``_slab_ops``'
+    ``view``): ``buf_rows`` zero rows with ``x_local``'s rows written at
+    row ``start``.  Only the input is relaid; the buffer is built in the
+    view and stays in it through the steps to the unpack."""
+    view = _slab_ops()[3]
+    with jax.named_scope(RELAYOUT_IN):
+        x = view(x_local)
+    with jax.named_scope(FILL):
+        buf = jnp.zeros((buf_rows,) + x.shape[1:], x.dtype)
+        # spill rows past the input are later overwritten by received
+        # ranges (module docstring invariant)
+        return jax.lax.dynamic_update_slice(
+            buf, x, (start,) + (jnp.int32(0),) * (x.ndim - 1))
+
+
+def _rows(buf: jax.Array, start, n: int, F: int) -> jax.Array:
+    """``n`` rows of the viewed buffer from row ``start``, as (n, F): only
+    the rows taken are relaid."""
+    at = (start,) + (jnp.int32(0),) * (buf.ndim - 1)
+    return jax.lax.dynamic_slice(buf, at, (n,) + buf.shape[1:]).reshape(n, F)
+
+
+def _flat(buf: jax.Array, F: int) -> jax.Array:
+    """The whole viewed buffer as (N, F): the output's own relayout."""
+    with jax.named_scope(RELAYOUT_OUT):
+        return buf.reshape(buf.shape[0], F)
 
 
 def _apply_steps(buf: jax.Array, steps, r, axis_name: str,
                  reduce: bool = False) -> jax.Array:
     """Run ppermute step tables over a flat row buffer (shared by the
-    gatherv, scatterv, and composed executors).  Each step: extract the
+    gatherv, scatterv, and composed executors).  The buffer comes in the
+    data plane's row view (``_slab_ops``' ``view``; ``_fill`` builds it
+    there) and leaves in it, never relaid here.  Each step: extract the
     ``payload``-row slab at the device's send offset, permute ONLY that
     slab (never the whole capacity buffer), merge the valid prefix at the
     device's receive offset (same flat offset: zero-copy invariant).
@@ -424,10 +455,7 @@ def _apply_steps(buf: jax.Array, steps, r, axis_name: str,
     """
     if not steps:
         return buf
-    extract, merge, step, view = _slab_ops(reduce)
-    shape = buf.shape
-    with jax.named_scope(RELAYOUT_IN):
-        buf = view(buf)
+    extract, merge, step, _ = _slab_ops(reduce)
     _, payload0, send0, _, _ = steps[0]
     with jax.named_scope(STEP):
         out = extract(buf, jnp.asarray(send0)[r], payload0)
@@ -444,8 +472,7 @@ def _apply_steps(buf: jax.Array, steps, r, axis_name: str,
                                 npayload)
             else:
                 buf = merge(buf, got, r0, nv)
-    with jax.named_scope(RELAYOUT_OUT):
-        return buf.reshape(shape)
+    return buf
 
 
 def gatherv_shard(x_local: jax.Array, plan: GathervPlan, axis_name: str) -> jax.Array:
@@ -454,15 +481,11 @@ def gatherv_shard(x_local: jax.Array, plan: GathervPlan, axis_name: str) -> jax.
     rank order.  Call under shard_map with in/out specs P(axis_name).
     """
     r = jax.lax.axis_index(axis_name)
-    F = x_local.shape[1]
     offs = jnp.asarray(plan.offsets, jnp.int32)
-    with jax.named_scope(FILL):
-        buf = jnp.zeros((plan.buf_rows, F), x_local.dtype)
-        # write own (padded) block at its global offset; spill rows are
-        # later overwritten by received ranges (module docstring invariant)
-        buf = jax.lax.dynamic_update_slice(buf, x_local,
-                                           (offs[r], jnp.int32(0)))
-    return _apply_steps(buf, plan.steps, r, axis_name)
+    # own (padded) block at its global offset
+    buf = _fill(x_local, plan.buf_rows, offs[r])
+    return _flat(_apply_steps(buf, plan.steps, r, axis_name),
+                 x_local.shape[1])
 
 
 def _reversed_step_tables(plan: "GathervPlan") -> tuple[tuple, ...]:
@@ -495,12 +518,12 @@ def scatterv_shard(buf_root: jax.Array, plan: GathervPlan, axis_name: str) -> ja
     Returns the local (cap, F) block for every device.
     """
     r = jax.lax.axis_index(axis_name)
-    F = buf_root.shape[1]
     offs = jnp.asarray(plan.offsets, jnp.int32)
-    buf = _apply_steps(buf_root, _reversed_step_tables(plan), r, axis_name)
+    with jax.named_scope(RELAYOUT_IN):   # the input is the whole buffer
+        buf = _slab_ops()[3](buf_root)
+    buf = _apply_steps(buf, _reversed_step_tables(plan), r, axis_name)
     with jax.named_scope(UNPACK):
-        return jax.lax.dynamic_slice(buf, (offs[r], jnp.int32(0)),
-                                     (plan.cap, F))
+        return _rows(buf, offs[r], plan.cap, buf_root.shape[1])
 
 
 # --------------------------------------------------------------------------
@@ -897,13 +920,10 @@ def allgatherv_shard(x_local: jax.Array, plan: ComposedPlan,
     Returns (buf_rows, F); rows [0:total] hold all blocks in rank order on
     EVERY device (gather rounds, then broadcast rounds)."""
     r = jax.lax.axis_index(axis_name)
-    F = x_local.shape[1]
     starts = jnp.asarray(plan.in_starts, jnp.int32)
-    with jax.named_scope(FILL):
-        buf = jnp.zeros((plan.buf_rows, F), x_local.dtype)
-        buf = jax.lax.dynamic_update_slice(buf, x_local,
-                                           (starts[r], jnp.int32(0)))
-    return _apply_steps(buf, plan.steps, r, axis_name)
+    buf = _fill(x_local, plan.buf_rows, starts[r])
+    return _flat(_apply_steps(buf, plan.steps, r, axis_name),
+                 x_local.shape[1])
 
 
 def alltoallv_shard(x_local: jax.Array, plan: ComposedPlan,
@@ -915,10 +935,7 @@ def alltoallv_shard(x_local: jax.Array, plan: ComposedPlan,
     r = jax.lax.axis_index(axis_name)
     F = x_local.shape[1]
     starts = jnp.asarray(plan.in_starts, jnp.int32)
-    with jax.named_scope(FILL):
-        buf = jnp.zeros((plan.buf_rows, F), x_local.dtype)
-        buf = jax.lax.dynamic_update_slice(buf, x_local,
-                                           (starts[r], jnp.int32(0)))
+    buf = _fill(x_local, plan.buf_rows, starts[r])
     buf = _apply_steps(buf, plan.steps, r, axis_name)
     with jax.named_scope(UNPACK):
         out = jnp.zeros((plan.out_rows, F), x_local.dtype)
@@ -927,8 +944,7 @@ def alltoallv_shard(x_local: jax.Array, plan: ComposedPlan,
             s0 = jnp.asarray(src_start)[r]
             d0 = jnp.asarray(dst_start)[r]
             nv = jnp.asarray(valid)[r]
-            blk = jax.lax.dynamic_slice(buf, (s0, jnp.int32(0)),
-                                        (plan.chunk, F))
+            blk = _rows(buf, s0, plan.chunk, F)
             cur = jax.lax.dynamic_slice(out, (d0, jnp.int32(0)),
                                         (plan.chunk, F))
             upd = jnp.where(mask_rows < nv, blk, cur)
@@ -1243,16 +1259,11 @@ def reduce_scatterv_shard(x_local: jax.Array, plan: ReduceScattervPlan,
     ``offsets[j]``).  Returns (cap, F); rows [0:sizes[r]] on device ``r``
     hold ``sum_i x_i[offsets[r]: offsets[r]+sizes[r]]``."""
     r = jax.lax.axis_index(axis_name)
-    F = x_local.shape[1]
     offs = jnp.asarray(plan.offsets, jnp.int32)
-    with jax.named_scope(FILL):
-        buf = jnp.zeros((plan.buf_rows, F), x_local.dtype)
-        buf = jax.lax.dynamic_update_slice(buf, x_local,
-                                           (jnp.int32(0), jnp.int32(0)))
+    buf = _fill(x_local, plan.buf_rows, jnp.int32(0))
     buf = _apply_steps(buf, plan.steps, r, axis_name, reduce=True)
     with jax.named_scope(UNPACK):
-        return jax.lax.dynamic_slice(buf, (offs[r], jnp.int32(0)),
-                                     (plan.cap, F))
+        return _rows(buf, offs[r], plan.cap, x_local.shape[1])
 
 
 def allreducev_shard(x_local: jax.Array, plan: AllreducevPlan,
@@ -1264,13 +1275,10 @@ def allreducev_shard(x_local: jax.Array, plan: AllreducevPlan,
     start state — so the gather steps run directly on the same buffer
     with overwrite semantics."""
     r = jax.lax.axis_index(axis_name)
-    F = x_local.shape[1]
-    with jax.named_scope(FILL):
-        buf = jnp.zeros((plan.buf_rows, F), x_local.dtype)
-        buf = jax.lax.dynamic_update_slice(buf, x_local,
-                                           (jnp.int32(0), jnp.int32(0)))
+    buf = _fill(x_local, plan.buf_rows, jnp.int32(0))
     buf = _apply_steps(buf, plan.rs.steps, r, axis_name, reduce=True)
-    return _apply_steps(buf, plan.ag.steps, r, axis_name)
+    return _flat(_apply_steps(buf, plan.ag.steps, r, axis_name),
+                 x_local.shape[1])
 
 
 def run_reduce_scatterv(mesh: Mesh, axis_name, contribs: list[np.ndarray],

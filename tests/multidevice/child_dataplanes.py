@@ -1,0 +1,68 @@
+"""Every executor on the ``"interpret"`` slab data plane (the Pallas
+kernels on the kernels' row view) against the ``"xla"`` one (the jnp
+oracles on the flat buffer), on 4 forced CPU devices, at a width whose
+row view is (N, 2, 128) and at one whose row view is (N, 1, 96).  Prints
+one ``DATAPLANE <op> <F> <equal|differ|zero>`` line per executor and
+width.  Subprocess-only (XLA_FLAGS):
+
+    PYTHONPATH=src python tests/multidevice/child_dataplanes.py
+"""
+import os
+
+os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
+
+import numpy as np  # noqa: E402
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import AxisType, PartitionSpec as P  # noqa: E402
+
+from repro.core import jax_collectives as jc  # noqa: E402
+
+OPS = ("gatherv", "scatterv", "allgatherv", "alltoallv", "reduce_scatterv",
+       "allreducev")
+WIDTHS = (256, 96)
+SIZES = [13, 0, 21, 6]                  # offsets 0, 13, 13, 34: unaligned
+S = [[3, 0, 5, 2], [7, 1, 0, 4], [0, 6, 2, 1], [5, 2, 0, 0]]
+
+
+def plan(op: str):
+    return {"gatherv": lambda: jc.plan_gatherv(SIZES, 1),
+            "scatterv": lambda: jc.plan_gatherv(SIZES, 1),
+            "allgatherv": lambda: jc.plan_allgatherv(SIZES),
+            "alltoallv": lambda: jc.plan_alltoallv(S),
+            "reduce_scatterv": lambda: jc.plan_reduce_scatterv(SIZES),
+            "allreducev": lambda: jc.plan_allreducev(SIZES)}[op]()
+
+
+def run(mesh, op: str, pl, x, dataplane: str) -> np.ndarray:
+    shard = getattr(jc, op + "_shard")
+    jc.set_dataplane(dataplane)
+    fn = jax.jit(jax.shard_map(lambda xl: shard(xl, pl, "x"), mesh=mesh,
+                               in_specs=P("x"), out_specs=P("x"),
+                               check_vma=False))
+    return np.asarray(fn(x).astype(jnp.float32))
+
+
+def main():
+    assert jax.device_count() == 4, jax.devices()
+    mesh = jax.make_mesh((4,), ("x",), axis_types=(AxisType.Auto,))
+    for F in WIDTHS:
+        for k, op in enumerate(OPS):
+            pl = plan(op)
+            rows = (pl.buf_rows if op == "scatterv" else
+                    pl.in_rows if op in ("reduce_scatterv", "allreducev")
+                    else pl.cap)
+            x = jax.random.normal(jax.random.key(10 * F + k), (4 * rows, F),
+                                  jnp.bfloat16)
+            want = run(mesh, op, pl, x, "xla")
+            got = run(mesh, op, pl, x, "interpret")
+            same = want.shape == got.shape and np.array_equal(
+                want.view(np.uint32), got.view(np.uint32))
+            verdict = ("zero" if not want.any() else
+                       "equal" if same else "differ")
+            print(f"DATAPLANE {op} {F} {verdict}", flush=True)
+
+
+if __name__ == "__main__":
+    main()

@@ -92,6 +92,44 @@ def _fits(compiled) -> int:
     return used
 
 
+RELAYOUT_OPCODES = ("copy", "copy-start", "transpose")
+_INSTR = re.compile(r"\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]\S* "
+                    r"([\w\-]+)\(")
+
+
+def _computations(hlo: str) -> dict:
+    """Compiled HLO text as ``{computation: [(name, opcode, elements,
+    called computation)]}``; ``"ENTRY"`` names the entry computation.
+    Tuple-valued instructions are left out."""
+    comps, body = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(ENTRY )?%([\w.\-]+) \(", line)
+        if head:
+            body = comps.setdefault("ENTRY" if head.group(1)
+                                    else head.group(2), [])
+        elif body is not None and (m := _INSTR.match(line)):
+            name, dims, opcode = m.groups()
+            n = int(np.prod([int(d) for d in dims.split(",") if d]))
+            calls = re.search(r"calls=%([\w.\-]+)", line)
+            body.append((name, opcode, n, calls and calls.group(1)))
+    return comps
+
+
+def _whole_buffer_relayouts(hlo: str, elements: int) -> list[str]:
+    """Entry instructions that copy or transpose an array of at least
+    ``elements``, alone or inside a fusion: the relayouts of a whole
+    buffer.  Where the buffer's rows are not a multiple of the tile's 8,
+    its relayout also pads or slices it by a few rows; those ops belong
+    to the copy and are not counted apart.  Kernels are custom-calls and
+    never count."""
+    comps = _computations(hlo)
+    return [f"{name} ({opcode})"
+            for name, opcode, n, calls in comps["ENTRY"]
+            if any(op in RELAYOUT_OPCODES and m >= elements
+                   for _, op, m, _ in [(name, opcode, n, None)]
+                   + (comps.get(calls, []) if opcode == "fusion" else []))]
+
+
 def _slab_program(op: str, F: int, sharding):
     """``(fn, args)``: slab kernel ``op`` at the buffer, payloads and
     offsets of the first transfer of the mixtral dispatch plan whose send
@@ -165,8 +203,9 @@ def test_kernel_instruction_carries_its_name(name, one_chip, pallas):
                                 "allreducev"])
 def test_executor_compiles_on_2x2_mesh(op, model, mesh4, pallas):
     """The whole SPMD executor on the four described chips: the Pallas
-    kernels are in the program, one collective-permute per plan step at
-    least, and it fits one chip's HBM."""
+    kernels are in the program, no whole-buffer relayout but that of an
+    input or output which is the whole buffer, one collective-permute per
+    plan step at least, and it fits one chip's HBM."""
     plan = _plan(model, op)
     F = WIDTHS[model]
     rows = (plan.buf_rows if op == "scatterv" else
@@ -181,10 +220,11 @@ def test_executor_compiles_on_2x2_mesh(op, model, mesh4, pallas):
     compiled = fn.lower(x).compile()
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo
-    # the entry and exit row-view relayouts carry their phase's scope
-    scopes = {scope for name in re.findall(r'op_name="([^"]*)"', hlo)
-              for scope in name.split("/")}
-    assert {jc.RELAYOUT_IN, jc.RELAYOUT_OUT} <= scopes, scopes
+    # the capacity buffer lives in the kernels' row view from fill to
+    # unpack: only an input or an output that IS the whole buffer is relaid
+    moved = _whole_buffer_relayouts(hlo, plan.buf_rows * F)
+    assert len(moved) <= (0 if op in ("alltoallv", "reduce_scatterv")
+                          else 1), moved
     permutes = len(re.findall(r" collective-permute(?:-start)?\(", hlo))
     assert permutes >= len(plan.steps), permutes
     _fits(compiled)
