@@ -132,6 +132,29 @@ def dataplane() -> str:
     return _DATAPLANE
 
 
+def lane_width(F: int, dtype) -> int:
+    """The width at which the executors' capacity buffer holds (N, F) rows
+    of ``dtype``: ``ops.lane_width`` on the kernels' row view, padded
+    with zero lanes where F is not one the TPU lays out for a DMA at any
+    row; F itself on the ``"xla"`` data plane, whose view is the
+    identity."""
+    if _DATAPLANE == "xla":
+        return F
+    from repro.kernels.ragged_gather import ops
+    return ops.lane_width(F, dtype)
+
+
+def moved_row_bytes(row_bytes: int, dtype) -> int:
+    """The bytes per row the executors' ppermutes move for rows of
+    ``row_bytes`` bytes of ``dtype``: the row at :func:`lane_width`.
+    ``row_bytes`` that is not whole elements (a row-count unit) is
+    returned as it is."""
+    itemsize = jnp.dtype(dtype).itemsize
+    if row_bytes % itemsize:
+        return row_bytes
+    return lane_width(row_bytes // itemsize, dtype) * itemsize
+
+
 # --------------------------------------------------------------------------
 # plan construction (host, trace time)
 # --------------------------------------------------------------------------
@@ -398,17 +421,39 @@ STEP = "ragged.step"                  # each step's slab op
 PPERMUTE = "ragged.ppermute"          # each step's ``lax.ppermute``
 RELAYOUT_OUT = "ragged.relayout_out"  # whole buffer returned as (N, F)
 UNPACK = "ragged.unpack"              # the output taken from the buffer
-SCOPES = (FILL, RELAYOUT_IN, STEP, PPERMUTE, RELAYOUT_OUT, UNPACK)
+LANE_PAD = "ragged.lane_pad"          # rows padded to / sliced from lane_width
+SCOPES = (FILL, RELAYOUT_IN, STEP, PPERMUTE, RELAYOUT_OUT, UNPACK, LANE_PAD)
+
+
+def _pad_lanes(x: jax.Array) -> jax.Array:
+    """(N, F) rows padded with zero lanes to :func:`lane_width`; ``x``
+    itself, with no op, where F is that width already."""
+    F = x.shape[1]
+    pad = lane_width(F, x.dtype) - F
+    if not pad:
+        return x
+    with jax.named_scope(LANE_PAD):
+        return jnp.pad(x, ((0, 0), (0, pad)))
+
+
+def _unpad_lanes(x: jax.Array, F: int) -> jax.Array:
+    """The first ``F`` lanes of (N, W) rows; ``x`` itself where W is F."""
+    if x.shape[1] == F:
+        return x
+    with jax.named_scope(LANE_PAD):
+        return x[:, :F]
 
 
 def _fill(x_local: jax.Array, buf_rows: int, start) -> jax.Array:
     """The capacity buffer in the data plane's row view (``_slab_ops``'
-    ``view``): ``buf_rows`` zero rows with ``x_local``'s rows written at
-    row ``start``.  Only the input is relaid; the buffer is built in the
-    view and stays in it through the steps to the unpack."""
+    ``view``) of rows at :func:`lane_width`: ``buf_rows`` zero rows with
+    ``x_local``'s rows written at row ``start``.  Only the input is
+    padded and relaid; the buffer is built in the view and stays in it
+    through the steps to the unpack."""
     view = _slab_ops()[3]
+    x = _pad_lanes(x_local)
     with jax.named_scope(RELAYOUT_IN):
-        x = view(x_local)
+        x = view(x)
     with jax.named_scope(FILL):
         buf = jnp.zeros((buf_rows,) + x.shape[1:], x.dtype)
         # spill rows past the input are later overwritten by received
@@ -419,15 +464,17 @@ def _fill(x_local: jax.Array, buf_rows: int, start) -> jax.Array:
 
 def _rows(buf: jax.Array, start, n: int, F: int) -> jax.Array:
     """``n`` rows of the viewed buffer from row ``start``, as (n, F): only
-    the rows taken are relaid."""
+    the rows taken are relaid and cut to their F lanes."""
     at = (start,) + (jnp.int32(0),) * (buf.ndim - 1)
-    return jax.lax.dynamic_slice(buf, at, (n,) + buf.shape[1:]).reshape(n, F)
+    rows = jax.lax.dynamic_slice(buf, at, (n,) + buf.shape[1:])
+    return _unpad_lanes(rows.reshape(n, -1), F)
 
 
 def _flat(buf: jax.Array, F: int) -> jax.Array:
     """The whole viewed buffer as (N, F): the output's own relayout."""
     with jax.named_scope(RELAYOUT_OUT):
-        return buf.reshape(buf.shape[0], F)
+        out = buf.reshape(buf.shape[0], -1)
+    return _unpad_lanes(out, F)
 
 
 def _apply_steps(buf: jax.Array, steps, r, axis_name: str,
@@ -519,8 +566,9 @@ def scatterv_shard(buf_root: jax.Array, plan: GathervPlan, axis_name: str) -> ja
     """
     r = jax.lax.axis_index(axis_name)
     offs = jnp.asarray(plan.offsets, jnp.int32)
+    buf = _pad_lanes(buf_root)
     with jax.named_scope(RELAYOUT_IN):   # the input is the whole buffer
-        buf = _slab_ops()[3](buf_root)
+        buf = _slab_ops()[3](buf)
     buf = _apply_steps(buf, _reversed_step_tables(plan), r, axis_name)
     with jax.named_scope(UNPACK):
         return _rows(buf, offs[r], plan.cap, buf_root.shape[1])
@@ -621,24 +669,29 @@ def call_with_deadline(op: str, thunk):
             time.sleep(min(sleep_s * backoff ** (attempt - 1), 1.0))
 
 
-def _run_traced(op: str, plan, row_bytes: int, fn, xg) -> np.ndarray:
-    """Execute a jitted driver inside the ``run/<op>`` span.
+def _run_traced(op: str, plan, fn, xg) -> np.ndarray:
+    """Execute a jitted driver on the (rows, F) input ``xg`` inside the
+    ``run/<op>`` span.
 
     The span always lands in a running profiler's trace; when
     ``repro.obs.trace`` is enabled it is also recorded with the plan
-    shape and bytes moved.  The ``run_<op>`` counter counts every call.
-    Execution goes through :func:`call_with_deadline`, so an armed step
-    deadline (or an installed chaos fault hook) gets bounded retry and
-    escalates as :class:`CollectiveTimeout` instead of hanging.
+    shape and bytes moved, at the rows' bytes and at the bytes the
+    ppermutes move (:func:`moved_row_bytes`).  The ``run_<op>`` counter
+    counts every call.  Execution goes through :func:`call_with_deadline`,
+    so an armed step deadline (or an installed chaos fault hook) gets
+    bounded retry and escalates as :class:`CollectiveTimeout` instead of
+    hanging.
     """
     args = {}
     if obs_trace.current() is not None:
+        row_bytes = xg.shape[1] * xg.dtype.itemsize
+        moved = moved_row_bytes(row_bytes, xg.dtype)
         args = {"op": op, "p": plan.p,
                 "segments": getattr(plan, "segments", 1),
                 "num_stages": getattr(plan, "num_stages", 0),
-                "row_bytes": int(row_bytes)}
+                "row_bytes": row_bytes, "moved_row_bytes": moved}
         for cls, nb in obs_trace.plan_link_bytes(
-                plan.steps, row_bytes=int(row_bytes)).items():
+                plan.steps, row_bytes=moved).items():
             args[f"bytes_{cls}"] = nb
     with obs_trace.span("run/" + op, "collective", **args) as sp:
         t0 = time.perf_counter()
@@ -674,8 +727,7 @@ def run_gatherv(mesh: Mesh, axis_name, blocks: list[np.ndarray],
             check_vma=False)(xg)
 
     xg = jax.device_put(x, NamedSharding(mesh, P(axis_name)))
-    out = _run_traced("gatherv", plan, F * blocks[0].dtype.itemsize,
-                      run, xg)  # (p * buf_rows, F)
+    out = _run_traced("gatherv", plan, run, xg)  # (p * buf_rows, F)
     out = out.reshape(plan.p, plan.buf_rows, F)
     return out[root, : plan.total], plan
 
@@ -699,8 +751,7 @@ def run_scatterv(mesh: Mesh, axis_name, data: np.ndarray,
             check_vma=False)(xg)
 
     xg = jax.device_put(xin, NamedSharding(mesh, P(axis_name)))
-    out = _run_traced("scatterv", plan, F * data.dtype.itemsize,
-                      run, xg).reshape(plan.p, plan.cap, F)
+    out = _run_traced("scatterv", plan, run, xg).reshape(plan.p, plan.cap, F)
     return [out[i, : sizes[i]] for i in range(plan.p)], plan
 
 
@@ -980,8 +1031,8 @@ def run_allgatherv(mesh: Mesh, axis_name, blocks: list[np.ndarray],
             check_vma=False)(xg)
 
     xg = jax.device_put(x, NamedSharding(mesh, P(axis_name)))
-    out = _run_traced("allgatherv", plan, F * blocks[0].dtype.itemsize,
-                      run, xg).reshape(plan.p, plan.buf_rows, F)
+    out = _run_traced("allgatherv", plan, run, xg)
+    out = out.reshape(plan.p, plan.buf_rows, F)
     return out[:, : plan.total], plan
 
 
@@ -1018,8 +1069,7 @@ def run_alltoallv(mesh: Mesh, axis_name: str,
             check_vma=False)(xg)
 
     xg = jax.device_put(x, NamedSharding(mesh, P(axis_name)))
-    out = _run_traced("alltoallv", plan, F * dtype.itemsize,
-                      run, xg).reshape(p, plan.out_rows, F)
+    out = _run_traced("alltoallv", plan, run, xg).reshape(p, plan.out_rows, F)
     return [out[j, : plan.out_valid[j]] for j in range(p)], plan
 
 
@@ -1311,9 +1361,7 @@ def run_reduce_scatterv(mesh: Mesh, axis_name, contribs: list[np.ndarray],
             check_vma=False)(xg)
 
     xg = jax.device_put(x, NamedSharding(mesh, P(axis_name)))
-    out = _run_traced("reduce_scatterv", plan,
-                      F * contribs[0].dtype.itemsize,
-                      run, xg).reshape(p, plan.cap, F)
+    out = _run_traced("reduce_scatterv", plan, run, xg).reshape(p, plan.cap, F)
     return [out[j, : plan.sizes[j]] for j in range(p)], plan
 
 
@@ -1347,8 +1395,7 @@ def run_allreducev(mesh: Mesh, axis_name, contribs: list[np.ndarray],
             check_vma=False)(xg)
 
     xg = jax.device_put(x, NamedSharding(mesh, P(axis_name)))
-    out = _run_traced("allreducev", plan, F * contribs[0].dtype.itemsize,
-                      run, xg).reshape(p, plan.buf_rows, F)
+    out = _run_traced("allreducev", plan, run, xg).reshape(p, plan.buf_rows, F)
     return out[:, : plan.total], plan
 
 

@@ -22,10 +22,34 @@ def row_view(buf):
     """The (N, F // 128, 128) view of an (N, F) buffer, or (N, 1, F) when
     F is not a multiple of 128.  The TPU tiles an array's last two
     dimensions, so only in this view can the slab kernels' DMAs start at
-    any row; the executor hands them this view."""
+    any row; the executor hands them this view of rows it has padded to
+    :func:`lane_width`."""
     n, f = buf.shape
     lanes = 128 if f % 128 == 0 else f
     return buf.reshape(n, f // lanes, lanes)
+
+
+def lane_width(f: int, dtype) -> int:
+    """The row width at which the slab kernels run (N, f) rows of
+    ``dtype``: the least width >= f whose :func:`row_view` the TPU lays
+    out so that a DMA can start at any row.
+
+    32-bit rows need whole 128-lane groups, which every multiple of 128
+    is.  Packed rows (16- and 8-bit, ``4 // itemsize`` to a word) also
+    need a group count that is a multiple of 8 or a power of two no
+    smaller than the packing: in bf16, 2688 (21 groups) runs at 3072 and
+    128 at 256, while 2048 and 4096 run as they are.  A width that is not
+    whole lane groups is returned as it is; its (N, 1, f) view runs
+    interpreted only."""
+    if f % 128:
+        return f
+    groups = f // 128
+    pack = 4 // jnp.dtype(dtype).itemsize
+    if pack > 1 and groups % 8:
+        groups = max(groups, pack)
+        groups = (1 << (groups - 1).bit_length() if groups < 8
+                  else -(-groups // 8) * 8)
+    return 128 * groups
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))

@@ -28,12 +28,14 @@ import pickle
 from collections import OrderedDict
 from dataclasses import dataclass
 
-CACHE_VERSION = 7  # v7: schedule zoo — the exact-DP opt trees, PAT,
-                   # van-de-Geijn ring and binomial-broadcast candidates
-                   # joined the enumeration (new candidate names, opt
-                   # construction memoized per quantized signature), and
-                   # reduction plans became health-shaped; older stores
-                   # predate those candidates and are discarded wholesale
+CACHE_VERSION = 8  # v8: a PlanRecord carries the row bytes it was given
+                   # and the moved row bytes it was priced at, and a key
+                   # names the moved bytes where rows are lane-padded
+# v7: schedule zoo — the exact-DP opt trees, PAT, van-de-Geijn ring and
+# binomial-broadcast candidates joined the enumeration (new candidate
+# names, opt construction memoized per quantized signature), and
+# reduction plans became health-shaped; older stores predate those
+# candidates and are discarded wholesale
 # v6: telemetry plane — PlanKey grows a params-epoch field
 # (drift-triggered refits bump it, honestly invalidating every plan
 # priced under the stale (α, β)); older stores carry epoch-less tokens

@@ -68,7 +68,7 @@ from repro.core.costmodel import (CostParams, DegradedCostParams,
                                   LinkHealthMap)
 from repro.obs import trace as obs_trace
 from repro.obs.guidelines_monitor import GuidelineMonitor
-from repro.obs.metrics import Registry
+from repro.obs.metrics import REGISTRY as _OBS_REGISTRY, Registry
 from repro.obs.residuals import DriftDetector, ResidualLedger
 
 from .cache import (PlanCache, PlanKey, mesh_fingerprint, quantize_matrix,
@@ -78,6 +78,13 @@ from .calibrate import (Calibration, HierarchicalCalibration,
                         flat_weights, hierarchical_weights)
 from .candidates import OPS, enumerate_candidates, plan_pipeline_cost
 from .select import Selection, select
+
+
+def _moved_row_bytes(row_bytes: int, dtype: str) -> int:
+    """``jax_collectives.moved_row_bytes``, imported on first use: the
+    planner itself needs no JAX."""
+    from repro.core.jax_collectives import moved_row_bytes
+    return moved_row_bytes(int(row_bytes), dtype)
 
 
 @dataclass(frozen=True)
@@ -95,6 +102,8 @@ class PlanRecord:
     algo: str                              # winning candidate name
     costs: tuple[tuple[str, float], ...]   # full scoreboard at plan time
     serial: str = ""
+    row_bytes: int = 1                     # bytes of one row, as given
+    moved_row_bytes: int = 1               # what the ppermutes move per row
 
 
 class _RowScaledCalibrator:
@@ -263,7 +272,9 @@ class PlannerService:
         return quantize_sizes(sizes, self.quantum)
 
     def _key(self, op: str, arg, root: int | None, dtype: str,
-             row_bytes: int) -> PlanKey:
+             row_bytes: int, moved: int | None = None) -> PlanKey:
+        if moved is None:
+            moved = _moved_row_bytes(row_bytes, dtype)
         if op == "alltoallv":
             sig = quantize_matrix(arg, self.quantum)
             p = len(sig)
@@ -277,9 +288,11 @@ class PlannerService:
             # epoch bump on every health change (suspenders): a plan
             # selected on a degraded machine never serves the healed one
             mesh = f"{mesh}|{hf}"
+        width = f"{dtype}r{int(row_bytes)}"
+        if moved != row_bytes:        # rows padded on this data plane
+            width += f"m{int(moved)}"
         return PlanKey(op, p, sig, -1 if root is None else int(root),
-                       f"{dtype}r{int(row_bytes)}", mesh,
-                       epoch=self.params_epoch)
+                       width, mesh, epoch=self.params_epoch)
 
     def _sel_params(self, row_bytes: int):
         """Selection/prediction params in BYTES: per-row β scaled by the
@@ -308,20 +321,27 @@ class PlannerService:
         if op in ("gatherv", "scatterv") and root is None:
             raise ValueError(f"{op} needs a root")
         with obs_trace.span("plan/" + op, "planner", op=op) as sp:
-            key = self._key(op, arg, root, dtype, row_bytes)
+            moved = _moved_row_bytes(row_bytes, dtype)
+            # the module registry: a reader that holds no service finds it
+            _OBS_REGISTRY.gauge("moved_row_bytes").set(moved)
+            key = self._key(op, arg, root, dtype, row_bytes, moved)
             rec = self.cache.get(key)
             sp.args["hit"] = rec is not None
             if rec is None:
-                rec = self._plan_miss(op, key, root, row_bytes, sp.args)
+                rec = self._plan_miss(op, key, root, row_bytes, moved,
+                                      sp.args)
         return rec
 
     def _plan_miss(self, op: str, key: PlanKey, root: int | None,
-                   row_bytes: int, span_args: dict) -> PlanRecord:
+                   row_bytes: int, moved: int,
+                   span_args: dict) -> PlanRecord:
         """Enumerate, select and lower the plan of ``key``, store it, and
-        fill the ``plan/<op>`` span's args with how it was chosen."""
+        fill the ``plan/<op>`` span's args with how it was chosen.  The
+        plan is priced at ``moved`` bytes a row, what its ppermutes move
+        (``jax_collectives.moved_row_bytes`` of ``row_bytes``)."""
         qarg = key.signature
         # selection params in bytes: scale the per-row β by the row width
-        rb = max(1, int(row_bytes))
+        rb = max(1, int(moved))
         sel_params = self._sel_params(rb)
         cands = enumerate_candidates(op, qarg, root, sel_params,
                                      view="dataplane", buckets=self.buckets,
@@ -362,14 +382,16 @@ class PlannerService:
                            else fit.cost_params())
         rec = PlanRecord(op=op, plan=sel.candidate(cands).build(),
                          algo=sel.chosen, costs=sel.costs,
-                         serial=uuid.uuid4().hex)
+                         serial=uuid.uuid4().hex, row_bytes=int(row_bytes),
+                         moved_row_bytes=rb)
         self.cache.put(key, rec)
         self.metrics.counter("plans_planned").inc()
         if sel.measured:
             self.metrics.counter("candidates_raced").inc(len(sel.measured))
         span_args.update(
             p=key.p, token=token, algo=sel.chosen, cost=sel.cost,
-            epoch=self.params_epoch, row_bytes=rb, candidates=len(cands),
+            epoch=self.params_epoch, row_bytes=int(row_bytes),
+            moved_row_bytes=rb, candidates=len(cands),
             raced=[n for n, _ in sel.measured] if sel.measured else [],
             kept_previous=sel.kept_previous)
         return rec
@@ -442,11 +464,13 @@ class PlannerService:
 
     # ----------------------------------------------------------- telemetry
 
-    def _run(self, op: str, rec: PlanRecord, fn, x, row_bytes: int,
-             arg=None, root: int | None = None) -> np.ndarray:
+    def _run(self, op: str, rec: PlanRecord, fn, x, arg=None,
+             root: int | None = None) -> np.ndarray:
         """Execute a compiled plan inside the ``exec/<op>`` span, count it,
-        and deposit its wall time into the residual/guideline plane."""
+        and deposit its wall time into the residual/guideline plane, priced
+        at the row bytes the plan was priced at."""
         fresh = self._just_compiled
+        row_bytes = rec.moved_row_bytes
         args = {}
         if obs_trace.current() is not None:
             args = self._exec_span_args(op, rec, row_bytes, fresh)
@@ -755,7 +779,7 @@ class PlannerService:
         for i, b in enumerate(blocks):
             x[i, : sizes[i]] = b
         out = self._run("gatherv", rec, fn, x.reshape(plan.p * plan.cap, F),
-                        row_bytes=F * dt.itemsize, arg=sizes, root=root)
+                        arg=sizes, root=root)
         out = out.reshape(plan.p, plan.buf_rows, F)
         res, off = [], 0
         for i, s in enumerate(sizes):
@@ -782,7 +806,7 @@ class PlannerService:
             off_q += plan.sizes[i]
         out = self._run("scatterv", rec, fn,
                         xin.reshape(plan.p * plan.buf_rows, F),
-                        row_bytes=F * dt.itemsize, arg=sizes, root=root)
+                        arg=sizes, root=root)
         out = out.reshape(plan.p, plan.cap, F)
         return [out[i, : sizes[i]] for i in range(plan.p)], plan
 
@@ -801,8 +825,7 @@ class PlannerService:
         for i, b in enumerate(blocks):
             x[i, : sizes[i]] = b
         out = self._run("allgatherv", rec, fn,
-                        x.reshape(plan.p * plan.cap, F),
-                        row_bytes=F * dt.itemsize, arg=sizes)
+                        x.reshape(plan.p * plan.cap, F), arg=sizes)
         out = out.reshape(plan.p, plan.buf_rows, F)
         keep = []
         for i, s in enumerate(sizes):
@@ -831,7 +854,7 @@ class PlannerService:
                 x[i, off: off + S[i][j]] = b
                 off += Sq[i, j]
         out = self._run("alltoallv", rec, fn, x.reshape(p * plan.cap, F),
-                        row_bytes=F * dt.itemsize, arg=S)
+                        arg=S)
         out = out.reshape(p, plan.out_rows, F)
         res = []
         for j in range(p):
@@ -867,8 +890,7 @@ class PlannerService:
                 off_true += s
                 off_q += plan.sizes[j]    # quantized stride
         out = self._run("reduce_scatterv", rec, fn,
-                        x.reshape(p * plan.in_rows, F),
-                        row_bytes=F * dt.itemsize, arg=sizes)
+                        x.reshape(p * plan.in_rows, F), arg=sizes)
         out = out.reshape(p, plan.cap, F)
         return [out[j, : sizes[j]] for j in range(p)], plan
 
@@ -893,8 +915,7 @@ class PlannerService:
                 off_true += s
                 off_q += plan.sizes[j]
         out = self._run("allreducev", rec, fn,
-                        x.reshape(p * plan.in_rows, F),
-                        row_bytes=F * dt.itemsize, arg=sizes)
+                        x.reshape(p * plan.in_rows, F), arg=sizes)
         out = out.reshape(p, plan.buf_rows, F)
         keep, off_q = [], 0
         for j, s in enumerate(sizes):

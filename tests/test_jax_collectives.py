@@ -83,20 +83,19 @@ SCOPE_SIZES = [5, 0, 9, 3]
 SCOPE_S = [[2, 0, 1, 3], [4, 1, 0, 2], [0, 3, 3, 1], [1, 2, 0, 0]]
 
 
-@pytest.mark.parametrize("op", ["gatherv", "scatterv", "allgatherv",
-                                "alltoallv", "reduce_scatterv",
-                                "allreducev"])
-def test_executor_phases_carry_scopes(op):
-    """Each executor, lowered for a 4-rank mesh on the ``"xla"`` data
-    plane, names its phases in the ``op_name`` metadata: the fill of the
-    capacity buffer, every ppermute and every step's slab op, and the
-    output taken from the buffer where the executor takes one.  The
-    process has one CPU device, so the program is lowered against an
-    abstract mesh, which needs no devices."""
+EXECUTORS = ["gatherv", "scatterv", "allgatherv", "alltoallv",
+             "reduce_scatterv", "allreducev"]
+
+
+def _lowered_scopes(op: str, dataplane: str, F: int, dtype) -> tuple:
+    """``(scopes, hlo)``: the executor of ``op`` lowered for a 4-rank mesh
+    on ``dataplane`` at (rows, F) ``dtype`` input, and every name in its
+    ops' ``op_name`` metadata.  The process has one CPU device, so the
+    program is lowered against an abstract mesh, which needs no
+    devices."""
     import re
 
     import jax
-    import jax.numpy as jnp
     from jax.sharding import AbstractMesh, NamedSharding, PartitionSpec as P
 
     from repro.core import jax_collectives as jc
@@ -113,12 +112,12 @@ def test_executor_phases_carry_scopes(op):
     mesh = AbstractMesh((4,), ("x",))
     shard = getattr(jc, op + "_shard")
     prev = jc.dataplane()
-    jc.set_dataplane("xla")
+    jc.set_dataplane(dataplane)
     try:
         fn = jax.jit(jax.shard_map(lambda xl: shard(xl, plan, "x"),
                                    mesh=mesh, in_specs=P("x"),
                                    out_specs=P("x"), check_vma=False))
-        x = jax.ShapeDtypeStruct((4 * rows, 8), jnp.float32,
+        x = jax.ShapeDtypeStruct((4 * rows, F), dtype,
                                  sharding=NamedSharding(mesh, P("x")))
         lowered = fn.trace(x).lower(lowering_platforms=("cpu",))
     finally:
@@ -126,6 +125,22 @@ def test_executor_phases_carry_scopes(op):
     hlo = lowered.compiler_ir("hlo").as_hlo_module().to_string()
     scopes = {scope for name in re.findall(r'op_name="([^"]*)"', hlo)
               for scope in name.split("/")}
+    return scopes, hlo
+
+
+@pytest.mark.parametrize("op", EXECUTORS)
+def test_executor_phases_carry_scopes(op):
+    """Each executor, lowered for a 4-rank mesh on the ``"xla"`` data
+    plane, names its phases in the ``op_name`` metadata: the fill of the
+    capacity buffer, every ppermute and every step's slab op, and the
+    output taken from the buffer where the executor takes one."""
+    import re
+
+    import jax.numpy as jnp
+
+    from repro.core import jax_collectives as jc
+
+    scopes, hlo = _lowered_scopes(op, "xla", 8, jnp.float32)
     want = {jc.PPERMUTE, jc.STEP}
     if op != "scatterv":                  # scatterv runs on its input
         want.add(jc.FILL)
@@ -136,3 +151,19 @@ def test_executor_phases_carry_scopes(op):
     # the slab ops carry the name of the kernel they stand for
     steps = re.findall(r'op_name="ragged\.step/([^/"]*)/', hlo)
     assert set(steps) <= set(KERNEL_NAMES) and steps, steps
+
+
+@pytest.mark.parametrize("op", EXECUTORS)
+def test_lane_padded_rows_carry_lane_pad_scope(op):
+    """On the kernels' data plane, rows of 384 bf16 lanes (3 lane groups)
+    are padded to 512 where they enter the capacity buffer and cut back
+    where they leave it, both under ``ragged.lane_pad``; 256 lanes need
+    neither."""
+    import jax.numpy as jnp
+
+    from repro.core import jax_collectives as jc
+
+    scopes, _ = _lowered_scopes(op, "interpret", 384, jnp.bfloat16)
+    assert jc.LANE_PAD in scopes and jc.STEP in scopes, scopes
+    scopes, _ = _lowered_scopes(op, "interpret", 256, jnp.bfloat16)
+    assert jc.LANE_PAD not in scopes and jc.STEP in scopes, scopes

@@ -7,7 +7,9 @@ would refuse — slices not aligned to the tiling, blocks larger than
 VMEM, programs larger than HBM — none of which interpret mode sees.
 The shapes are the real ones: the rows, payloads and offsets of a
 4-rank dispatch plan at the row widths of mixtral-8x7b (4096) and
-deepseek-moe-16b (2048), in bf16.
+deepseek-moe-16b (2048), in bf16, and at the MoE widths the kernels take
+only lane-padded (``ops.lane_width``): 2688 (Nemotron-3-Nano-30B-A3B),
+2304, 2560, 3584, 4608 and 7680.
 
 The topology is described in a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and under
@@ -32,6 +34,9 @@ from repro.kernels.ragged_gather.kernel import KERNEL_NAMES
 
 HBM_BYTES = 16 * 2**30          # one v5e chip
 WIDTHS = {"mixtral-8x7b": 4096, "deepseek-moe-16b": 2048}
+# MoE hidden sizes of bf16 rows whose lane-group count is not a multiple
+# of 8: the kernels run them at ``ops.lane_width``
+PADDED_WIDTHS = (2688, 2304, 2560, 3584, 4608, 7680)
 
 
 @pytest.fixture(scope="module")
@@ -139,7 +144,8 @@ def _slab_program(op: str, F: int, sharding):
                        for s, d in step[0]
                        if step[2][s] % 8 and step[3][d] % 8)
     (perm, payload, send, recv, valid), nxt = plan.steps[k:k + 2]
-    row = ops.row_view(jnp.zeros((1, F))).shape[1:]
+    row = ops.row_view(jnp.zeros((1, ops.lane_width(F, jnp.bfloat16)))
+                       ).shape[1:]
 
     def shape(rows):
         return jax.ShapeDtypeStruct((rows,) + row, jnp.bfloat16,
@@ -164,13 +170,14 @@ def _slab_program(op: str, F: int, sharding):
     return body, (shape(plan.buf_rows), shape(payload))
 
 
-@pytest.mark.parametrize("F", sorted(WIDTHS.values()))
+@pytest.mark.parametrize("F", sorted(WIDTHS.values()) + list(PADDED_WIDTHS))
 @pytest.mark.parametrize("op", ["slab_extract", "slab_merge", "slab_step",
                                 "slab_merge_add", "slab_step_reduce"])
 def test_slab_kernel_compiles_at_moe_width(op, F, one_chip, pallas):
     """Each slab kernel at the buffer, payloads and offsets of the first
     transfer of the mixtral dispatch plan whose send and receive rows
-    are both unaligned."""
+    are both unaligned, in the row view of the width the executor holds
+    the rows at."""
     body, args = _slab_program(op, F, one_chip)
     compiled = jax.jit(body).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -197,17 +204,17 @@ def test_kernel_instruction_carries_its_name(name, one_chip, pallas):
     assert re.search(rf"%{name}(\.\d+)* = [^\n]* custom-call\(", hlo), name
 
 
-@pytest.mark.parametrize("model", sorted(WIDTHS))
-@pytest.mark.parametrize("op", ["gatherv", "scatterv", "allgatherv",
-                                "alltoallv", "reduce_scatterv",
-                                "allreducev"])
-def test_executor_compiles_on_2x2_mesh(op, model, mesh4, pallas):
-    """The whole SPMD executor on the four described chips: the Pallas
+EXECUTORS = ["gatherv", "scatterv", "allgatherv", "alltoallv",
+             "reduce_scatterv", "allreducev"]
+
+
+def _compile_executor(op: str, model: str, F: int, mesh4) -> str:
+    """The whole SPMD executor of ``op`` for ``model``'s dispatch plan at
+    (rows, F) bf16 on the four described chips, compiled: the Pallas
     kernels are in the program, no whole-buffer relayout but that of an
     input or output which is the whole buffer, one collective-permute per
-    plan step at least, and it fits one chip's HBM."""
+    plan step at least, and it fits one chip's HBM.  Returns its HLO."""
     plan = _plan(model, op)
-    F = WIDTHS[model]
     rows = (plan.buf_rows if op == "scatterv" else
             plan.in_rows if op in ("reduce_scatterv", "allreducev") else
             plan.cap)
@@ -228,3 +235,23 @@ def test_executor_compiles_on_2x2_mesh(op, model, mesh4, pallas):
     permutes = len(re.findall(r" collective-permute(?:-start)?\(", hlo))
     assert permutes >= len(plan.steps), permutes
     _fits(compiled)
+    return hlo
+
+
+@pytest.mark.parametrize("model", sorted(WIDTHS))
+@pytest.mark.parametrize("op", EXECUTORS)
+def test_executor_compiles_on_2x2_mesh(op, model, mesh4, pallas):
+    """The executor at the model's width (``_compile_executor``); its rows
+    are whole tiles, so the program pads and slices no lanes."""
+    hlo = _compile_executor(op, model, WIDTHS[model], mesh4)
+    assert jc.LANE_PAD not in hlo
+
+
+@pytest.mark.parametrize("op", EXECUTORS)
+def test_executor_compiles_lane_padded_on_2x2_mesh(op, mesh4, pallas):
+    """The executor at Nemotron-3-Nano-30B-A3B's 2688-wide bf16 rows, which
+    the capacity buffer holds at 3072 lanes, over the deepseek-moe-16b
+    dispatch plan (top-6, as Nemotron routes): it compiles, fits, and
+    pads and slices lanes under ``ragged.lane_pad``."""
+    hlo = _compile_executor(op, "deepseek-moe-16b", 2688, mesh4)
+    assert jc.LANE_PAD in hlo
