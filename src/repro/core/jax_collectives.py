@@ -415,7 +415,7 @@ def _scoped(name: str, fn):
 # Device scopes of the executors' phases (``jax.named_scope``).  A scope
 # lands in the ``op_name`` metadata of every op traced inside it, which a
 # profiler shows as the op's ``tf_op``; it changes no op of the program.
-FILL = "ragged.fill"                  # capacity buffer: zeros + own input
+FILL = "ragged.fill"                  # capacity buffer: own input (+ zeros)
 RELAYOUT_IN = "ragged.relayout_in"    # ``row_view`` of the executor's input
 STEP = "ragged.step"                  # each step's slab op
 PPERMUTE = "ragged.ppermute"          # each step's ``lax.ppermute``
@@ -444,17 +444,32 @@ def _unpad_lanes(x: jax.Array, F: int) -> jax.Array:
         return x[:, :F]
 
 
-def _fill(x_local: jax.Array, buf_rows: int, start) -> jax.Array:
+def _fill(x_local: jax.Array, buf_rows: int, start, *,
+          zero: bool = True) -> jax.Array:
     """The capacity buffer in the data plane's row view (``_slab_ops``'
-    ``view``) of rows at :func:`lane_width`: ``buf_rows`` zero rows with
+    ``view``) of rows at :func:`lane_width`: ``buf_rows`` rows with
     ``x_local``'s rows written at row ``start``.  Only the input is
     padded and relaid; the buffer is built in the view and stays in it
-    through the steps to the unpack."""
+    through the steps to the unpack.
+
+    ``zero=True`` zeroes every other row.  ``zero=False`` leaves them
+    uninitialised on the Pallas data planes (the ``slab_fill`` kernel
+    writes the input alone; the ``"xla"`` oracle still zeroes), which is
+    right only for an executor that reads no row outside its input and
+    the valid prefixes it has received but under a mask: alltoallv,
+    whose steps merge only ``recv_valid`` prefixes and whose unpack
+    selects only each block's valid rows.  gatherv and allgatherv
+    return the whole buffer, and reduce_scatterv and allreducev fold
+    received slabs into it, so those keep the zeros."""
     view = _slab_ops()[3]
     x = _pad_lanes(x_local)
     with jax.named_scope(RELAYOUT_IN):
         x = view(x)
     with jax.named_scope(FILL):
+        if not zero and _DATAPLANE != "xla":
+            from repro.kernels.ragged_gather import ops
+            return ops.slab_fill(x, buf_rows, start,
+                                 interpret=_DATAPLANE == "interpret")
         buf = jnp.zeros((buf_rows,) + x.shape[1:], x.dtype)
         # spill rows past the input are later overwritten by received
         # ranges (module docstring invariant)
@@ -986,7 +1001,9 @@ def alltoallv_shard(x_local: jax.Array, plan: ComposedPlan,
     r = jax.lax.axis_index(axis_name)
     F = x_local.shape[1]
     starts = jnp.asarray(plan.in_starts, jnp.int32)
-    buf = _fill(x_local, plan.buf_rows, starts[r])
+    # every read of the buffer outside the input and the received prefixes
+    # is masked, so its other rows need no zeros (``_fill``)
+    buf = _fill(x_local, plan.buf_rows, starts[r], zero=False)
     buf = _apply_steps(buf, plan.steps, r, axis_name)
     with jax.named_scope(UNPACK):
         out = jnp.zeros((plan.out_rows, F), x_local.dtype)

@@ -27,6 +27,10 @@
 * ``slab_merge_add_kernel`` / ``slab_step_reduce_kernel`` — the same
   with the received rows ADDED into the buffer (reduce data plane),
   folded through VMEM one row tile at a time.
+* ``slab_fill_kernel`` — a fresh capacity buffer holding the input at a
+  dynamic row: the input's rows are DMAed in and nothing else is
+  written, so every other row is left uninitialised (the executor uses
+  it only where no such row can be read unmasked).
 
 Every ``pallas_call`` carries a stable ``name=`` from ``KERNEL_NAMES``.
 The name becomes the HLO custom-call instruction's name, so a device
@@ -47,7 +51,8 @@ from .ref import fold_add
 
 # The HLO instruction name of each kernel (``pallas_call(name=...)``).
 KERNEL_NAMES = ("slab_extract", "slab_merge", "slab_step", "slab_merge_add",
-                "slab_step_reduce", "ragged_gather", "ragged_scatter")
+                "slab_step_reduce", "slab_fill", "ragged_gather",
+                "ragged_scatter")
 
 
 def _kernel(idx_ref, x_ref, o_ref, *, block_rows: int):
@@ -341,3 +346,24 @@ def slab_step_reduce_kernel(buf: jax.Array, slab: jax.Array,
          jax.ShapeDtypeStruct((rows_out,) + buf.shape[1:], buf.dtype)),
         _fold_scratch(buf, slab.shape[0]), in_place=True,
         interpret=interpret)
+
+
+def _slab_fill_kernel(start_ref, x, buf, sem):
+    copy = pltpu.make_async_copy(x, buf.at[pl.ds(start_ref[0], x.shape[0])],
+                                 sem)
+    copy.start()
+    copy.wait()
+
+
+def slab_fill_kernel(x: jax.Array, buf_rows: int, start: jax.Array, *,
+                     interpret: bool | pltpu.InterpretParams = False
+                     ) -> jax.Array:
+    """A fresh ``(buf_rows,) + x.shape[1:]`` buffer whose rows
+    ``[start, start + x.shape[0])`` are ``x``, moved by one DMA;
+    ``start`` is a (1,) int32 array (a traced per-device value).
+    Nothing else is written: every other row is uninitialised (NaN under
+    ``pltpu.InterpretParams(uninitialized_memory="nan")``)."""
+    return _slab_call(
+        "slab_fill", _slab_fill_kernel, (start,), (x,),
+        jax.ShapeDtypeStruct((buf_rows,) + x.shape[1:], x.dtype),
+        [pltpu.SemaphoreType.DMA(())], in_place=False, interpret=interpret)

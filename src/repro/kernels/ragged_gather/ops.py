@@ -12,9 +12,9 @@ import jax
 import jax.numpy as jnp
 
 from .kernel import (ragged_gather_kernel, ragged_scatter_kernel,
-                     slab_extract_kernel, slab_merge_add_kernel,
-                     slab_merge_kernel, slab_step_kernel,
-                     slab_step_reduce_kernel)
+                     slab_extract_kernel, slab_fill_kernel,
+                     slab_merge_add_kernel, slab_merge_kernel,
+                     slab_step_kernel, slab_step_reduce_kernel)
 from .ref import build_pack_index
 
 
@@ -160,3 +160,11 @@ def slab_step_reduce(buf, got, recv_start, recv_valid, send_start,
     return slab_step_reduce_kernel(buf, got, _scalar(recv_start),
                                    _scalar(recv_valid), _scalar(send_start),
                                    rows_out, interpret=interpret)
+
+
+def slab_fill(x, buf_rows: int, start, *, interpret=False):
+    """A fresh ``buf_rows``-row buffer holding ``x`` at traced row
+    ``start``; its other rows are uninitialised, so only a caller that
+    reads nothing of them unmasked may use it (alltoallv's capacity
+    buffer)."""
+    return slab_fill_kernel(x, buf_rows, _scalar(start), interpret=interpret)

@@ -1,9 +1,13 @@
 """Every executor on the ``"interpret"`` slab data plane (the Pallas
 kernels on the kernels' row view) against the ``"xla"`` one (the jnp
 oracles on the flat buffer), on 4 forced CPU devices, at a width whose
-row view is (N, 2, 128) and at one whose row view is (N, 1, 96).  Prints
-one ``DATAPLANE <op> <F> <equal|differ|zero>`` line per executor and
-width.  Subprocess-only (XLA_FLAGS):
+row view is (N, 2, 128) and at one whose row view is (N, 1, 96).
+``alltoallv_skew`` is alltoallv on a skewed matrix with zero blocks and
+a chip that sends nothing, where a padded slab reads past its sender's
+input: alltoallv's capacity buffer is not zeroed, and the interpreter
+leaves its unwritten rows NaN, so a row that leaked into the output
+would show.  Prints one ``DATAPLANE <op> <F> <equal|differ|zero|nan>``
+line per executor and width.  Subprocess-only (XLA_FLAGS):
 
     PYTHONPATH=src python tests/multidevice/child_dataplanes.py
 """
@@ -20,10 +24,18 @@ from jax.sharding import AxisType, PartitionSpec as P  # noqa: E402
 from repro.core import jax_collectives as jc  # noqa: E402
 
 OPS = ("gatherv", "scatterv", "allgatherv", "alltoallv", "reduce_scatterv",
-       "allreducev")
+       "allreducev", "alltoallv_skew")
 WIDTHS = (256, 96)
 SIZES = [13, 0, 21, 6]                  # offsets 0, 13, 13, 34: unaligned
 S = [[3, 0, 5, 2], [7, 1, 0, 4], [0, 6, 2, 1], [5, 2, 0, 0]]
+# chip 0's last block (1 row, to chip 3) shares a step with a 22-row one
+SKEW = [[4, 0, 30, 1], [17, 3, 0, 0], [0, 0, 0, 0], [1, 22, 0, 2]]
+
+
+def reads_past_input(pl) -> bool:
+    """Whether some step's padded slab runs past its sender's input."""
+    return any(send[s] + payload > pl.in_starts[s] + sum(SKEW[s])
+               for perm, payload, send, _, _ in pl.steps for s, _ in perm)
 
 
 def plan(op: str):
@@ -32,11 +44,12 @@ def plan(op: str):
             "allgatherv": lambda: jc.plan_allgatherv(SIZES),
             "alltoallv": lambda: jc.plan_alltoallv(S),
             "reduce_scatterv": lambda: jc.plan_reduce_scatterv(SIZES),
-            "allreducev": lambda: jc.plan_allreducev(SIZES)}[op]()
+            "allreducev": lambda: jc.plan_allreducev(SIZES),
+            "alltoallv_skew": lambda: jc.plan_alltoallv(SKEW)}[op]()
 
 
 def run(mesh, op: str, pl, x, dataplane: str) -> np.ndarray:
-    shard = getattr(jc, op + "_shard")
+    shard = getattr(jc, op.removesuffix("_skew") + "_shard")
     jc.set_dataplane(dataplane)
     fn = jax.jit(jax.shard_map(lambda xl: shard(xl, pl, "x"), mesh=mesh,
                                in_specs=P("x"), out_specs=P("x"),
@@ -47,6 +60,7 @@ def run(mesh, op: str, pl, x, dataplane: str) -> np.ndarray:
 def main():
     assert jax.device_count() == 4, jax.devices()
     mesh = jax.make_mesh((4,), ("x",), axis_types=(AxisType.Auto,))
+    assert reads_past_input(plan("alltoallv_skew"))
     for F in WIDTHS:
         for k, op in enumerate(OPS):
             pl = plan(op)
@@ -59,7 +73,8 @@ def main():
             got = run(mesh, op, pl, x, "interpret")
             same = want.shape == got.shape and np.array_equal(
                 want.view(np.uint32), got.view(np.uint32))
-            verdict = ("zero" if not want.any() else
+            verdict = ("nan" if np.isnan(got).any() else
+                       "zero" if not want.any() else
                        "equal" if same else "differ")
             print(f"DATAPLANE {op} {F} {verdict}", flush=True)
 

@@ -1,7 +1,10 @@
 """The executors on the kernels' row view: all six, run on 4 forced CPU
 devices with the slab kernels interpreted, are bitwise equal to the
 ``"xla"`` data plane, at a width whose row view is (N, 2, 128) and at one
-whose row view is (N, 1, 96).  One child process runs every case."""
+whose row view is (N, 1, 96); and alltoallv, whose capacity buffer is
+not zeroed, also on a skewed matrix whose padded slabs read past their
+sender's input, with no NaN (an unwritten row) anywhere in its output.
+One child process runs every case."""
 import os
 import subprocess
 import sys
@@ -11,7 +14,7 @@ import pytest
 CHILD = os.path.join(os.path.dirname(__file__), "multidevice",
                      "child_dataplanes.py")
 OPS = ("gatherv", "scatterv", "allgatherv", "alltoallv", "reduce_scatterv",
-       "allreducev")
+       "allreducev", "alltoallv_skew")
 
 
 @pytest.fixture(scope="module")

@@ -233,6 +233,31 @@ def test_slab_ops_row_view_match_refs(op, dtype):
                                       np.asarray(w))
 
 
+@pytest.mark.parametrize("row,dtype", [((2, 128), jnp.float32),
+                                       ((1, 96), jnp.float32),
+                                       ((24, 128), jnp.bfloat16)])
+@pytest.mark.parametrize("start", [0, 13, 23])   # 23 + 37 rows = buf_rows
+def test_slab_fill_writes_only_its_input(row, dtype, start):
+    """``slab_fill`` puts its input bit for bit at rows [start, start + n)
+    and writes no other row: with uninitialised memory read as NaN,
+    every other row is NaN."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.kernels.ragged_gather import ops
+
+    n, buf_rows = 37, 60
+    x = jnp.asarray(RNG.standard_normal((n,) + row), dtype)
+    nan = pltpu.InterpretParams(uninitialized_memory="nan")
+    got = np.asarray(jax.jit(lambda x, s: ops.slab_fill(
+        x, buf_rows, s, interpret=nan))(x, jnp.int32(start)))
+    assert got.shape == (buf_rows,) + row
+    bits = np.uint16 if dtype == jnp.bfloat16 else np.uint32
+    np.testing.assert_array_equal(got[start:start + n].view(bits),
+                                  np.asarray(x).view(bits))
+    rest = np.delete(got, np.s_[start:start + n], axis=0)
+    assert np.isnan(rest.astype(np.float32)).all()
+
+
 def test_row_view_layout():
     from repro.kernels.ragged_gather.ops import row_view
 

@@ -120,17 +120,18 @@ def _computations(hlo: str) -> dict:
     return comps
 
 
-def _whole_buffer_relayouts(hlo: str, elements: int) -> list[str]:
-    """Entry instructions that copy or transpose an array of at least
-    ``elements``, alone or inside a fusion: the relayouts of a whole
-    buffer.  Where the buffer's rows are not a multiple of the tile's 8,
-    its relayout also pads or slices it by a few rows; those ops belong
-    to the copy and are not counted apart.  Kernels are custom-calls and
-    never count."""
+def _whole_buffer_ops(hlo: str, elements: int,
+                      opcodes=RELAYOUT_OPCODES) -> list[str]:
+    """Entry instructions that are one of ``opcodes`` over an array of at
+    least ``elements``, alone or inside a fusion: by default the
+    relayouts (copies, transposes) of a whole buffer.  Where the
+    buffer's rows are not a multiple of the tile's 8, its relayout also
+    pads or slices it by a few rows; those ops belong to the copy and are
+    not counted apart.  Kernels are custom-calls and never count."""
     comps = _computations(hlo)
     return [f"{name} ({opcode})"
             for name, opcode, n, calls in comps["ENTRY"]
-            if any(op in RELAYOUT_OPCODES and m >= elements
+            if any(op in opcodes and m >= elements
                    for _, op, m, _ in [(name, opcode, n, None)]
                    + (comps.get(calls, []) if opcode == "fusion" else []))]
 
@@ -138,7 +139,8 @@ def _whole_buffer_relayouts(hlo: str, elements: int) -> list[str]:
 def _slab_program(op: str, F: int, sharding):
     """``(fn, args)``: slab kernel ``op`` at the buffer, payloads and
     offsets of the first transfer of the mixtral dispatch plan whose send
-    and receive rows are both unaligned, at row width ``F``."""
+    and receive rows are both unaligned, at row width ``F``;
+    ``slab_fill`` at the sender's input and input offset."""
     plan = _plan("mixtral-8x7b", "alltoallv")
     k, src, dst = next((k, s, d) for k, step in enumerate(plan.steps[:-1])
                        for s, d in step[0]
@@ -166,13 +168,18 @@ def _slab_program(op: str, F: int, sharding):
                                             int(valid[dst]),
                                             int(nxt[2][dst]), nxt[1],
                                             interpret=False),
+        "slab_fill": lambda x: fn(x, plan.buf_rows, plan.in_starts[src],
+                                  interpret=False),
     }[op]
+    if op == "slab_fill":   # the sender's input into a fresh buffer
+        return body, (shape(plan.cap),)
     return body, (shape(plan.buf_rows), shape(payload))
 
 
 @pytest.mark.parametrize("F", sorted(WIDTHS.values()) + list(PADDED_WIDTHS))
 @pytest.mark.parametrize("op", ["slab_extract", "slab_merge", "slab_step",
-                                "slab_merge_add", "slab_step_reduce"])
+                                "slab_merge_add", "slab_step_reduce",
+                                "slab_fill"])
 def test_slab_kernel_compiles_at_moe_width(op, F, one_chip, pallas):
     """Each slab kernel at the buffer, payloads and offsets of the first
     transfer of the mixtral dispatch plan whose send and receive rows
@@ -206,14 +213,19 @@ def test_kernel_instruction_carries_its_name(name, one_chip, pallas):
 
 EXECUTORS = ["gatherv", "scatterv", "allgatherv", "alltoallv",
              "reduce_scatterv", "allreducev"]
+# the op that zeroes each fill-built buffer; scatterv's buffer is its input
+ZEROED = {"gatherv": "broadcast", "allgatherv": "broadcast",
+          "reduce_scatterv": "pad", "allreducev": "pad"}
 
 
 def _compile_executor(op: str, model: str, F: int, mesh4) -> str:
     """The whole SPMD executor of ``op`` for ``model``'s dispatch plan at
     (rows, F) bf16 on the four described chips, compiled: the Pallas
     kernels are in the program, no whole-buffer relayout but that of an
-    input or output which is the whole buffer, one collective-permute per
-    plan step at least, and it fits one chip's HBM.  Returns its HLO."""
+    input or output which is the whole buffer, whole-buffer zeros in
+    every executor that builds its buffer by ``_fill`` but alltoallv, one
+    collective-permute per plan step at least, and it fits one chip's
+    HBM.  Returns its HLO."""
     plan = _plan(model, op)
     rows = (plan.buf_rows if op == "scatterv" else
             plan.in_rows if op in ("reduce_scatterv", "allreducev") else
@@ -229,9 +241,17 @@ def _compile_executor(op: str, model: str, F: int, mesh4) -> str:
     assert "tpu_custom_call" in hlo
     # the capacity buffer lives in the kernels' row view from fill to
     # unpack: only an input or an output that IS the whole buffer is relaid
-    moved = _whole_buffer_relayouts(hlo, plan.buf_rows * F)
+    moved = _whole_buffer_ops(hlo, plan.buf_rows * F)
     assert len(moved) <= (0 if op in ("alltoallv", "reduce_scatterv")
                           else 1), moved
+    # alltoallv's buffer is not zeroed (``slab_fill``); the executors whose
+    # unwritten rows are output or summed into keep their zeros, which
+    # XLA folds into a pad where the input sits at row 0
+    zeros = _whole_buffer_ops(hlo, plan.buf_rows * F, ("broadcast", "pad"))
+    if op == "alltoallv":
+        assert not zeros, zeros
+    elif op in ZEROED:
+        assert any(f"({ZEROED[op]})" in z for z in zeros), zeros
     permutes = len(re.findall(r" collective-permute(?:-start)?\(", hlo))
     assert permutes >= len(plan.steps), permutes
     _fits(compiled)
