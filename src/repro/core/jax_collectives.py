@@ -17,11 +17,9 @@ TPU adaptation of the paper's point-to-point schedules:
   data-dependent communication graph is not expressible inside one XLA
   program, so sizes quantize to buckets and one compiled executable is
   cached per bucketed size tuple (the standard JAX/TPU raggedness
-  answer).  This now lives in ``repro.tuner.service.PlannerService``,
+  answer).  This lives in ``repro.tuner.service.PlannerService``,
   which also *selects* the schedule per calibrated (alpha, beta) and
-  covers all four ops; ``RaggedGathervPlanner`` below is a
-  backward-compatible shim over it.  The fully distributed Lemma-3
-  construction
+  covers all six ops.  The fully distributed Lemma-3 construction
   itself IS expressible on device with static scalar ppermutes —
   ``tree_metadata_exchange`` demonstrates it and is property-tested against
   the host construction.
@@ -74,6 +72,14 @@ TPU adaptation of the paper's point-to-point schedules:
   an emulated 2-host x 4-device CPU mesh and asserts byte-identity
   against the single-host oracle.
 
+* **host-staged execution** — :func:`run_plan` runs any plan from host
+  arrays: :func:`stage` lays them out as the shard body's input,
+  :func:`shard_program` is the jitted ``shard_map`` of the op's body
+  (``SHARD_BODIES``), the call goes through :func:`call_with_deadline`,
+  and :func:`unstage` reads the result back.  ``PlannerService`` runs its
+  six ops through the same program, layout and deadline, at quantized
+  strides.
+
 The ordering invariant of the paper carries over: every payload is a
 consecutive rank range written at its global offset, so the root's buffer
 ends up in rank order with no reordering pass (zero-copy receives).
@@ -94,7 +100,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.obs import trace as obs_trace
 from repro.obs.metrics import REGISTRY as _OBS_REGISTRY
 
 from .composed import (ComposedSchedule, allgatherv_schedule,
@@ -590,7 +595,7 @@ def scatterv_shard(buf_root: jax.Array, plan: GathervPlan, axis_name: str) -> ja
 
 
 # --------------------------------------------------------------------------
-# convenience drivers
+# step deadline and fault hook of host-staged executions
 # --------------------------------------------------------------------------
 
 class InjectedFault(RuntimeError):
@@ -616,7 +621,7 @@ class CollectiveTimeout(RuntimeError):
         self.last_s = last_s
 
 
-# step-deadline config for every host driver; None disables the check.
+# step-deadline config for every host-staged execution; None disables it.
 _STEP_DEADLINE = {"deadline_s": None, "retries": 2, "backoff": 2.0,
                   "sleep_s": 0.0}
 _FAULT_HOOK = None  # callable(op, attempt) raising InjectedFault, or None
@@ -627,7 +632,8 @@ def configure_step_deadline(deadline_s: float | None, retries: int = 2,
                             sleep_s: float = 0.0) -> None:
     """Arm (or disarm, ``deadline_s=None``) the per-step deadline.
 
-    Every host driver's execution gets ``retries`` retries; attempt ``k``
+    Every host-staged execution (:func:`run_plan`, ``PlannerService``'s
+    ops) gets ``retries`` retries; attempt ``k``
     is allowed ``deadline_s * backoff**k`` (bounded exponential backoff —
     transient congestion gets more slack each try), with an optional
     ``sleep_s``-seeded backoff sleep between attempts.  The final miss
@@ -641,7 +647,7 @@ def configure_step_deadline(deadline_s: float | None, retries: int = 2,
 
 def set_fault_hook(hook) -> None:
     """Install a chaos hook called as ``hook(op, attempt)`` before every
-    host-driver execution attempt; raising :class:`InjectedFault` fails
+    host-staged execution attempt; raising :class:`InjectedFault` fails
     that attempt into the retry path.  ``None`` uninstalls."""
     global _FAULT_HOOK
     _FAULT_HOOK = hook
@@ -684,95 +690,29 @@ def call_with_deadline(op: str, thunk):
             time.sleep(min(sleep_s * backoff ** (attempt - 1), 1.0))
 
 
-def _run_traced(op: str, plan, fn, xg) -> np.ndarray:
-    """Execute a jitted driver on the (rows, F) input ``xg`` inside the
-    ``run/<op>`` span.
-
-    The span always lands in a running profiler's trace; when
-    ``repro.obs.trace`` is enabled it is also recorded with the plan
-    shape and bytes moved, at the rows' bytes and at the bytes the
-    ppermutes move (:func:`moved_row_bytes`).  The ``run_<op>`` counter
-    counts every call.  Execution goes through :func:`call_with_deadline`,
-    so an armed step deadline (or an installed chaos fault hook) gets
-    bounded retry and escalates as :class:`CollectiveTimeout` instead of
-    hanging.
-    """
-    args = {}
-    if obs_trace.current() is not None:
-        row_bytes = xg.shape[1] * xg.dtype.itemsize
-        moved = moved_row_bytes(row_bytes, xg.dtype)
-        args = {"op": op, "p": plan.p,
-                "segments": getattr(plan, "segments", 1),
-                "num_stages": getattr(plan, "num_stages", 0),
-                "row_bytes": row_bytes, "moved_row_bytes": moved}
-        for cls, nb in obs_trace.plan_link_bytes(
-                plan.steps, row_bytes=moved).items():
-            args[f"bytes_{cls}"] = nb
-    with obs_trace.span("run/" + op, "collective", **args) as sp:
-        t0 = time.perf_counter()
-        out, _, attempts = call_with_deadline(op,
-                                              lambda: np.asarray(fn(xg)))
-        sp.args.update(measured_s=time.perf_counter() - t0,
-                       attempts=attempts)
-    _OBS_REGISTRY.counter("run_" + op).inc()
-    return out
-
-
-def run_gatherv(mesh: Mesh, axis_name, blocks: list[np.ndarray],
-                root: int, bucket_rounds: int = 1, segments: int = 1,
-                wave_bin_ratio: float = 0.0, tree: GatherTree | None = None):
-    """Host-facing helper: gather ragged ``blocks`` (list of (n_i, F)) to the
-    root over ``mesh[axis_name]``.  Returns (result (total, F), plan).
-    ``axis_name`` may be an axis tuple (``("host", "device")``) and
-    ``tree`` a custom contiguous tree (e.g. a two-level schedule)."""
-    sizes = [int(b.shape[0]) for b in blocks]
-    F = blocks[0].shape[1]
-    plan = plan_gatherv(sizes, root, tree=tree, bucket_rounds=bucket_rounds,
-                        segments=segments, wave_bin_ratio=wave_bin_ratio)
-    x = np.zeros((plan.p, plan.cap, F), blocks[0].dtype)
-    for i, b in enumerate(blocks):
-        x[i, : sizes[i]] = b
-    x = x.reshape(plan.p * plan.cap, F)
-
-    @jax.jit
-    def run(xg):
-        return jax.shard_map(
-            lambda xl: gatherv_shard(xl, plan, axis_name),
-            mesh=mesh, in_specs=P(axis_name), out_specs=P(axis_name),
-            check_vma=False)(xg)
-
-    xg = jax.device_put(x, NamedSharding(mesh, P(axis_name)))
-    out = _run_traced("gatherv", plan, run, xg)  # (p * buf_rows, F)
-    out = out.reshape(plan.p, plan.buf_rows, F)
-    return out[root, : plan.total], plan
-
-
-def run_scatterv(mesh: Mesh, axis_name, data: np.ndarray,
-                 sizes: list[int], root: int, segments: int = 1,
-                 tree: GatherTree | None = None):
-    """Scatter rank-ordered rows of ``data`` (total, F) from the root into
-    ragged per-device blocks.  Returns (list of (n_i, F), plan)."""
-    plan = plan_gatherv(sizes, root, tree=tree, segments=segments)
-    F = data.shape[1]
-    xin = np.zeros((plan.p, plan.buf_rows, F), data.dtype)
-    xin[root, : plan.total] = data
-    xin = xin.reshape(plan.p * plan.buf_rows, F)
-
-    @jax.jit
-    def run(xg):
-        return jax.shard_map(
-            lambda xl: scatterv_shard(xl, plan, axis_name),
-            mesh=mesh, in_specs=P(axis_name), out_specs=P(axis_name),
-            check_vma=False)(xg)
-
-    xg = jax.device_put(xin, NamedSharding(mesh, P(axis_name)))
-    out = _run_traced("scatterv", plan, run, xg).reshape(plan.p, plan.cap, F)
-    return [out[i, : sizes[i]] for i in range(plan.p)], plan
-
-
 # --------------------------------------------------------------------------
 # composed collectives: allgatherv / alltoallv (repro.core.composed)
 # --------------------------------------------------------------------------
+
+def _validate_steps(steps, buf_rows: int, exact: int, padded: int) -> None:
+    """ppermute legality and bounds of a step table over a ``buf_rows``
+    buffer whose valid rows sum to ``exact`` and payloads to ``padded``;
+    raises AssertionError on violation."""
+    recv_total = 0
+    for perm, payload, send_start, recv_start, recv_valid in steps:
+        srcs = [s for s, _ in perm]
+        dsts = [d for _, d in perm]
+        assert len(set(srcs)) == len(srcs), "step has a double sender"
+        assert len(set(dsts)) == len(dsts), "step has a double receiver"
+        assert 1 <= payload
+        for s, d in perm:
+            assert 0 <= send_start[s] <= buf_rows - payload
+            assert 0 <= recv_start[d] <= buf_rows - payload
+            assert 0 < recv_valid[d] <= payload
+            recv_total += int(recv_valid[d])
+    assert recv_total == exact
+    assert exact <= padded
+
 
 @dataclass(frozen=True)
 class ComposedPlan:
@@ -825,20 +765,8 @@ class ComposedPlan:
 
     def validate(self) -> None:
         """ppermute legality + bounds; raises AssertionError on violation."""
-        recv_total = 0
-        for perm, payload, send_start, recv_start, recv_valid in self.steps:
-            srcs = [s for s, _ in perm]
-            dsts = [d for _, d in perm]
-            assert len(set(srcs)) == len(srcs), "step has a double sender"
-            assert len(set(dsts)) == len(dsts), "step has a double receiver"
-            assert 1 <= payload
-            for s, d in perm:
-                assert 0 <= send_start[s] <= self.buf_rows - payload
-                assert 0 <= recv_start[d] <= self.buf_rows - payload
-                assert 0 < recv_valid[d] <= payload
-                recv_total += int(recv_valid[d])
-        assert recv_total == self.tree_bytes_exact
-        assert self.tree_bytes_exact <= self.tree_bytes_padded
+        _validate_steps(self.steps, self.buf_rows, self.tree_bytes_exact,
+                        self.tree_bytes_padded)
         for src_start, dst_start, valid in self.extract:
             for i in range(self.p):
                 if valid[i] > 0:
@@ -1020,76 +948,6 @@ def alltoallv_shard(x_local: jax.Array, plan: ComposedPlan,
         return out
 
 
-def run_allgatherv(mesh: Mesh, axis_name, blocks: list[np.ndarray],
-                   root: int | None = None, bucket_rounds: int = 1,
-                   segments: int = 1, wave_bin_ratio: float = 0.0,
-                   schedule: ComposedSchedule | None = None):
-    """Host-facing helper: allgatherv ragged ``blocks`` over the mesh.
-    Returns ((p, total, F) array — every device's rank-ordered copy —
-    and the plan)."""
-    sizes = [int(b.shape[0]) for b in blocks]
-    F = blocks[0].shape[1]
-    if len(blocks) != mesh.devices.size:
-        raise ValueError(f"{len(blocks)} blocks for a "
-                         f"{mesh.devices.size}-device mesh")
-    plan = plan_allgatherv(sizes, root=root, bucket_rounds=bucket_rounds,
-                           segments=segments, wave_bin_ratio=wave_bin_ratio,
-                           schedule=schedule)
-    x = np.zeros((plan.p, plan.cap, F), blocks[0].dtype)
-    for i, b in enumerate(blocks):
-        x[i, : sizes[i]] = b
-    x = x.reshape(plan.p * plan.cap, F)
-
-    @jax.jit
-    def run(xg):
-        return jax.shard_map(
-            lambda xl: allgatherv_shard(xl, plan, axis_name),
-            mesh=mesh, in_specs=P(axis_name), out_specs=P(axis_name),
-            check_vma=False)(xg)
-
-    xg = jax.device_put(x, NamedSharding(mesh, P(axis_name)))
-    out = _run_traced("allgatherv", plan, run, xg)
-    out = out.reshape(plan.p, plan.buf_rows, F)
-    return out[:, : plan.total], plan
-
-
-def run_alltoallv(mesh: Mesh, axis_name: str,
-                  blocks: list[list[np.ndarray]], bucket_rounds: int = 1,
-                  segments: int = 1, wave_bin_ratio: float = 0.0,
-                  schedule: ComposedSchedule | None = None):
-    """Host-facing helper: ``blocks[i][j]`` is the (S[i][j], F) block rank
-    ``i`` sends to rank ``j``.  Returns (list of per-device received
-    buffers — device j's is ``concat_i blocks[i][j]`` — and the plan)."""
-    p = len(blocks)
-    if p != mesh.devices.size:
-        raise ValueError(f"{p}x{p} block matrix for a "
-                         f"{mesh.devices.size}-device mesh")
-    S = [[int(b.shape[0]) for b in row] for row in blocks]
-    F = blocks[0][0].shape[1]
-    dtype = blocks[0][0].dtype
-    plan = plan_alltoallv(S, bucket_rounds=bucket_rounds,
-                          segments=segments, wave_bin_ratio=wave_bin_ratio,
-                          schedule=schedule)
-    x = np.zeros((p, plan.cap, F), dtype)
-    for i, row in enumerate(blocks):
-        off = 0
-        for b in row:
-            x[i, off: off + b.shape[0]] = b
-            off += b.shape[0]
-    x = x.reshape(p * plan.cap, F)
-
-    @jax.jit
-    def run(xg):
-        return jax.shard_map(
-            lambda xl: alltoallv_shard(xl, plan, axis_name),
-            mesh=mesh, in_specs=P(axis_name), out_specs=P(axis_name),
-            check_vma=False)(xg)
-
-    xg = jax.device_put(x, NamedSharding(mesh, P(axis_name)))
-    out = _run_traced("alltoallv", plan, run, xg).reshape(p, plan.out_rows, F)
-    return [out[j, : plan.out_valid[j]] for j in range(p)], plan
-
-
 # --------------------------------------------------------------------------
 # reduction collectives: reduce_scatterv / allreducev
 # --------------------------------------------------------------------------
@@ -1143,20 +1001,8 @@ class ReduceScattervPlan:
         """ppermute legality + bounds; raises AssertionError on violation.
         The unique-receiver check is CORRECTNESS here, not just
         legality: a row folded twice in one step would double-count."""
-        recv_total = 0
-        for perm, payload, send_start, recv_start, recv_valid in self.steps:
-            srcs = [s for s, _ in perm]
-            dsts = [d for _, d in perm]
-            assert len(set(srcs)) == len(srcs), "step has a double sender"
-            assert len(set(dsts)) == len(dsts), "step has a double receiver"
-            assert 1 <= payload
-            for s, d in perm:
-                assert 0 <= send_start[s] <= self.buf_rows - payload
-                assert 0 <= recv_start[d] <= self.buf_rows - payload
-                assert 0 < recv_valid[d] <= payload
-                recv_total += int(recv_valid[d])
-        assert recv_total == self.tree_bytes_exact
-        assert self.tree_bytes_exact <= self.tree_bytes_padded
+        _validate_steps(self.steps, self.buf_rows, self.tree_bytes_exact,
+                        self.tree_bytes_padded)
 
 
 def plan_reduce_scatterv(sizes, bucket_rounds: int = 1, segments: int = 1,
@@ -1348,72 +1194,126 @@ def allreducev_shard(x_local: jax.Array, plan: AllreducevPlan,
                  x_local.shape[1])
 
 
-def run_reduce_scatterv(mesh: Mesh, axis_name, contribs: list[np.ndarray],
-                        sizes, bucket_rounds: int = 1, segments: int = 1,
-                        wave_bin_ratio: float = 0.0,
-                        schedule: ComposedSchedule | None = None):
-    """Host-facing helper: sum the per-device contribution vectors and
-    scatter ownership.  ``contribs[i]``: (total, F) flat contribution of
-    rank ``i``; ``sizes[j]`` rows at segment ``j``'s offset go to rank
-    ``j``.  Returns (list of per-device reduced blocks, plan)."""
-    p = len(contribs)
-    if p != mesh.devices.size:
-        raise ValueError(f"{p} contributions for a "
+# --------------------------------------------------------------------------
+# host-staged execution: one program, one host layout and one runner
+# --------------------------------------------------------------------------
+
+SHARD_BODIES = {"gatherv": gatherv_shard, "scatterv": scatterv_shard,
+                "allgatherv": allgatherv_shard,
+                "alltoallv": alltoallv_shard,
+                "reduce_scatterv": reduce_scatterv_shard,
+                "allreducev": allreducev_shard}
+
+
+def shard_program(op: str, plan, mesh: Mesh, axis_name):
+    """The jitted SPMD program of ``op``'s shard body (``SHARD_BODIES``)
+    for ``plan`` over ``mesh[axis_name]``: it takes the input
+    :func:`stage` lays out and returns the output :func:`unstage` reads.
+    ``axis_name`` may be an axis tuple (``("host", "device")``)."""
+    body = SHARD_BODIES[op]
+    return jax.jit(jax.shard_map(
+        lambda xl: body(xl, plan, axis_name), mesh=mesh,
+        in_specs=P(axis_name), out_specs=P(axis_name), check_vma=False))
+
+
+def host_rows(op: str, data, sizes=None):
+    """``(sizes, F, dtype)`` of ``op``'s host input ``data`` (see
+    :func:`stage`): the true sizes, read from the blocks of gatherv,
+    allgatherv and alltoallv (a p x p matrix) and given as ``sizes`` for
+    scatterv and the reductions, and the rows' width and dtype."""
+    if op == "alltoallv":
+        sizes = [[int(b.shape[0]) for b in row] for row in data]
+        rows = data[0][0]
+    elif op in ("gatherv", "allgatherv"):
+        sizes, rows = [int(b.shape[0]) for b in data], data[0]
+    else:
+        sizes = [int(s) for s in sizes]
+        rows = data if op == "scatterv" else data[0]
+    return sizes, int(rows.shape[1]), rows.dtype
+
+
+def _place(dst: np.ndarray, blocks, strides) -> None:
+    """Write ``blocks`` into ``dst`` in order, block ``k`` at row
+    ``sum(strides[:k])``."""
+    off = 0
+    for b, q in zip(blocks, strides):
+        dst[off: off + len(b)] = b
+        off += int(q)
+
+
+def _take(buf: np.ndarray, sizes, strides) -> np.ndarray:
+    """What :func:`_place` wrote: rows ``[sum(strides[:k]),
+    + sizes[k])`` of ``buf`` for every ``k``, concatenated."""
+    starts = np.cumsum([0, *strides[:-1]])
+    return np.concatenate([buf[s: s + int(n)]
+                           for s, n in zip(starts, sizes)])
+
+
+def stage(op: str, plan, data, sizes, strides) -> np.ndarray:
+    """The (p * in_rows, F) input of ``op``'s shard body from the host
+    arrays ``data``: gatherv and allgatherv take p blocks (sizes[i], F);
+    scatterv the root's rank-ordered rows (sum(sizes), F); alltoallv p x p
+    blocks, ``data[i][j]`` from rank i to rank j; the reductions p
+    contribution vectors (sum(sizes), F).
+
+    ``sizes`` are the true sizes (:func:`host_rows`) and ``strides`` the
+    sizes ``plan`` was built at, of the same shape: each block sits at
+    its offset in the plan and is padded with zero rows to its stride.
+    A plan built at the true sizes takes them as its strides; a
+    quantized one (``PlannerService``) takes the quantized sizes."""
+    p = plan.p
+    _, F, dtype = host_rows(op, data, sizes)
+    if op == "scatterv":
+        x = np.zeros((p, plan.buf_rows, F), dtype)
+        _place(x[plan.root], np.split(data, np.cumsum(sizes)[:-1]), strides)
+    elif op in ("reduce_scatterv", "allreducev"):
+        x = np.zeros((p, plan.in_rows, F), dtype)
+        for xi, c in zip(x, data):
+            _place(xi, np.split(c, np.cumsum(sizes)[:-1]), strides)
+    else:
+        x = np.zeros((p, plan.cap, F), dtype)
+        for i, xi in enumerate(x):
+            if op == "alltoallv":
+                _place(xi, data[i], strides[i])
+            else:
+                xi[: sizes[i]] = data[i]
+    return x.reshape(-1, F)
+
+
+def unstage(op: str, plan, out: np.ndarray, sizes, strides):
+    """``op``'s result from its program's host output ``out`` (the
+    true rows only, read at the offsets :func:`stage` used): gatherv the
+    root's (sum(sizes), F) rows in rank order; scatterv and
+    reduce_scatterv a list of rank i's (sizes[i], F) rows; allgatherv and
+    allreducev (p, sum(sizes), F), every rank's copy; alltoallv a list of
+    rank j's received rows, ``concat_i data[i][j]``."""
+    p = plan.p
+    out = out.reshape(p, -1, out.shape[-1])
+    if op in ("scatterv", "reduce_scatterv"):
+        return [out[i, :n] for i, n in enumerate(sizes)]
+    if op == "alltoallv":
+        S, Q = np.asarray(sizes), np.asarray(strides)
+        return [_take(out[j], S[:, j], Q[:, j]) for j in range(p)]
+    if op == "gatherv":
+        return _take(out[plan.root], sizes, strides)
+    return np.stack([_take(o, sizes, strides) for o in out])
+
+
+def run_plan(op: str, plan, mesh: Mesh, axis_name, data, sizes=None):
+    """Run ``plan``, built by ``plan_<op>`` at the true sizes, from host
+    arrays over ``mesh[axis_name]``: :func:`stage`, ``device_put``,
+    :func:`shard_program` under :func:`call_with_deadline`,
+    :func:`unstage`.  ``data`` as :func:`stage` takes it, ``sizes`` for
+    scatterv and the reductions.  Returns ``(result, plan)``."""
+    sizes, _, _ = host_rows(op, data, sizes)
+    if len(sizes) != mesh.devices.size:
+        raise ValueError(f"{op} over {len(sizes)} ranks on a "
                          f"{mesh.devices.size}-device mesh")
-    plan = plan_reduce_scatterv(sizes, bucket_rounds=bucket_rounds,
-                                segments=segments,
-                                wave_bin_ratio=wave_bin_ratio,
-                                schedule=schedule)
-    F = contribs[0].shape[1]
-    x = np.zeros((p, plan.in_rows, F), contribs[0].dtype)
-    for i, c in enumerate(contribs):
-        x[i, : plan.total] = c
-    x = x.reshape(p * plan.in_rows, F)
-
-    @jax.jit
-    def run(xg):
-        return jax.shard_map(
-            lambda xl: reduce_scatterv_shard(xl, plan, axis_name),
-            mesh=mesh, in_specs=P(axis_name), out_specs=P(axis_name),
-            check_vma=False)(xg)
-
-    xg = jax.device_put(x, NamedSharding(mesh, P(axis_name)))
-    out = _run_traced("reduce_scatterv", plan, run, xg).reshape(p, plan.cap, F)
-    return [out[j, : plan.sizes[j]] for j in range(p)], plan
-
-
-def run_allreducev(mesh: Mesh, axis_name, contribs: list[np.ndarray],
-                   sizes, bucket_rounds: int = 1, segments: int = 1,
-                   wave_bin_ratio: float = 0.0,
-                   rs_schedule: ComposedSchedule | None = None,
-                   ag_schedule: ComposedSchedule | None = None):
-    """Host-facing helper: allreducev the per-device contribution
-    vectors.  Returns ((p, total, F) array — every device's copy of the
-    reduced vector — and the plan)."""
-    p = len(contribs)
-    if p != mesh.devices.size:
-        raise ValueError(f"{p} contributions for a "
-                         f"{mesh.devices.size}-device mesh")
-    plan = plan_allreducev(sizes, bucket_rounds=bucket_rounds,
-                           segments=segments,
-                           wave_bin_ratio=wave_bin_ratio,
-                           rs_schedule=rs_schedule, ag_schedule=ag_schedule)
-    F = contribs[0].shape[1]
-    x = np.zeros((p, plan.in_rows, F), contribs[0].dtype)
-    for i, c in enumerate(contribs):
-        x[i, : plan.total] = c
-    x = x.reshape(p * plan.in_rows, F)
-
-    @jax.jit
-    def run(xg):
-        return jax.shard_map(
-            lambda xl: allreducev_shard(xl, plan, axis_name),
-            mesh=mesh, in_specs=P(axis_name), out_specs=P(axis_name),
-            check_vma=False)(xg)
-
-    xg = jax.device_put(x, NamedSharding(mesh, P(axis_name)))
-    out = _run_traced("allreducev", plan, run, xg).reshape(p, plan.buf_rows, F)
-    return out[:, : plan.total], plan
+    x = jax.device_put(stage(op, plan, data, sizes, sizes),
+                       NamedSharding(mesh, P(axis_name)))
+    fn = shard_program(op, plan, mesh, axis_name)
+    out, _, _ = call_with_deadline(op, lambda: np.asarray(fn(x)))
+    return unstage(op, plan, out, sizes, sizes), plan
 
 
 # --------------------------------------------------------------------------
@@ -1470,54 +1370,3 @@ def tree_metadata_exchange(m_local: jax.Array, axis_name: str, p: int):
         est = new_total - new_mg
         groot, m_groot, total = new_groot, new_mg, new_total
     return est, groot, total
-
-
-# --------------------------------------------------------------------------
-# runtime-ragged planner (host-in-the-loop bucketing)
-# --------------------------------------------------------------------------
-
-class RaggedGathervPlanner:
-    """Backward-compatible shim over :class:`repro.tuner.PlannerService`.
-
-    The original class cached compiled gatherv executables keyed by
-    bucketed size tuples in an UNBOUNDED dict; the service keeps the same
-    quantum-bucketing contract but bounds both the plan cache and the
-    compiled-executable cache (LRU) and counts hits/misses.  New code
-    should use ``PlannerService`` directly — it also selects the schedule
-    (TUW vs linear, bucket rounds) per calibrated (alpha, beta) and covers
-    scatterv/allgatherv/alltoallv.
-    """
-
-    def __init__(self, mesh: Mesh, axis_name: str, quantum: int = 128,
-                 max_plans: int = 64):
-        from repro.tuner.service import PlannerService
-
-        self._svc = PlannerService(mesh=mesh, axis_name=axis_name,
-                                   quantum=quantum,
-                                   max_cached_plans=max_plans,
-                                   max_compiled=max_plans)
-        self.mesh = mesh
-        self.axis = axis_name
-        self.quantum = quantum
-
-    @property
-    def service(self):
-        return self._svc
-
-    def bucketed(self, sizes) -> tuple[int, ...]:
-        return self._svc.bucketed(sizes)
-
-    def gatherv(self, blocks: list[np.ndarray], root: int):
-        return self._svc.gatherv(blocks, root)
-
-    @property
-    def cache_size(self) -> int:
-        return self._svc.cache_size
-
-    @property
-    def hits(self) -> int:
-        return self._svc.plan_hits
-
-    @property
-    def misses(self) -> int:
-        return self._svc.plan_misses

@@ -10,7 +10,7 @@ audit loop:
   is on, exported as Chrome-trace/Perfetto JSON;
 * :mod:`~repro.obs.metrics` — pure-Python counters / gauges /
   histograms published by the plan cache, the compiled-executable LRU,
-  the selection path, and the ``run_*`` drivers;
+  the selection path, and the host-staged executions;
 * :mod:`~repro.obs.residuals` — per-link-class measured-vs-predicted
   residual ledgers with a CUSUM drift detector; a detected shift
   triggers online refit and a params-epoch bump that honestly

@@ -4,13 +4,13 @@ Prometheus-shaped primitives with zero dependencies, built for the
 planner's publication points: the plan cache (hits / misses /
 evictions), the compiled-executable LRU, the selection path (which
 candidate won, was a race run), the drift loop (residuals recorded,
-refits fired, epoch bumps), and the ``run_*`` drivers (collectives
-executed, bytes moved).
+refits fired, epoch bumps), and the host-staged executions
+(collectives executed, deadline retries).
 
 Everything is process-local and synchronous — the single mutation per
 event is a dict/int update under the GIL, cheap enough to leave on
 unconditionally (services create their own :class:`Registry`; the
-module-level :data:`REGISTRY` serves the free-function drivers).
+module-level :data:`REGISTRY` serves code that holds no service).
 
 Example (doctested from docs/ARCHITECTURE.md §Telemetry)::
 
@@ -153,6 +153,7 @@ class Registry:
         return out
 
 
-# Default registry: publication point for the free-function `run_*`
-# drivers, which have no service object to hang a registry off.
+# Default registry: publication point for code that has no service object
+# to hang a registry off (the deadline's `run_retries`, the
+# `moved_row_bytes` gauge).
 REGISTRY = Registry()

@@ -3,7 +3,7 @@
 The fault-aware runtime needs one source of degraded-machine truth that
 every layer sees identically — the synthetic timing backends the tuner
 calibrates against, the step-oracle span accounting the telemetry plane
-consumes, and the host drivers' deadline/retry path.  A
+consumes, and the host-staged executions' deadline/retry path.  A
 :class:`FaultSchedule` is that source: a list of typed fault events
 (per-link β slowdowns, one-shot α stalls, message timeouts, hard host
 loss), all derived deterministically from the schedule contents and a
@@ -22,7 +22,7 @@ Consumers:
 * :class:`FaultClock` — the ``chaos=`` adapter of the calibration
   backends in ``tuner/calibrate.py`` (perturbs raw micro-measurements).
 * :class:`ExecutionFaultInjector` — wires ``TimeoutFault`` events into
-  the host drivers' deadline/retry path
+  the host-staged executions' deadline/retry path
   (``jax_collectives.set_fault_hook``).
 
 Elastic-shrink helpers (``surviving_ranks`` / ``shrink_sizes`` /
@@ -69,8 +69,8 @@ class HostStall:
 @dataclass(frozen=True)
 class TimeoutFault:
     """The first ``attempts`` delivery attempts of ``op`` (any op when
-    ``None``) at ``step`` time out — exercises the drivers' bounded
-    retry; ``attempts > retries`` escalates to ``CollectiveTimeout``."""
+    ``None``) at ``step`` time out — exercises the host-staged
+    executions' bounded retry; ``attempts > retries`` escalates to ``CollectiveTimeout``."""
 
     step: int
     op: str | None = None
@@ -260,7 +260,7 @@ class ChaoticMachine:
 
 
 class ExecutionFaultInjector:
-    """Feeds ``TimeoutFault`` events into the host drivers.
+    """Feeds ``TimeoutFault`` events into the host-staged executions.
 
     Registered via ``jax_collectives.set_fault_hook``; raises
     ``InjectedFault`` for the scheduled number of attempts, exercising

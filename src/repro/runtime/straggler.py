@@ -126,7 +126,8 @@ class StragglerPolicy:
         return self.observe_hosts(step, host_times)
 
     def record_timeout(self, step: int, host=None) -> str:
-        """A :class:`CollectiveTimeout` escalation from the host drivers.
+        """A :class:`CollectiveTimeout` escalation from a host-staged
+        execution.
 
         A collective that misses its step deadline after bounded retry
         is a breach by definition — no median comparison needed.  Counts

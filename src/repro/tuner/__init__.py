@@ -18,8 +18,8 @@ schedules:
   cache keyed by (op, p, quantized m-signature, root, dtype, mesh);
 * :mod:`~repro.tuner.service` — :class:`PlannerService`, the six ops'
   serving front end — gatherv/scatterv/allgatherv/alltoallv plus the
-  reduction collectives reduce_scatterv/allreducev (the old
-  ``RaggedGathervPlanner`` is now a shim over it);
+  reduction collectives reduce_scatterv/allreducev, executed through
+  the one host layout and program of :mod:`repro.core.jax_collectives`;
 * :mod:`~repro.tuner.classifier` / :mod:`~repro.tuner.serving` — the
   decode-time continuous-batching layer: raw per-step size vectors map
   onto bounded padded signature classes (padding priced under α-β,

@@ -463,7 +463,7 @@ class HierarchicalOnlineCalibrator:
 
     Hierarchical races used to be measured and then DROPPED from
     refitting (the flat calibrator had nowhere to put a two-link-class
-    observation — ``stats()['dropped_refit_observations']``).  This
+    observation).  This
     class keeps them: each observation is a 4-weight row ``(na_ici,
     nb_ici, na_dcn, nb_dcn)`` from :func:`hierarchical_weights` plus
     measured seconds, and ``fitted()`` solves the 4-parameter ridge

@@ -55,7 +55,6 @@ enabled.
 """
 from __future__ import annotations
 
-import time
 import uuid
 from collections import OrderedDict
 from dataclasses import dataclass
@@ -78,6 +77,14 @@ from .calibrate import (Calibration, HierarchicalCalibration,
                         flat_weights, hierarchical_weights)
 from .candidates import OPS, enumerate_candidates, plan_pipeline_cost
 from .select import Selection, select
+
+
+# telemetry settings no caller changes: the guideline monitor's slack over
+# the padded-regular bound, the residual CUSUM's allowance (in log ratio)
+# and each residual ledger's length
+GUIDELINE_SLACK = 1.25
+DRIFT_K = 0.5
+MAX_RESIDUALS = 512
 
 
 def _moved_row_bytes(row_bytes: int, dtype: str) -> int:
@@ -128,9 +135,9 @@ class PlannerService:
     """Autotuned, cached planning (and execution) for irregular collectives.
 
     ``mesh=None`` gives a plan-only service (benchmarks, tests without
-    devices); with a mesh, ``gatherv``/``scatterv``/``allgatherv``/
-    ``alltoallv`` execute through cached compiled executables exactly like
-    the old ``RaggedGathervPlanner`` did for gatherv alone.
+    devices); with a mesh, the six ops execute host arrays through one
+    cached program per plan record and the host layout of
+    :mod:`repro.core.jax_collectives` (``stage``/``unstage``).
     """
 
     def __init__(self, mesh=None, axis_name: str = "x", quantum: int = 128,
@@ -148,10 +155,8 @@ class PlannerService:
                  calibrator=None,
                  topology: HostTopology | None = None,
                  metrics: Registry | None = None,
-                 guideline_slack: float = 1.25,
-                 drift_k: float = 0.5, drift_h: float = 4.0,
+                 drift_h: float = 4.0,
                  drift_warmup: int = 8,
-                 max_residuals: int = 512,
                  refit_window: int = 8,
                  refit_prior_weight: float = 4.0,
                  auto_refit: bool = True,
@@ -230,14 +235,11 @@ class PlannerService:
         self.compiled_hits = 0
         self.compiled_misses = 0
         self.last_selection: Selection | None = None
-        # kept for stats() compatibility: always 0 now that hierarchical
-        # races refit through HierarchicalOnlineCalibrator
-        self.dropped_refit_observations = 0
         # ------------------------------------------------- telemetry plane
         self.metrics = metrics if metrics is not None else Registry()
         if self.cache.metrics is None:
             self.cache.metrics = self.metrics
-        self.guidelines = GuidelineMonitor(slack=guideline_slack)
+        self.guidelines = GuidelineMonitor(slack=GUIDELINE_SLACK)
         self.params_epoch = 0
         self.drift_refits = 0
         # ---------------------------------------------------- health plane
@@ -256,8 +258,8 @@ class PlannerService:
         # one residual ledger per link class: drift is usually per-fabric,
         # and per-class rows are what refit_from_residuals refits from
         def _ledger(cls: str) -> ResidualLedger:
-            return ResidualLedger(cls, max_observations=max_residuals,
-                                  detector=DriftDetector(k=drift_k,
+            return ResidualLedger(cls, max_observations=MAX_RESIDUALS,
+                                  detector=DriftDetector(k=DRIFT_K,
                                                          h=drift_h,
                                                          warmup=drift_warmup))
         self.ledgers = ({"ici": _ledger("ici"), "dcn": _ledger("dcn")}
@@ -408,29 +410,12 @@ class PlannerService:
     def plan_misses(self) -> int:
         return self.cache.misses
 
-    @property
-    def cache_size(self) -> int:
-        """Number of cached compiled executables (shim compatibility)."""
-        return len(self._compiled)
-
     # ----------------------------------------------------------- execution
-
-    def _require_mesh(self, p: int):
-        if self.mesh is None:
-            raise RuntimeError("execution needs a mesh; this PlannerService "
-                               "is plan-only (mesh=None)")
-        if p != self.mesh.devices.size:
-            raise ValueError(f"problem over {p} ranks on a "
-                             f"{self.mesh.devices.size}-device mesh")
 
     def _compiled_fn(self, kind: str, rec: PlanRecord, F: int,
                      dtype_str: str):
-        import jax
-        from jax.sharding import PartitionSpec as P
-
         from repro.core import jax_collectives as jc
 
-        plan = rec.plan
         ckey = (rec.serial, kind, F, dtype_str, jc.dataplane())
         fn = self._compiled.get(ckey)
         if fn is not None:
@@ -442,15 +427,7 @@ class PlannerService:
         self.compiled_misses += 1
         self.metrics.counter("compiled_lru_misses").inc()
         self._just_compiled = True
-        body = {"gatherv": jc.gatherv_shard, "scatterv": jc.scatterv_shard,
-                "allgatherv": jc.allgatherv_shard,
-                "alltoallv": jc.alltoallv_shard,
-                "reduce_scatterv": jc.reduce_scatterv_shard,
-                "allreducev": jc.allreducev_shard}[kind]
-        fn = jax.jit(jax.shard_map(
-            lambda xl: body(xl, plan, self.axis),
-            mesh=self.mesh, in_specs=P(self.axis), out_specs=P(self.axis),
-            check_vma=False))
+        fn = jc.shard_program(kind, rec.plan, self.mesh, self.axis)
         self._compiled[ckey] = fn
         while len(self._compiled) > self.max_compiled:
             self._compiled.popitem(last=False)
@@ -466,19 +443,22 @@ class PlannerService:
 
     def _run(self, op: str, rec: PlanRecord, fn, x, arg=None,
              root: int | None = None) -> np.ndarray:
-        """Execute a compiled plan inside the ``exec/<op>`` span, count it,
-        and deposit its wall time into the residual/guideline plane, priced
-        at the row bytes the plan was priced at."""
+        """Execute a compiled plan inside the ``exec/<op>`` span under the
+        step deadline and fault hook (``jax_collectives
+        .call_with_deadline``), count it, and deposit its wall time into
+        the residual/guideline plane, priced at the row bytes the plan was
+        priced at."""
+        from repro.core.jax_collectives import call_with_deadline
+
         fresh = self._just_compiled
         row_bytes = rec.moved_row_bytes
         args = {}
         if obs_trace.current() is not None:
             args = self._exec_span_args(op, rec, row_bytes, fresh)
         with obs_trace.span("exec/" + op, "collective", **args) as sp:
-            t0 = time.perf_counter()
-            out = np.asarray(fn(self._put(x)))
-            dt = time.perf_counter() - t0
-            sp.args["measured_s"] = dt
+            out, dt, attempts = call_with_deadline(
+                op, lambda: np.asarray(fn(self._put(x))))
+            sp.args.update(measured_s=dt, attempts=attempts)
         self.metrics.counter("collectives_executed").inc()
         if not fresh:
             # a freshly jitted executable's first call is dominated by XLA
@@ -764,164 +744,62 @@ class PlannerService:
             led.reset_after_refit()
         self.metrics.counter("drift_refits").inc()
 
-    def gatherv(self, blocks: list[np.ndarray], root: int):
-        """Gather ragged blocks to ``root``; returns (result, plan) — the
-        result rows are the true (unquantized) blocks in rank order."""
-        sizes = [int(b.shape[0]) for b in blocks]
-        self._require_mesh(len(blocks))
-        F = int(blocks[0].shape[1])
-        dt = blocks[0].dtype
-        rec = self.plan_record("gatherv", sizes, root=root, dtype=str(dt),
+    def _execute(self, op: str, data, sizes=None, root: int | None = None):
+        """Run ``op`` on host arrays (``jax_collectives.stage``) through
+        the cached plan and program of their quantized signature: the
+        blocks are staged at the quantized strides and the true rows read
+        back.  Returns ``(result, plan)``."""
+        from repro.core import jax_collectives as jc
+
+        if self.mesh is None:
+            raise RuntimeError("execution needs a mesh; this PlannerService "
+                               "is plan-only (mesh=None)")
+        sizes, F, dt = jc.host_rows(op, data, sizes)
+        if len(sizes) != self.mesh.devices.size:
+            raise ValueError(f"problem over {len(sizes)} ranks on a "
+                             f"{self.mesh.devices.size}-device mesh")
+        rec = self.plan_record(op, sizes, root=root, dtype=str(dt),
                                row_bytes=F * dt.itemsize)
-        plan = rec.plan
-        fn = self._compiled_fn("gatherv", rec, F, str(dt))
-        x = np.zeros((plan.p, plan.cap, F), dt)
-        for i, b in enumerate(blocks):
-            x[i, : sizes[i]] = b
-        out = self._run("gatherv", rec, fn, x.reshape(plan.p * plan.cap, F),
+        fn = self._compiled_fn(op, rec, F, str(dt))
+        strides = (quantize_matrix(sizes, self.quantum) if op == "alltoallv"
+                   else self.bucketed(sizes))
+        out = self._run(op, rec, fn, jc.stage(op, rec.plan, data, sizes,
+                                              strides),
                         arg=sizes, root=root)
-        out = out.reshape(plan.p, plan.buf_rows, F)
-        res, off = [], 0
-        for i, s in enumerate(sizes):
-            res.append(out[root, off: off + s])
-            off += plan.sizes[i]          # quantized stride
-        return np.concatenate(res, axis=0), plan
+        return jc.unstage(op, rec.plan, out, sizes, strides), rec.plan
+
+    def gatherv(self, blocks: list[np.ndarray], root: int):
+        """Gather ragged blocks to ``root``; returns ((sum(sizes), F) true
+        rows in rank order, plan)."""
+        return self._execute("gatherv", blocks, root=root)
 
     def scatterv(self, data: np.ndarray, sizes, root: int):
         """Scatter rank-ordered rows of ``data`` into ragged blocks;
         returns (list of (n_i, F) blocks, plan)."""
-        sizes = [int(s) for s in sizes]
-        self._require_mesh(len(sizes))
-        F = int(data.shape[1])
-        dt = data.dtype
-        rec = self.plan_record("scatterv", sizes, root=root, dtype=str(dt),
-                               row_bytes=F * dt.itemsize)
-        plan = rec.plan
-        fn = self._compiled_fn("scatterv", rec, F, str(dt))
-        xin = np.zeros((plan.p, plan.buf_rows, F), dt)
-        off_true, off_q = 0, 0
-        for i, s in enumerate(sizes):
-            xin[root, off_q: off_q + s] = data[off_true: off_true + s]
-            off_true += s
-            off_q += plan.sizes[i]
-        out = self._run("scatterv", rec, fn,
-                        xin.reshape(plan.p * plan.buf_rows, F),
-                        arg=sizes, root=root)
-        out = out.reshape(plan.p, plan.cap, F)
-        return [out[i, : sizes[i]] for i in range(plan.p)], plan
+        return self._execute("scatterv", data, sizes, root=root)
 
     def allgatherv(self, blocks: list[np.ndarray], root: int | None = None):
         """Every device ends with all true blocks in rank order; returns
         ((p, sum(sizes), F) array, plan)."""
-        sizes = [int(b.shape[0]) for b in blocks]
-        self._require_mesh(len(blocks))
-        F = int(blocks[0].shape[1])
-        dt = blocks[0].dtype
-        rec = self.plan_record("allgatherv", sizes, root=root, dtype=str(dt),
-                               row_bytes=F * dt.itemsize)
-        plan = rec.plan
-        fn = self._compiled_fn("allgatherv", rec, F, str(dt))
-        x = np.zeros((plan.p, plan.cap, F), dt)
-        for i, b in enumerate(blocks):
-            x[i, : sizes[i]] = b
-        out = self._run("allgatherv", rec, fn,
-                        x.reshape(plan.p * plan.cap, F), arg=sizes)
-        out = out.reshape(plan.p, plan.buf_rows, F)
-        keep = []
-        for i, s in enumerate(sizes):
-            start = plan.in_starts[i]     # quantized offsets
-            keep.append(out[:, start: start + s])
-        return np.concatenate(keep, axis=1), plan
+        return self._execute("allgatherv", blocks, root=root)
 
     def alltoallv(self, blocks: list[list[np.ndarray]]):
         """``blocks[i][j]``: block rank i sends to rank j.  Returns (list of
         per-device received buffers — device j's is ``concat_i blocks[i][j]``
         — and the plan)."""
-        p = len(blocks)
-        self._require_mesh(p)
-        S = [[int(b.shape[0]) for b in row] for row in blocks]
-        F = int(blocks[0][0].shape[1])
-        dt = blocks[0][0].dtype
-        rec = self.plan_record("alltoallv", S, dtype=str(dt),
-                               row_bytes=F * dt.itemsize)
-        plan = rec.plan
-        fn = self._compiled_fn("alltoallv", rec, F, str(dt))
-        Sq = np.asarray(quantize_matrix(S, self.quantum), np.int64)
-        x = np.zeros((p, plan.cap, F), dt)
-        for i, row in enumerate(blocks):
-            off = 0
-            for j, b in enumerate(row):
-                x[i, off: off + S[i][j]] = b
-                off += Sq[i, j]
-        out = self._run("alltoallv", rec, fn, x.reshape(p * plan.cap, F),
-                        arg=S)
-        out = out.reshape(p, plan.out_rows, F)
-        res = []
-        for j in range(p):
-            parts, off = [], 0
-            for i in range(p):
-                parts.append(out[j, off: off + S[i][j]])
-                off += Sq[i, j]
-            res.append(np.concatenate(parts, axis=0) if parts
-                       else out[j, :0])
-        return res, plan
+        return self._execute("alltoallv", blocks)
 
     def reduce_scatterv(self, contribs: list[np.ndarray], sizes):
-        """Sum the per-device flat contribution vectors; rank ``j`` keeps
-        segment ``j``.  ``contribs[i]``: (sum(sizes), F) in true (un-
-        quantized) layout.  Returns (list of (sizes[j], F) reduced
-        blocks, plan).  True segments pack at quantized offsets with
-        zero padding, so the padded rows sum to zero and the true rows'
-        sums are exact."""
-        sizes = [int(s) for s in sizes]
-        self._require_mesh(len(contribs))
-        F = int(contribs[0].shape[1])
-        dt = contribs[0].dtype
-        rec = self.plan_record("reduce_scatterv", sizes, dtype=str(dt),
-                               row_bytes=F * dt.itemsize)
-        plan = rec.plan
-        fn = self._compiled_fn("reduce_scatterv", rec, F, str(dt))
-        p = plan.p
-        x = np.zeros((p, plan.in_rows, F), dt)
-        for i, c in enumerate(contribs):
-            off_true, off_q = 0, 0
-            for j, s in enumerate(sizes):
-                x[i, off_q: off_q + s] = c[off_true: off_true + s]
-                off_true += s
-                off_q += plan.sizes[j]    # quantized stride
-        out = self._run("reduce_scatterv", rec, fn,
-                        x.reshape(p * plan.in_rows, F), arg=sizes)
-        out = out.reshape(p, plan.cap, F)
-        return [out[j, : sizes[j]] for j in range(p)], plan
+        """Sum the per-device flat contribution vectors (true layout, zero
+        rows padding each segment to its quantized stride, so the true
+        rows' sums are exact); rank ``j`` keeps segment ``j``.  Returns
+        (list of (sizes[j], F) reduced blocks, plan)."""
+        return self._execute("reduce_scatterv", contribs, sizes)
 
     def allreducev(self, contribs: list[np.ndarray], sizes):
-        """Sum the per-device flat contribution vectors; every rank ends
-        with the full reduced vector.  Returns ((p, sum(sizes), F) array
-        — padding rows stripped — and the plan)."""
-        sizes = [int(s) for s in sizes]
-        self._require_mesh(len(contribs))
-        F = int(contribs[0].shape[1])
-        dt = contribs[0].dtype
-        rec = self.plan_record("allreducev", sizes, dtype=str(dt),
-                               row_bytes=F * dt.itemsize)
-        plan = rec.plan
-        fn = self._compiled_fn("allreducev", rec, F, str(dt))
-        p = plan.p
-        x = np.zeros((p, plan.in_rows, F), dt)
-        for i, c in enumerate(contribs):
-            off_true, off_q = 0, 0
-            for j, s in enumerate(sizes):
-                x[i, off_q: off_q + s] = c[off_true: off_true + s]
-                off_true += s
-                off_q += plan.sizes[j]
-        out = self._run("allreducev", rec, fn,
-                        x.reshape(p * plan.in_rows, F), arg=sizes)
-        out = out.reshape(p, plan.buf_rows, F)
-        keep, off_q = [], 0
-        for j, s in enumerate(sizes):
-            keep.append(out[:, off_q: off_q + s])
-            off_q += plan.sizes[j]
-        return np.concatenate(keep, axis=1), plan
+        """Sum the per-device flat contribution vectors; returns ((p,
+        sum(sizes), F) array, every rank's copy of the sum, and plan)."""
+        return self._execute("allreducev", contribs, sizes)
 
     @property
     def stats(self) -> dict:
@@ -937,8 +815,6 @@ class PlannerService:
                 "compiled": len(self._compiled),
                 "compiled_hits": self.compiled_hits,
                 "compiled_misses": self.compiled_misses,
-                "dropped_refit_observations":
-                    self.dropped_refit_observations,
                 "params": params,
                 "params_epoch": self.params_epoch,
                 "drift_refits": self.drift_refits,

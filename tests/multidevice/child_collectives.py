@@ -19,10 +19,11 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import build_gather_tree
 from repro.core.distributions import NAMES, block_sizes
 from repro.core.jax_collectives import (
-    RaggedGathervPlanner, gatherv_shard, plan_gatherv, run_gatherv,
-    run_scatterv, set_dataplane, tree_metadata_exchange,
+    gatherv_shard, plan_gatherv, run_plan, set_dataplane,
+    tree_metadata_exchange,
 )
 from repro.analysis import collective_bytes_from_hlo
+from repro.tuner import PlannerService
 
 PP = 8
 
@@ -43,7 +44,8 @@ def check_gatherv_oracle():
             for scale in (3, 40):
                 sizes = block_sizes(name, PP, scale, seed=5)
                 blocks = rand_blocks(sizes, 4, rng)
-                got, plan = run_gatherv(mesh, "x", blocks, root)
+                got, plan = run_plan("gatherv", plan_gatherv(sizes, root),
+                                     mesh, "x", blocks)
                 want = np.concatenate(blocks, axis=0)
                 np.testing.assert_allclose(got, want, rtol=0, atol=0)
     print("gatherv oracle OK")
@@ -54,8 +56,10 @@ def check_gatherv_bucketing():
     rng = np.random.default_rng(1)
     sizes = block_sizes("spikes", PP, 50, seed=9)
     blocks = rand_blocks(sizes, 3, rng)
-    got1, plan1 = run_gatherv(mesh, "x", blocks, 2, bucket_rounds=1)
-    got3, plan3 = run_gatherv(mesh, "x", blocks, 2, bucket_rounds=3)
+    got1, plan1 = run_plan("gatherv", plan_gatherv(sizes, 2, bucket_rounds=1),
+                           mesh, "x", blocks)
+    got3, plan3 = run_plan("gatherv", plan_gatherv(sizes, 2, bucket_rounds=3),
+                           mesh, "x", blocks)
     np.testing.assert_allclose(got1, got3)
     assert plan3.tree_bytes_padded <= plan1.tree_bytes_padded, (
         plan1.tree_bytes_padded, plan3.tree_bytes_padded)
@@ -72,7 +76,8 @@ def check_scatterv_oracle():
             sizes = block_sizes(name, PP, 17, seed=3)
             total = sum(sizes)
             data = rng.standard_normal((total, 2)).astype(np.float32)
-            blocks, plan = run_scatterv(mesh, "x", data, sizes, root)
+            blocks, plan = run_plan("scatterv", plan_gatherv(sizes, root),
+                                    mesh, "x", data, sizes)
             off = 0
             for i, s in enumerate(sizes):
                 np.testing.assert_allclose(blocks[i], data[off: off + s])
@@ -85,7 +90,7 @@ def check_int_dtype():
     rng = np.random.default_rng(7)
     sizes = block_sizes("random", PP, 9, seed=1)
     blocks = [rng.integers(0, 1000, (s, 5)).astype(np.int32) for s in sizes]
-    got, _ = run_gatherv(mesh, "x", blocks, 4)
+    got, _ = run_plan("gatherv", plan_gatherv(sizes, 4), mesh, "x", blocks)
     np.testing.assert_array_equal(got, np.concatenate(blocks, axis=0))
     print("int dtype OK")
 
@@ -118,14 +123,15 @@ def check_metadata_exchange():
 def check_ragged_planner():
     mesh = mesh1d()
     rng = np.random.default_rng(3)
-    pl = RaggedGathervPlanner(mesh, "x", quantum=16)
+    svc = PlannerService(mesh, "x", quantum=16)
     for trial in range(6):
         sizes = [int(x) for x in rng.integers(1, 40, PP)]
         blocks = rand_blocks(sizes, 4, rng)
-        got, _ = pl.gatherv(blocks, root=1)
+        got, _ = svc.gatherv(blocks, root=1)
         np.testing.assert_allclose(got, np.concatenate(blocks, axis=0))
-    assert pl.cache_size <= 6  # bucketing caps distinct programs
-    print(f"ragged planner OK (cache={pl.cache_size} programs for 6 calls)")
+    assert len(svc._compiled) <= 6  # bucketing caps distinct programs
+    print(f"ragged planner OK (cache={len(svc._compiled)} programs for "
+          "6 calls)")
 
 
 def check_hlo_collectives():

@@ -16,7 +16,7 @@ from jax.sharding import AxisType
 from repro.core.composed import independent_scatter_bytes
 from repro.core.distributions import NAMES, block_sizes
 from repro.core.jax_collectives import (
-    plan_alltoallv, run_allgatherv, run_alltoallv, set_dataplane,
+    plan_allgatherv, plan_alltoallv, run_plan, set_dataplane,
 )
 
 PP = 8
@@ -33,7 +33,8 @@ def check_allgatherv_oracle():
         sizes = block_sizes(name, PP, 13, seed=4)
         blocks = [rng.standard_normal((s, 4)).astype(np.float32)
                   for s in sizes]
-        outs, plan = run_allgatherv(mesh, "x", blocks)
+        outs, plan = run_plan("allgatherv", plan_allgatherv(sizes), mesh,
+                              "x", blocks)
         want = np.concatenate(blocks, axis=0)
         for j in range(PP):  # EVERY device holds the rank-ordered buffer
             np.testing.assert_allclose(outs[j], want, rtol=0, atol=0)
@@ -48,7 +49,8 @@ def check_alltoallv_oracle():
         S[seed] = 0  # a silent sender too
         blocks = [[rng.standard_normal((int(S[i][j]), 3)).astype(np.float32)
                    for j in range(PP)] for i in range(PP)]
-        res, plan = run_alltoallv(mesh, "x", blocks)
+        res, plan = run_plan("alltoallv", plan_alltoallv(S), mesh, "x",
+                             blocks)
         for j in range(PP):
             # rank order of the received buffer: sources ascending
             want = np.concatenate(
@@ -66,8 +68,10 @@ def check_alltoallv_bucketing():
     S = rng.integers(0, 40, (PP, PP))
     blocks = [[rng.standard_normal((int(S[i][j]), 2)).astype(np.float32)
                for j in range(PP)] for i in range(PP)]
-    res1, p1 = run_alltoallv(mesh, "x", blocks, bucket_rounds=1)
-    res3, p3 = run_alltoallv(mesh, "x", blocks, bucket_rounds=3)
+    res1, p1 = run_plan("alltoallv", plan_alltoallv(S, bucket_rounds=1),
+                        mesh, "x", blocks)
+    res3, p3 = run_plan("alltoallv", plan_alltoallv(S, bucket_rounds=3),
+                        mesh, "x", blocks)
     for a, b in zip(res1, res3):
         np.testing.assert_allclose(a, b, rtol=0, atol=0)
     assert p3.tree_bytes_exact == p1.tree_bytes_exact
@@ -84,8 +88,10 @@ def check_allgatherv_bucketing():
     rng = np.random.default_rng(3)
     sizes = block_sizes("spikes", PP, 60, seed=8)
     blocks = [rng.standard_normal((s, 2)).astype(np.float32) for s in sizes]
-    o1, p1 = run_allgatherv(mesh, "x", blocks, bucket_rounds=1)
-    o2, p2 = run_allgatherv(mesh, "x", blocks, bucket_rounds=3)
+    o1, p1 = run_plan("allgatherv", plan_allgatherv(sizes, bucket_rounds=1),
+                      mesh, "x", blocks)
+    o2, p2 = run_plan("allgatherv", plan_allgatherv(sizes, bucket_rounds=3),
+                      mesh, "x", blocks)
     np.testing.assert_allclose(o1, o2, rtol=0, atol=0)
     assert p2.tree_bytes_padded <= p1.tree_bytes_padded
     print("allgatherv bucketing OK")
@@ -97,7 +103,7 @@ def check_int_dtype_alltoallv():
     S = rng.integers(0, 7, (PP, PP))
     blocks = [[rng.integers(0, 1000, (int(S[i][j]), 5)).astype(np.int32)
                for j in range(PP)] for i in range(PP)]
-    res, _ = run_alltoallv(mesh, "x", blocks)
+    res, _ = run_plan("alltoallv", plan_alltoallv(S), mesh, "x", blocks)
     for j in range(PP):
         want = np.concatenate(
             [blocks[i][j] for i in range(PP)], axis=0).reshape(-1, 5)

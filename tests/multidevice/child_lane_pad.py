@@ -5,8 +5,11 @@ hidden size of Nemotron-3-Nano-30B-A3B, at 3072.  Each result is
 compared bit for bit with the NumPy oracle (the reductions in the plan's
 fold order).  Prints one ``LANE_PAD <op> <F> <equal|differ>`` line per
 collective and width, and one ``MOVED <F> <row bytes> <moved row
-bytes>`` line per width from the plan records.  Subprocess-only
-(XLA_FLAGS):
+bytes>`` line per width from the plan records.  Then the six again at
+384 through a service of quantum 4, whose plans are built at sizes
+rounded up to multiples of 4, so every block is staged at a stride
+longer than itself: one ``QUANT <op> <equal|differ>`` line per
+collective.  Subprocess-only (XLA_FLAGS):
 
     PYTHONPATH=src python tests/multidevice/child_lane_pad.py
 """
@@ -37,6 +40,16 @@ def same(a, b) -> bool:
     return a.shape == b.shape and a.tobytes() == b.tobytes()
 
 
+def at_plan_offsets(c, sizes, plan):
+    """The flat contribution ``c`` (segments of ``sizes`` rows, one after
+    another) laid out as ``plan`` reads it: segment ``j`` at
+    ``plan.offsets[j]``, zero rows elsewhere."""
+    out = np.zeros((plan.total, c.shape[1]), c.dtype)
+    for j, (o, n) in enumerate(zip(np.cumsum([0, *sizes[:-1]]), sizes)):
+        out[plan.offsets[j]: plan.offsets[j] + n] = c[o: o + n]
+    return out
+
+
 def verdicts(svc, F: int) -> dict:
     """``{op: equal}`` for the six collectives at width ``F``."""
     rng = np.random.default_rng(F)
@@ -58,13 +71,17 @@ def verdicts(svc, F: int) -> dict:
         same(got[j], np.concatenate([a2a[i][j] for i in range(p)]))
         for j in range(p))
     got, plan = svc.reduce_scatterv(contribs, sizes)
+    want = execute_reduce_scatterv_plan_numpy(
+        plan, [at_plan_offsets(c, sizes, plan) for c in contribs])
     out["reduce_scatterv"] = all(
-        same(g, w) for g, w in zip(
-            got, execute_reduce_scatterv_plan_numpy(plan, contribs)))
+        same(g, w[:n]) for g, w, n in zip(got, want, sizes))
     got, plan = svc.allreducev(contribs, sizes)
+    want = execute_allreducev_plan_numpy(
+        plan, [at_plan_offsets(c, sizes, plan) for c in contribs])
     out["allreducev"] = all(
-        same(got[j], w) for j, w in enumerate(
-            execute_allreducev_plan_numpy(plan, contribs)))
+        same(got[j], np.concatenate([w[o: o + n] for o, n in
+                                     zip(plan.offsets, sizes)]))
+        for j, w in enumerate(want))
     return out
 
 
@@ -80,6 +97,9 @@ def main():
         rec = svc.plan_record("alltoallv", S, dtype="bfloat16",
                               row_bytes=2 * F)
         print(f"MOVED {F} {rec.row_bytes} {rec.moved_row_bytes}", flush=True)
+    svc = PlannerService(mesh=mesh, axis_name="x", quantum=4)
+    for op, ok in verdicts(svc, WIDTHS[0]).items():
+        print(f"QUANT {op} {'equal' if ok else 'differ'}", flush=True)
 
 
 if __name__ == "__main__":

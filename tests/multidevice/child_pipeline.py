@@ -31,23 +31,31 @@ def check_pipelined_equals_monolithic():
     sizes = block_sizes("spikes", PP, 25, seed=3)
     blocks = [rng.standard_normal((s, 3)).astype(np.float32) for s in sizes]
     want = np.concatenate(blocks, axis=0)
-    g1, _ = jc.run_gatherv(mesh, "x", blocks, root=2, segments=1)
-    s1, _ = jc.run_scatterv(mesh, "x", want, list(sizes), 2, segments=1)
-    a1, _ = jc.run_allgatherv(mesh, "x", blocks, segments=1)
     S_mat = rng.integers(0, 10, (PP, PP))
     ab = [[rng.standard_normal((int(S_mat[i][j]), 2)).astype(np.float32)
            for j in range(PP)] for i in range(PP)]
-    t1, _ = jc.run_alltoallv(mesh, "x", ab, segments=1)
+
+    def run4(S):
+        """gatherv, scatterv, allgatherv and alltoallv at ``S`` segments."""
+        return (jc.run_plan("gatherv", jc.plan_gatherv(sizes, 2, segments=S),
+                            mesh, "x", blocks),
+                jc.run_plan("scatterv", jc.plan_gatherv(sizes, 2, segments=S),
+                            mesh, "x", want, sizes)[0],
+                jc.run_plan("allgatherv",
+                            jc.plan_allgatherv(sizes, segments=S),
+                            mesh, "x", blocks)[0],
+                jc.run_plan("alltoallv",
+                            jc.plan_alltoallv(S_mat, segments=S),
+                            mesh, "x", ab)[0])
+
+    (g1, _), s1, a1, t1 = run4(1)
     for S in (2, 4):
-        gS, plan = jc.run_gatherv(mesh, "x", blocks, root=2, segments=S)
+        (gS, plan), sS, aS, tS = run4(S)
         assert plan.segments == S and max(plan.stage_ids) < plan.num_stages
         np.testing.assert_array_equal(gS, g1)
-        sS, _ = jc.run_scatterv(mesh, "x", want, list(sizes), 2, segments=S)
         for a, b in zip(sS, s1):
             np.testing.assert_array_equal(a, b)
-        aS, _ = jc.run_allgatherv(mesh, "x", blocks, segments=S)
         np.testing.assert_array_equal(aS, a1)
-        tS, _ = jc.run_alltoallv(mesh, "x", ab, segments=S)
         for a, b in zip(tS, t1):
             np.testing.assert_array_equal(a, b)
     print("pipelined == monolithic OK (4 ops, S in {2,4}, p=8)")
@@ -57,14 +65,16 @@ def check_pipelined_equals_monolithic():
     from repro.core.composed import alltoallv_direct_schedule
 
     S_sizes = [[int(b.shape[0]) for b in row] for row in ab]
-    tb, plan = jc.run_alltoallv(mesh, "x", ab, segments=2,
-                                wave_bin_ratio=2.0)
+    tb, plan = jc.run_plan(
+        "alltoallv", jc.plan_alltoallv(S_sizes, segments=2,
+                                       wave_bin_ratio=2.0), mesh, "x", ab)
     assert plan.wave_bin_ratio == 2.0
     for a, b in zip(tb, t1):
         np.testing.assert_array_equal(a, b)
-    td, plan = jc.run_alltoallv(mesh, "x", ab, segments=2,
-                                wave_bin_ratio=2.0,
-                                schedule=alltoallv_direct_schedule(S_sizes))
+    td, plan = jc.run_plan(
+        "alltoallv", jc.plan_alltoallv(
+            S_sizes, segments=2, wave_bin_ratio=2.0,
+            schedule=alltoallv_direct_schedule(S_sizes)), mesh, "x", ab)
     off_diag = sum(S_sizes[i][j] for i in range(PP) for j in range(PP)
                    if i != j)
     assert plan.tree_bytes_exact == off_diag  # direct: exact bytes
@@ -87,29 +97,38 @@ def check_pallas_slab_backend():
            for j in range(PP)] for i in range(PP)]
     contribs = [rng.standard_normal((sum(sizes), 4)).astype(np.float32)
                 for _ in range(PP)]
+    a2a = jc.plan_alltoallv(S_mat)
+    rs_plan = jc.plan_reduce_scatterv(sizes)
+    ar_plan = jc.plan_allreducev(sizes)
     jc.set_dataplane("xla")
-    t_ref, _ = jc.run_alltoallv(mesh, "x", ab)
-    rs_ref, _ = jc.run_reduce_scatterv(mesh, "x", contribs, sizes)
-    ar_ref, _ = jc.run_allreducev(mesh, "x", contribs, sizes)
+    t_ref, _ = jc.run_plan("alltoallv", a2a, mesh, "x", ab)
+    rs_ref, _ = jc.run_plan("reduce_scatterv", rs_plan, mesh, "x", contribs,
+                            sizes)
+    ar_ref, _ = jc.run_plan("allreducev", ar_plan, mesh, "x", contribs,
+                            sizes)
     try:
         jc.set_dataplane("interpret")
         for S in (1, 3):
-            out, _ = jc.run_gatherv(mesh, "x", blocks, root=0, segments=S)
+            gplan = jc.plan_gatherv(sizes, 0, segments=S)
+            out, _ = jc.run_plan("gatherv", gplan, mesh, "x", blocks)
             np.testing.assert_array_equal(out, want)
-            sc, _ = jc.run_scatterv(mesh, "x", want, list(sizes), 0,
-                                    segments=S)
+            sc, _ = jc.run_plan("scatterv", gplan, mesh, "x", want, sizes)
             for a, b in zip(sc, blocks):
                 np.testing.assert_array_equal(a, b)
-            ag, _ = jc.run_allgatherv(mesh, "x", blocks, segments=S)
+            ag, _ = jc.run_plan("allgatherv",
+                                jc.plan_allgatherv(sizes, segments=S),
+                                mesh, "x", blocks)
             for j in range(PP):
                 np.testing.assert_array_equal(ag[j], want)
-        t, _ = jc.run_alltoallv(mesh, "x", ab)
+        t, _ = jc.run_plan("alltoallv", a2a, mesh, "x", ab)
         for a, b in zip(t, t_ref):
             np.testing.assert_array_equal(a, b)
-        rs, _ = jc.run_reduce_scatterv(mesh, "x", contribs, sizes)
+        rs, _ = jc.run_plan("reduce_scatterv", rs_plan, mesh, "x", contribs,
+                            sizes)
         for a, b in zip(rs, rs_ref):
             np.testing.assert_array_equal(a, b)
-        ar, _ = jc.run_allreducev(mesh, "x", contribs, sizes)
+        ar, _ = jc.run_plan("allreducev", ar_plan, mesh, "x", contribs,
+                            sizes)
         np.testing.assert_array_equal(ar, ar_ref)
     finally:
         jc.set_dataplane("xla")

@@ -17,8 +17,8 @@ from repro.core.composed import (
     reduce_scatterv_direct_schedule, reduce_scatterv_halving_schedule,
 )
 from repro.core.distributions import NAMES, block_sizes
-from repro.core.jax_collectives import (run_allreducev, run_reduce_scatterv,
-                                        set_dataplane)
+from repro.core.jax_collectives import (plan_allreducev, plan_reduce_scatterv,
+                                        run_plan, set_dataplane)
 
 PP = 8
 
@@ -32,6 +32,13 @@ def _contribs(rng, total, F=3):
             for _ in range(PP)]
 
 
+def run_rs(mesh, contribs, sizes, **kw):
+    """reduce_scatterv of ``contribs`` under ``plan_reduce_scatterv(sizes,
+    **kw)``."""
+    return run_plan("reduce_scatterv", plan_reduce_scatterv(sizes, **kw),
+                    mesh, "x", contribs, sizes)
+
+
 def check_reduce_scatterv_oracle():
     mesh = mesh1d()
     rng = np.random.default_rng(0)
@@ -39,7 +46,7 @@ def check_reduce_scatterv_oracle():
         sizes = block_sizes(name, PP, 9, seed=5)
         offs = np.concatenate([[0], np.cumsum(sizes)])
         contribs = _contribs(rng, int(offs[-1]))
-        outs, plan = run_reduce_scatterv(mesh, "x", contribs, sizes)
+        outs, plan = run_rs(mesh, contribs, sizes)
         want = np.sum(contribs, axis=0)
         for j in range(PP):
             np.testing.assert_allclose(
@@ -54,13 +61,11 @@ def check_schedule_variants_agree():
     sizes = [7, 0, 3, 12, 1, 0, 5, 9]
     total = int(np.sum(sizes))
     contribs = _contribs(rng, total)
-    tuw, _ = run_reduce_scatterv(mesh, "x", contribs, sizes)
-    direct, _ = run_reduce_scatterv(
-        mesh, "x", contribs, sizes,
-        schedule=reduce_scatterv_direct_schedule(sizes))
-    halving, _ = run_reduce_scatterv(
-        mesh, "x", contribs, sizes,
-        schedule=reduce_scatterv_halving_schedule(sizes))
+    tuw, _ = run_rs(mesh, contribs, sizes)
+    direct, _ = run_rs(mesh, contribs, sizes,
+                       schedule=reduce_scatterv_direct_schedule(sizes))
+    halving, _ = run_rs(mesh, contribs, sizes,
+                        schedule=reduce_scatterv_halving_schedule(sizes))
     for a, b, c in zip(tuw, direct, halving):
         np.testing.assert_allclose(a, b, rtol=0, atol=1e-5)
         np.testing.assert_allclose(a, c, rtol=0, atol=1e-5)
@@ -72,13 +77,13 @@ def check_bitwise_repeatable():
     rng = np.random.default_rng(2)
     sizes = block_sizes("spikes", PP, 11, seed=3)
     contribs = _contribs(rng, int(np.sum(sizes)))
-    a, _ = run_reduce_scatterv(mesh, "x", contribs, sizes)
-    b, _ = run_reduce_scatterv(mesh, "x", contribs, sizes)
+    a, _ = run_rs(mesh, contribs, sizes)
+    b, _ = run_rs(mesh, contribs, sizes)
     for x, y in zip(a, b):
         np.testing.assert_array_equal(x, y)  # BITWISE, not approx
     # pipelined run is bitwise-identical to the monolithic one: the fold
     # order per flat row is the same step order either way
-    c, _ = run_reduce_scatterv(mesh, "x", contribs, sizes, segments=2)
+    c, _ = run_rs(mesh, contribs, sizes, segments=2)
     for x, y in zip(a, c):
         np.testing.assert_array_equal(x, y)
     print("reduce_scatterv bitwise repeatable (rerun + pipelined)")
@@ -89,7 +94,8 @@ def check_allreducev_oracle():
     rng = np.random.default_rng(4)
     sizes = block_sizes("decreasing", PP, 6, seed=7)
     contribs = _contribs(rng, int(np.sum(sizes)))
-    out, plan = run_allreducev(mesh, "x", contribs, sizes)
+    out, plan = run_plan("allreducev", plan_allreducev(sizes), mesh, "x",
+                         contribs, sizes)
     want = np.sum(contribs, axis=0)
     for j in range(PP):  # EVERY device holds the full reduced vector
         np.testing.assert_allclose(out[j], want, rtol=0, atol=1e-5)

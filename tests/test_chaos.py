@@ -268,6 +268,31 @@ class TestDeadlineRetry:
         out, _dt, attempts = jc.call_with_deadline("gatherv", lambda: 7)
         assert out == 7 and attempts == 1
 
+    @pytest.mark.parametrize("path", ["service", "run_plan"])
+    def test_host_staged_execution_retries_a_fault(self, path):
+        """Both host-staged paths run their program under the deadline:
+        an injected transient fault is retried and the result is exact."""
+        import jax
+        from jax.sharding import AxisType
+
+        mesh = jax.make_mesh((1,), ("x",), axis_types=(AxisType.Auto,))
+        blocks = [np.arange(10, dtype=np.float32).reshape(5, 2)]
+        inj = ExecutionFaultInjector(FaultSchedule.scripted(
+            TimeoutFault(0, op="gatherv", attempts=1))).install()
+        prev = jc.dataplane()
+        jc.set_dataplane("xla")
+        try:
+            if path == "service":
+                got, _ = PlannerService(mesh=mesh, axis_name="x",
+                                        quantum=4).gatherv(blocks, root=0)
+            else:
+                got, _ = jc.run_plan("gatherv", jc.plan_gatherv([5], 0),
+                                     mesh, "x", blocks)
+        finally:
+            jc.set_dataplane(prev)
+        np.testing.assert_array_equal(got, blocks[0])
+        assert inj.injected == 1
+
 
 # ------------------------------------------------------------- straggler
 

@@ -82,13 +82,22 @@ def child(child_env):
     lines = [line.split() for line in res.stdout.splitlines()]
     return ({(w[1], int(w[2])): w[3] for w in lines if w[0] == "LANE_PAD"},
             {int(w[1]): (int(w[2]), int(w[3])) for w in lines
-             if w[0] == "MOVED"})
+             if w[0] == "MOVED"},
+            {w[1]: w[2] for w in lines if w[0] == "QUANT"})
 
 
 @pytest.mark.parametrize("F", [384, 2688])
 @pytest.mark.parametrize("op", OPS)
 def test_executor_equals_oracle_at_lane_padded_width(op, F, child):
     assert child[0].get((op, F)) == "equal", child[0]
+
+
+@pytest.mark.parametrize("op", OPS)
+def test_executor_at_quantized_strides(op, child):
+    """Through a service of quantum 4 the plan is built at sizes rounded
+    up to multiples of 4: the true blocks are staged at the plan's longer
+    strides and read back from them, bitwise equal to the oracle."""
+    assert child[2].get(op) == "equal", child[2]
 
 
 @pytest.mark.parametrize("F, moved", [(384, 1024), (2688, 6144)])

@@ -1,6 +1,6 @@
 """PlannerService tests that need no devices (mesh=None plan path):
 warm-cache behavior, persistence across service instances, selection
-plumbing, and the RaggedGathervPlanner shim surface."""
+plumbing, and the bounded plan cache."""
 import pickle
 
 import numpy as np
@@ -109,15 +109,9 @@ def test_online_measurement_loop_updates_service_params():
     assert abs(np.log10(after.beta / 1e-7)) < abs(np.log10(before.beta / 1e-7))
 
 
-def test_shim_exposes_bounded_cache_and_counters():
-    """The RaggedGathervPlanner shim keeps its old surface (bucketed,
-    cache_size) and gains hit/miss counters; execution itself is covered
-    by the multidevice child test."""
-    from repro.core.jax_collectives import RaggedGathervPlanner
-
-    pl = RaggedGathervPlanner.__new__(RaggedGathervPlanner)  # no mesh needed
-    for attr in ("bucketed", "gatherv", "cache_size", "hits", "misses"):
-        assert hasattr(RaggedGathervPlanner, attr) or hasattr(pl, attr)
+def test_plan_cache_is_bounded():
+    """The plan cache keeps at most ``max_cached_plans`` records and
+    counts what it evicts."""
     svc = PlannerService(mesh=None, max_cached_plans=2, quantum=1)
     for i in range(4):
         svc.plan_record("gatherv", [i + 1, 2, 3, 4], root=0)
