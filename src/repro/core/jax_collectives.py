@@ -409,6 +409,20 @@ def _slab_ops(reduce: bool = False):
             ops.row_view)
 
 
+def _slab_unpack():
+    """The ``slab_unpack`` op of the selected data plane (``set_dataplane``),
+    which builds alltoallv's output from its blocks in the buffer: the
+    Pallas kernel, compiled or interpreted, whose rows outside the blocks
+    are uninitialised, or the jnp oracle, whose rows there are zero, under
+    the kernel's name."""
+    if _DATAPLANE == "xla":
+        from repro.kernels.ragged_gather import ref
+        return _scoped("slab_unpack", ref.slab_unpack_ref)
+    from repro.kernels.ragged_gather import ops
+    return functools.partial(ops.slab_unpack,
+                             interpret=_DATAPLANE == "interpret")
+
+
 def _scoped(name: str, fn):
     """``fn`` run under ``jax.named_scope(name)``."""
     def run(*args):
@@ -463,9 +477,9 @@ def _fill(x_local: jax.Array, buf_rows: int, start, *,
     right only for an executor that reads no row outside its input and
     the valid prefixes it has received but under a mask: alltoallv,
     whose steps merge only ``recv_valid`` prefixes and whose unpack
-    selects only each block's valid rows.  gatherv and allgatherv
-    return the whole buffer, and reduce_scatterv and allreducev fold
-    received slabs into it, so those keep the zeros."""
+    (``slab_unpack``) copies only each block's valid rows.  gatherv and
+    allgatherv return the whole buffer, and reduce_scatterv and
+    allreducev fold received slabs into it, so those keep the zeros."""
     view = _slab_ops()[3]
     x = _pad_lanes(x_local)
     with jax.named_scope(RELAYOUT_IN):
@@ -721,9 +735,9 @@ class ComposedPlan:
     Same step-table format as :class:`GathervPlan` (so the same
     ``_apply_steps`` executor runs it), plus the flat-row-space layout:
     device ``i`` writes its input at ``in_starts[i]``; for alltoallv the
-    ``extract`` tables copy each received block from its flat offset to
-    its consecutive-rank-range output offset (a static per-tree
-    ``dynamic_slice`` of ``chunk`` rows).
+    ``extract`` tables copy the valid rows of each received block from
+    its flat offset to its consecutive-rank-range output offset (the
+    ``slab_unpack`` op; ``chunk`` bounds every block's rows).
     """
 
     kind: str                       # "allgatherv" | "alltoallv"
@@ -734,10 +748,10 @@ class ComposedPlan:
     buf_rows: int                   # working buffer rows (total + spill)
     in_starts: tuple[int, ...]      # where device i's input lives (flat)
     out_valid: tuple[int, ...]      # true output rows per device
-    out_rows: int                   # output buffer rows (incl. spill)
+    out_rows: int                   # output rows (incl. spill), 8-row tiles
     steps: tuple[tuple, ...]        # (perm, payload, send/recv tables)
     extract: tuple[tuple, ...]      # alltoallv: (src_start, dst_start, valid)
-    chunk: int                      # static extraction slice rows
+    chunk: int                      # most rows of one extracted block
     num_rounds: int                 # composed global rounds (pre-bucketing)
     tree_bytes_exact: int
     tree_bytes_padded: int
@@ -880,7 +894,9 @@ def plan_alltoallv(size_matrix, bucket_rounds: int = 1, segments: int = 1,
         rounds, p, bucket_rounds, wave_bin_ratio)
     buf_rows = total + max(cap, max_payload, chunk)
     out_valid = tuple(int(c) for c in col_totals)
-    out_rows = max(1, int(col_totals.max(initial=0))) + chunk
+    # whole 8-row tiles: XLA then fuses the output's relayout out of the
+    # kernels' row view with alltoallv_shard's row mask into one op
+    out_rows = -(-(max(1, int(col_totals.max(initial=0))) + chunk) // 8) * 8
     # output offsets: block (r -> j) lands at sum_{i<r} S[i][j] — the
     # column-wise consecutive-rank-range invariant
     dst_off = np.concatenate([np.zeros((1, p), np.int64),
@@ -925,27 +941,29 @@ def alltoallv_shard(x_local: jax.Array, plan: ComposedPlan,
     """Per-shard alltoallv body.  ``x_local``: (cap, F) packed row of
     blocks destined to ranks 0..p-1.  Returns (out_rows, F); rows
     [0:out_valid[j]] on device j are the received blocks ordered by
-    source rank (each at its consecutive-rank-range offset)."""
+    source rank (each at its consecutive-rank-range offset), and the
+    rows past them are 0."""
     r = jax.lax.axis_index(axis_name)
-    F = x_local.shape[1]
     starts = jnp.asarray(plan.in_starts, jnp.int32)
     # every read of the buffer outside the input and the received prefixes
     # is masked, so its other rows need no zeros (``_fill``)
     buf = _fill(x_local, plan.buf_rows, starts[r], zero=False)
     buf = _apply_steps(buf, plan.steps, r, axis_name)
     with jax.named_scope(UNPACK):
-        out = jnp.zeros((plan.out_rows, F), x_local.dtype)
-        mask_rows = jnp.arange(plan.chunk, dtype=jnp.int32)[:, None]
-        for src_start, dst_start, valid in plan.extract:
-            s0 = jnp.asarray(src_start)[r]
-            d0 = jnp.asarray(dst_start)[r]
-            nv = jnp.asarray(valid)[r]
-            blk = _rows(buf, s0, plan.chunk, F)
-            cur = jax.lax.dynamic_slice(out, (d0, jnp.int32(0)),
-                                        (plan.chunk, F))
-            upd = jnp.where(mask_rows < nv, blk, cur)
-            out = jax.lax.dynamic_update_slice(out, upd, (d0, jnp.int32(0)))
-        return out
+        # (blocks, 3, p): each source block's src_start, dst_start and
+        # valid on every rank
+        tables = np.asarray(plan.extract, np.int32).reshape(-1, 3, plan.p)
+        src_start, dst_start, valid = jnp.asarray(
+            tables.transpose(1, 0, 2))[:, :, r]
+        out = _slab_unpack()(buf, src_start, dst_start, valid, plan.chunk,
+                             plan.out_rows)
+        # the blocks' valid rows tile [0, out_valid[r]); the rows past it
+        # read 0, set in the op that relays the output out of the view
+        out = out.reshape(plan.out_rows, -1)
+        rows = jnp.arange(plan.out_rows, dtype=jnp.int32)[:, None]
+        out = jnp.where(rows < jnp.asarray(plan.out_valid, jnp.int32)[r],
+                        out, jnp.zeros((), out.dtype))
+        return _unpad_lanes(out, x_local.shape[1])
 
 
 # --------------------------------------------------------------------------

@@ -31,6 +31,10 @@
   dynamic row: the input's rows are DMAed in and nothing else is
   written, so every other row is left uninitialised (the executor uses
   it only where no such row can be read unmasked).
+* ``slab_unpack_kernel`` — a fresh output holding the valid prefix of
+  each of p source blocks of the buffer, each at its own dynamic
+  destination row, by DMA alone; every other row is left uninitialised
+  (alltoallv's output, whose caller masks the rows past its valid count).
 
 Every ``pallas_call`` carries a stable ``name=`` from ``KERNEL_NAMES``.
 The name becomes the HLO custom-call instruction's name, so a device
@@ -51,8 +55,8 @@ from .ref import fold_add
 
 # The HLO instruction name of each kernel (``pallas_call(name=...)``).
 KERNEL_NAMES = ("slab_extract", "slab_merge", "slab_step", "slab_merge_add",
-                "slab_step_reduce", "slab_fill", "ragged_gather",
-                "ragged_scatter")
+                "slab_step_reduce", "slab_fill", "slab_unpack",
+                "ragged_gather", "ragged_scatter")
 
 
 def _kernel(idx_ref, x_ref, o_ref, *, block_rows: int):
@@ -164,21 +168,34 @@ def _extract(src, s0, out, sem):
     copy.wait()
 
 
-def _copy_prefix(src, dst, d0, n, sems):
-    """dst[d0 : d0 + n] <- src[0 : n] for a traced n <= src rows: one DMA
-    per set bit of n, all started before any is waited on."""
+def _prefix_copies(src, s0, dst, d0, n, rows: int, sems, sem0: int = 0):
+    """The DMAs of dst[d0 : d0 + n] <- src[s0 : s0 + n] for a traced
+    n <= rows, one per set bit of n, on semaphores ``sem0, sem0 + 1, ...``
+    of ``sems``: a list of ``(bit, copy)``, to run where ``bit != 0``."""
     copies = []
     off = jnp.int32(0)
-    for i, size in enumerate(_pow2_chunks(src.shape[0])):
+    for i, size in enumerate(_pow2_chunks(rows)):
         bit = n & size
         copies.append((bit, pltpu.make_async_copy(
-            src.at[pl.ds(off, size)], dst.at[pl.ds(d0 + off, size)],
-            sems.at[i])))
+            src.at[pl.ds(s0 + off, size)], dst.at[pl.ds(d0 + off, size)],
+            sems.at[sem0 + i])))
         off = off + bit
+    return copies
+
+
+def _run_copies(copies):
+    """Start every copy of ``_prefix_copies`` whose bit is set, then wait
+    for them all."""
     for bit, copy in copies:
         pl.when(bit != 0)(copy.start)
     for bit, copy in copies:
         pl.when(bit != 0)(copy.wait)
+
+
+def _copy_prefix(src, dst, d0, n, sems):
+    """dst[d0 : d0 + n] <- src[0 : n] for a traced n <= src rows: one DMA
+    per set bit of n, all started before any is waited on."""
+    _run_copies(_prefix_copies(src, 0, dst, d0, n, src.shape[0], sems))
 
 
 def _fold_prefix(src, dst, d0, n, cur, inc, sems):
@@ -367,3 +384,35 @@ def slab_fill_kernel(x: jax.Array, buf_rows: int, start: jax.Array, *,
         "slab_fill", _slab_fill_kernel, (start,), (x,),
         jax.ShapeDtypeStruct((buf_rows,) + x.shape[1:], x.dtype),
         [pltpu.SemaphoreType.DMA(())], in_place=False, interpret=interpret)
+
+
+def _slab_unpack_kernel(src_ref, dst_ref, valid_ref, buf, out, sems, *,
+                        chunk: int):
+    bits = len(_pow2_chunks(chunk))
+    copies = []
+    for i in range(src_ref.shape[0]):
+        copies += _prefix_copies(buf, src_ref[i], out, dst_ref[i],
+                                 valid_ref[i], chunk, sems, sem0=i * bits)
+    # every block's DMAs are in flight before the first wait
+    _run_copies(copies)
+
+
+def slab_unpack_kernel(buf: jax.Array, src_start: jax.Array,
+                       dst_start: jax.Array, valid: jax.Array, chunk: int,
+                       out_rows: int, *,
+                       interpret: bool | pltpu.InterpretParams = False
+                       ) -> jax.Array:
+    """A fresh ``(out_rows,) + buf.shape[1:]`` array whose rows
+    ``[dst_start[i], dst_start[i] + valid[i])`` are ``buf``'s rows
+    ``[src_start[i], src_start[i] + valid[i])``, for each block i of the
+    three (blocks,) int32 tables (traced per-device values), with
+    ``valid[i] <= chunk``.  DMA alone, one per set bit of each count, all
+    started before any is waited on; every other row is uninitialised
+    (NaN under ``pltpu.InterpretParams(uninitialized_memory="nan")``)."""
+    blocks = src_start.shape[0]
+    return _slab_call(
+        "slab_unpack", functools.partial(_slab_unpack_kernel, chunk=chunk),
+        (src_start, dst_start, valid), (buf,),
+        jax.ShapeDtypeStruct((out_rows,) + buf.shape[1:], buf.dtype),
+        [pltpu.SemaphoreType.DMA((blocks * len(_pow2_chunks(chunk)),))],
+        in_place=False, interpret=interpret)

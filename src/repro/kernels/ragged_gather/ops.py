@@ -14,7 +14,8 @@ import jax.numpy as jnp
 from .kernel import (ragged_gather_kernel, ragged_scatter_kernel,
                      slab_extract_kernel, slab_fill_kernel,
                      slab_merge_add_kernel, slab_merge_kernel,
-                     slab_step_kernel, slab_step_reduce_kernel)
+                     slab_step_kernel, slab_step_reduce_kernel,
+                     slab_unpack_kernel)
 from .ref import build_pack_index
 
 
@@ -168,3 +169,16 @@ def slab_fill(x, buf_rows: int, start, *, interpret=False):
     reads nothing of them unmasked may use it (alltoallv's capacity
     buffer)."""
     return slab_fill_kernel(x, buf_rows, _scalar(start), interpret=interpret)
+
+
+def slab_unpack(buf, src_start, dst_start, valid, chunk: int, out_rows: int,
+                *, interpret=False):
+    """A fresh ``out_rows``-row buffer holding, for each block i, the
+    ``valid[i]`` rows of ``buf`` from traced row ``src_start[i]`` at traced
+    row ``dst_start[i]`` (``valid[i] <= chunk``); its other rows are
+    uninitialised, so only a caller that masks them may use it
+    (alltoallv's output)."""
+    return slab_unpack_kernel(
+        buf, *(jnp.asarray(t, jnp.int32) for t in (src_start, dst_start,
+                                                    valid)),
+        chunk, out_rows, interpret=interpret)

@@ -100,6 +100,28 @@ def slab_step_reduce_ref(buf: jnp.ndarray, got: jnp.ndarray, recv_start,
     return buf, slab_extract_ref(buf, send_start, rows_out)
 
 
+def slab_unpack_ref(buf: jnp.ndarray, src_start, dst_start, valid,
+                    chunk: int, out_rows: int) -> jnp.ndarray:
+    """A zero ``(out_rows,) + buf.shape[1:]`` array whose rows
+    ``[dst_start[i], dst_start[i] + valid[i])`` are ``buf``'s rows
+    ``[src_start[i], src_start[i] + valid[i])``, block by block, for
+    ``valid[i] <= chunk``: ``chunk`` rows are cut from ``buf`` and
+    written under a mask of the valid ones, so every start plus ``chunk``
+    must lie within its array (``ComposedPlan.validate``'s bounds)."""
+    out = jnp.zeros((out_rows,) + buf.shape[1:], buf.dtype)
+    mask = jnp.arange(chunk, dtype=jnp.int32).reshape(
+        (chunk,) + (1,) * (buf.ndim - 1))
+    zeros = (jnp.int32(0),) * (buf.ndim - 1)
+    for s0, d0, nv in zip(src_start, dst_start, valid):
+        blk = jax.lax.dynamic_slice(buf, (s0,) + zeros,
+                                    (chunk,) + buf.shape[1:])
+        cur = jax.lax.dynamic_slice(out, (d0,) + zeros,
+                                    (chunk,) + buf.shape[1:])
+        out = jax.lax.dynamic_update_slice(
+            out, jnp.where(mask < nv, blk, cur), (d0,) + zeros)
+    return out
+
+
 def pack_blocks_ref(blocks: jnp.ndarray, sizes: jnp.ndarray,
                     total_pad: int) -> jnp.ndarray:
     """Pack padded (N, cap, F) blocks into a contiguous (total_pad, F)

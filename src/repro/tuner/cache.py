@@ -28,9 +28,10 @@ import pickle
 from collections import OrderedDict
 from dataclasses import dataclass
 
-CACHE_VERSION = 8  # v8: a PlanRecord carries the row bytes it was given
-                   # and the moved row bytes it was priced at, and a key
-                   # names the moved bytes where rows are lane-padded
+CACHE_VERSION = 9  # v9: an alltoallv plan's out_rows is whole 8-row tiles
+# v8: a PlanRecord carries the row bytes it was given and the moved row
+# bytes it was priced at, and a key names the moved bytes where rows are
+# lane-padded
 # v7: schedule zoo — the exact-DP opt trees, PAT, van-de-Geijn ring and
 # binomial-broadcast candidates joined the enumeration (new candidate
 # names, opt construction memoized per quantized signature), and

@@ -4,10 +4,13 @@ oracles on the flat buffer), on 4 forced CPU devices, at a width whose
 row view is (N, 2, 128) and at one whose row view is (N, 1, 96).
 ``alltoallv_skew`` is alltoallv on a skewed matrix with zero blocks and
 a chip that sends nothing, where a padded slab reads past its sender's
-input: alltoallv's capacity buffer is not zeroed, and the interpreter
-leaves its unwritten rows NaN, so a row that leaked into the output
-would show.  Prints one ``DATAPLANE <op> <F> <equal|differ|zero|nan>``
-line per executor and width.  Subprocess-only (XLA_FLAGS):
+input: alltoallv's capacity buffer and the output its ``slab_unpack``
+kernel builds are not zeroed, and the interpreter leaves their unwritten
+rows NaN, so a row that leaked into the output would show.  alltoallv's
+rows past ``out_valid`` must read 0 on both data planes, or the verdict
+is ``tail``.  Prints one ``DATAPLANE <op> <F>
+<equal|differ|zero|nan|tail>`` line per executor and width.
+Subprocess-only (XLA_FLAGS):
 
     PYTHONPATH=src python tests/multidevice/child_dataplanes.py
 """
@@ -48,6 +51,15 @@ def plan(op: str):
             "alltoallv_skew": lambda: jc.plan_alltoallv(SKEW)}[op]()
 
 
+def tail_is_zero(op: str, pl, out: np.ndarray) -> bool:
+    """Whether every chip's alltoallv output rows past ``out_valid`` are
+    all zero bits (true of the other ops, which have no such rows)."""
+    if not op.startswith("alltoallv"):
+        return True
+    per_chip = out.reshape(4, pl.out_rows, -1).view(np.uint32)
+    return all(not per_chip[j, n:].any() for j, n in enumerate(pl.out_valid))
+
+
 def run(mesh, op: str, pl, x, dataplane: str) -> np.ndarray:
     shard = getattr(jc, op.removesuffix("_skew") + "_shard")
     jc.set_dataplane(dataplane)
@@ -75,6 +87,8 @@ def main():
                 want.view(np.uint32), got.view(np.uint32))
             verdict = ("nan" if np.isnan(got).any() else
                        "zero" if not want.any() else
+                       "tail" if not (tail_is_zero(op, pl, want)
+                                      and tail_is_zero(op, pl, got)) else
                        "equal" if same else "differ")
             print(f"DATAPLANE {op} {F} {verdict}", flush=True)
 

@@ -2,9 +2,11 @@
 devices with the slab kernels interpreted, are bitwise equal to the
 ``"xla"`` data plane, at a width whose row view is (N, 2, 128) and at one
 whose row view is (N, 1, 96); and alltoallv, whose capacity buffer is
-not zeroed, also on a skewed matrix whose padded slabs read past their
-sender's input, with no NaN (an unwritten row) anywhere in its output.
-One child process runs every case."""
+not zeroed and whose output the ``slab_unpack`` kernel builds unzeroed,
+also on a skewed matrix whose padded slabs read past their sender's
+input, with no NaN (an unwritten row) anywhere in its output and zeros
+in every row past ``out_valid`` on both data planes.  One child process
+runs every case."""
 import os
 import subprocess
 import sys

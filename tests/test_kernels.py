@@ -258,6 +258,52 @@ def test_slab_fill_writes_only_its_input(row, dtype, start):
     assert np.isnan(rest.astype(np.float32)).all()
 
 
+# (row, dtype, chunk, src_start, dst_start, valid), in an alltoallv plan's
+# bounds: every start + chunk within the 64-row buffer and the 40-row output
+UNPACK_CASES = {
+    "zero_row_block": ((2, 128), jnp.float32, 8, (3, 20, 41), (0, 4, 4),
+                       (4, 0, 6)),
+    "valid_is_chunk": ((2, 128), jnp.float32, 16, (0, 17, 48), (0, 16, 24),
+                       (16, 8, 16)),
+    "chunk_not_pow2": ((2, 128), jnp.float32, 13, (50, 1, 30), (0, 13, 24),
+                       (13, 11, 5)),
+    "adjacent_dst": ((1, 96), jnp.float32, 7, (9, 33, 2), (0, 5, 12),
+                     (5, 7, 3)),
+    "lanes_3072_of_2688": ((24, 128), jnp.bfloat16, 13, (7, 29, 51),
+                           (0, 11, 24), (11, 13, 9)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(UNPACK_CASES))
+def test_slab_unpack_matches_ref(case):
+    """``slab_unpack`` puts each block's valid rows bit for bit where
+    ``ref.slab_unpack_ref`` puts them, and writes no other row: with
+    uninitialised memory read as NaN, every row outside the blocks is
+    NaN.  The last case is the 3072-lane row view that holds 2688-wide
+    bf16 rows."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.kernels.ragged_gather import ops, ref
+
+    row, dtype, chunk, *tables = UNPACK_CASES[case]
+    buf_rows, out_rows = 64, 40
+    buf = jnp.asarray(RNG.standard_normal((buf_rows,) + row), dtype)
+    src, dst, valid = (jnp.asarray(t, jnp.int32) for t in tables)
+    nan = pltpu.InterpretParams(uninitialized_memory="nan")
+    got = np.asarray(jax.jit(lambda b, s, d, n: ops.slab_unpack(
+        b, s, d, n, chunk, out_rows, interpret=nan))(buf, src, dst, valid))
+    want = np.asarray(ref.slab_unpack_ref(buf, src, dst, valid, chunk,
+                                          out_rows))
+    assert got.shape == want.shape == (out_rows,) + row
+    written = np.zeros(out_rows, bool)
+    for d0, n in zip(tables[1], tables[2]):
+        written[d0:d0 + n] = True
+    bits = np.uint16 if dtype == jnp.bfloat16 else np.uint32
+    np.testing.assert_array_equal(got[written].view(bits),
+                                  want[written].view(bits))
+    assert np.isnan(got[~written].astype(np.float32)).all()
+
+
 def test_row_view_layout():
     from repro.kernels.ragged_gather.ops import row_view
 

@@ -98,14 +98,14 @@ def _fits(compiled) -> int:
 
 
 RELAYOUT_OPCODES = ("copy", "copy-start", "transpose")
-_INSTR = re.compile(r"\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]\S* "
+_INSTR = re.compile(r"\s*(ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]\S* "
                     r"([\w\-]+)\(")
 
 
 def _computations(hlo: str) -> dict:
     """Compiled HLO text as ``{computation: [(name, opcode, elements,
-    called computation)]}``; ``"ENTRY"`` names the entry computation.
-    Tuple-valued instructions are left out."""
+    called computation, is root)]}``; ``"ENTRY"`` names the entry
+    computation.  Tuple-valued instructions are left out."""
     comps, body = {}, None
     for line in hlo.splitlines():
         head = re.match(r"(ENTRY )?%([\w.\-]+) \(", line)
@@ -113,34 +113,42 @@ def _computations(hlo: str) -> dict:
             body = comps.setdefault("ENTRY" if head.group(1)
                                     else head.group(2), [])
         elif body is not None and (m := _INSTR.match(line)):
-            name, dims, opcode = m.groups()
+            root, name, dims, opcode = m.groups()
             n = int(np.prod([int(d) for d in dims.split(",") if d]))
             calls = re.search(r"calls=%([\w.\-]+)", line)
-            body.append((name, opcode, n, calls and calls.group(1)))
+            body.append((name, opcode, n, calls and calls.group(1),
+                         bool(root)))
     return comps
 
 
-def _whole_buffer_ops(hlo: str, elements: int,
-                      opcodes=RELAYOUT_OPCODES) -> list[str]:
+def _whole_buffer_ops(hlo: str, elements: int, opcodes=RELAYOUT_OPCODES,
+                      *, fused: bool = True) -> list[str]:
     """Entry instructions that are one of ``opcodes`` over an array of at
     least ``elements``, alone or inside a fusion: by default the
     relayouts (copies, transposes) of a whole buffer.  Where the
     buffer's rows are not a multiple of the tile's 8, its relayout also
     pads or slices it by a few rows; those ops belong to the copy and are
-    not counted apart.  Kernels are custom-calls and never count."""
+    not counted apart.  ``fused=False`` counts a fusion only by its root,
+    the array it writes: a scalar that a fused ``select`` broadcasts is
+    no array of its own.  Kernels are custom-calls and never count."""
     comps = _computations(hlo)
+
+    def inside(calls):
+        return [ins for ins in comps.get(calls, []) if fused or ins[4]]
+
     return [f"{name} ({opcode})"
-            for name, opcode, n, calls in comps["ENTRY"]
+            for name, opcode, n, calls, _ in comps["ENTRY"]
             if any(op in opcodes and m >= elements
-                   for _, op, m, _ in [(name, opcode, n, None)]
-                   + (comps.get(calls, []) if opcode == "fusion" else []))]
+                   for _, op, m, *_ in [(name, opcode, n)]
+                   + (inside(calls) if opcode == "fusion" else []))]
 
 
 def _slab_program(op: str, F: int, sharding):
     """``(fn, args)``: slab kernel ``op`` at the buffer, payloads and
     offsets of the first transfer of the mixtral dispatch plan whose send
     and receive rows are both unaligned, at row width ``F``;
-    ``slab_fill`` at the sender's input and input offset."""
+    ``slab_fill`` at the sender's input and input offset, ``slab_unpack``
+    at the receiver's blocks and output."""
     plan = _plan("mixtral-8x7b", "alltoallv")
     k, src, dst = next((k, s, d) for k, step in enumerate(plan.steps[:-1])
                        for s, d in step[0]
@@ -170,16 +178,22 @@ def _slab_program(op: str, F: int, sharding):
                                             interpret=False),
         "slab_fill": lambda x: fn(x, plan.buf_rows, plan.in_starts[src],
                                   interpret=False),
+        "slab_unpack": lambda b: fn(b, *([int(t[dst]) for t in tables]
+                                         for tables in zip(*plan.extract)),
+                                    plan.chunk, plan.out_rows,
+                                    interpret=False),
     }[op]
     if op == "slab_fill":   # the sender's input into a fresh buffer
         return body, (shape(plan.cap),)
+    if op == "slab_unpack":   # the receiver's output from its buffer
+        return body, (shape(plan.buf_rows),)
     return body, (shape(plan.buf_rows), shape(payload))
 
 
 @pytest.mark.parametrize("F", sorted(WIDTHS.values()) + list(PADDED_WIDTHS))
 @pytest.mark.parametrize("op", ["slab_extract", "slab_merge", "slab_step",
                                 "slab_merge_add", "slab_step_reduce",
-                                "slab_fill"])
+                                "slab_fill", "slab_unpack"])
 def test_slab_kernel_compiles_at_moe_width(op, F, one_chip, pallas):
     """Each slab kernel at the buffer, payloads and offsets of the first
     transfer of the mixtral dispatch plan whose send and receive rows
@@ -225,7 +239,9 @@ def _compile_executor(op: str, model: str, F: int, mesh4) -> str:
     input or output which is the whole buffer, whole-buffer zeros in
     every executor that builds its buffer by ``_fill`` but alltoallv, one
     collective-permute per plan step at least, and it fits one chip's
-    HBM.  Returns its HLO."""
+    HBM.  alltoallv's output is built by one ``slab_unpack`` kernel: no
+    output-sized zeros, pad or ``dynamic-update-slice``.  Returns its
+    HLO."""
     plan = _plan(model, op)
     rows = (plan.buf_rows if op == "scatterv" else
             plan.in_rows if op in ("reduce_scatterv", "allreducev") else
@@ -250,6 +266,16 @@ def _compile_executor(op: str, model: str, F: int, mesh4) -> str:
     zeros = _whole_buffer_ops(hlo, plan.buf_rows * F, ("broadcast", "pad"))
     if op == "alltoallv":
         assert not zeros, zeros
+        unpacks = re.findall(r"%slab_unpack(?:\.\d+)* = [^\n]* custom-call\(",
+                             hlo)
+        assert len(unpacks) == 1, len(unpacks)
+        # the kernel's rows reach the output in one relayout, under a row
+        # mask that XLA fuses into it: no zeroed output, no block written
+        # into one
+        filled = _whole_buffer_ops(hlo, plan.out_rows * F,
+                                   ("dynamic-update-slice", "pad",
+                                    "broadcast"), fused=False)
+        assert not filled, filled
     elif op in ZEROED:
         assert any(f"({ZEROED[op]})" in z for z in zeros), zeros
     permutes = len(re.findall(r" collective-permute(?:-start)?\(", hlo))
